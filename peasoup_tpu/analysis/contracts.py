@@ -148,10 +148,9 @@ def _audit_built(
     import contextlib
 
     import jax
-    from jax.experimental import enable_x64
 
     findings: list[Finding] = []
-    x64 = enable_x64() if cfg.check_x64 else contextlib.nullcontext()
+    x64 = jax.enable_x64() if cfg.check_x64 else contextlib.nullcontext()
     try:
         fn, args, kwargs = build()
         if not hasattr(fn, "trace"):  # plain function: stage it

@@ -30,9 +30,8 @@ Two halves over :mod:`peasoup_tpu.ops.pallas`:
   - PSK203 — the kernel no longer traces/lowers in interpret mode at
     its registered geometry.
   - PSK208 — Mosaic lowering, attempted only where the toolchain
-    allows (a real TPU backend): failure is an error, downgraded to a
-    warning for kernels with a declared retile fallback (rejection is
-    exactly what their ladder exists to absorb).
+    allows (a real TPU backend): failure is an error (the TPU route
+    raises for that kernel).
 """
 
 from __future__ import annotations
@@ -40,7 +39,7 @@ from __future__ import annotations
 import ast
 
 from .astlint import ModuleContext, Rule, dotted_name, register_rule
-from .findings import Finding, SEV_ERROR, SEV_WARNING
+from .findings import Finding, SEV_ERROR
 
 _PALLAS_PATHS = ("peasoup_tpu/ops/pallas/",)
 _PALLAS_EXCLUDE = (
@@ -345,23 +344,22 @@ class ScalarPrefetchContract(Rule):
 
 @register_rule
 class LaneRetileWithoutFallback(Rule):
-    """Lane-retiling reshape in a kernel without a fallback ladder.
+    """Lane-retiling reshape in a kernel not declared as retiling.
 
-    The ``(span/dec, dec)`` family of reshapes re-tiles the minor
-    (lane) dimension inside the kernel; Mosaic support for it varies
-    by toolchain, so a kernel doing it must declare
-    ``retile_fallback=True`` in its registry entry — meaning a
-    probe-gated ladder exists for the driver to descend when THIS
-    toolchain rejects the retile. Flat ``reshape(-1)`` and
-    unit-row ``reshape(1, n)`` are tile-preserving and exempt.
+    Reshapes that re-tile the minor (lane) dimension inside a kernel
+    are refused by Mosaic in some forms (``(1, n) -> (n/32, 32)`` on
+    JAX 0.9), so a kernel doing one must declare ``lane_retile=True``
+    in its registry entry, which puts it among the kernels
+    tests/test_tpu_compile.py compiles for v5e. Flat ``reshape(-1)``
+    and unit-row ``reshape(1, n)`` are tile-preserving and exempt.
     """
 
     id = "PSK207"
     severity = SEV_ERROR
-    title = "lane-retiling reshape without a declared retile fallback"
+    title = "lane-retiling reshape in a kernel not declared lane_retile"
     fix_hint = (
-        "declare retile_fallback=True in the KernelSpec and give the "
-        "driver a probe-gated ladder (see spchain), or restructure "
+        "declare lane_retile=True in the KernelSpec and compile the "
+        "kernel for v5e in tests/test_tpu_compile.py, or restructure "
         "the kernel to avoid retiling the lane dim"
     )
     paths = _PALLAS_PATHS
@@ -384,7 +382,7 @@ class LaneRetileWithoutFallback(Rule):
 
     def check(self, ctx: ModuleContext):
         spec = _registry_spec(ctx.relpath)
-        if spec is not None and spec.retile_fallback:
+        if spec is not None and spec.lane_retile:
             return
         for fn in _kernel_defs(ctx):
             for node in ast.walk(fn):
@@ -540,16 +538,8 @@ def audit_kernel(spec, mosaic: bool | None = None) -> list[Finding]:
                     spec, "PSK208",
                     f"Mosaic lowering failed on this toolchain: "
                     f"{type(exc).__name__}: {exc!s:.300}",
-                    severity=(
-                        SEV_WARNING if spec.retile_fallback else SEV_ERROR
-                    ),
-                    hint=(
-                        "expected on toolchains the probe rejects — "
-                        "the declared fallback ladder absorbs it"
-                        if spec.retile_fallback
-                        else "the driver has no fallback for this "
-                        "kernel on this toolchain"
-                    ),
+                    hint="the TPU route raises for this kernel on this "
+                    "toolchain",
                 )
             )
     return findings

@@ -20,6 +20,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.telemetry import current as current_telemetry
+from ..utils.device import device_bytes_limit
+
 
 def _shift_slice(row_b: jax.Array, delay: jax.Array, nb: int) -> jax.Array:
     """row[delay : delay + nb*128] from a (T/128, 128) blocked channel
@@ -267,7 +270,8 @@ def _dedisperse_device_once(
     # skip the O(D*C) monotonicity scan entirely; the kernel also needs
     # its full f32 output + padded f32 filterbank copy to fit HBM —
     # bigger sets stay on the blocked scan, whose working set is one
-    # trial block
+    # trial block. The route lands in telemetry (``dedisp_engine``).
+    route = dict(ndm=int(delays.shape[0]), nchans=int(delays.shape[1]))
     if probe_pallas_dedisperse() and np.all(
         np.diff(np.asarray(delays), axis=0) >= 0
     ):
@@ -282,33 +286,16 @@ def _dedisperse_device_once(
             fil_tc.shape[0], delays.shape[1], delays.shape[0], out_nsamps,
             spread=spread,
         )
-        try:
-            limit = (
-                jax.local_devices()[0].memory_stats() or {}
-            ).get("bytes_limit", 0) or 12_000_000_000
-        except Exception:
-            limit = 12_000_000_000
-        if need < 0.6 * limit:
-            try:
-                res = dedisperse_pallas(
-                    fil_tc, delays, killmask, out_nsamps,
-                    quantize=quantize, scale=scale, spread=spread,
-                )
-                # force execution INSIDE the try: TPU runtime failures
-                # that surface asynchronously (e.g. allocation at a
-                # later sync) must also degrade to the jnp path, not
-                # crash the search (ADVICE r1)
-                jax.block_until_ready(res)
-                return res
-            except Exception as exc:
-                # the probe runs at one small shape; degrade instead of
-                # crashing if the production shape breaks Mosaic limits
-                import warnings
-
-                warnings.warn(
-                    "Pallas dedispersion failed at the production "
-                    f"shape; using the jnp scan: {exc!s:.200}"
-                )
+        route["fits"] = bool(need < 0.6 * device_bytes_limit())
+        if route["fits"]:
+            current_telemetry().event(
+                "dedisp_engine", engine="pallas", **route
+            )
+            return dedisperse_pallas(
+                fil_tc, delays, killmask, out_nsamps,
+                quantize=quantize, scale=scale, spread=spread,
+            )
+    current_telemetry().event("dedisp_engine", engine="scan", **route)
     ndm = delays.shape[0]
     fil_dev = jnp.asarray(fil_tc)
     kill_dev = jnp.asarray(killmask)

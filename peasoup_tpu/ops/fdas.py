@@ -11,10 +11,12 @@ program: overlap-save correlation, interbin power, normalisation,
 harmonic summing and per-level peak compaction stay fused; Python
 only ever sees static-size peak sets.
 
-Template rows are independent, so any row-split of the bank produces
-bitwise-identical outputs — the OOM ladder in pipeline/fdas.py halves
-the template batch under device pressure without perturbing results
-(the halving-bitwise test in tests/test_fdas.py pins this).
+Template rows are independent: a row's output depends on that row
+alone. The OOM ladder in pipeline/fdas.py halves the template batch
+under device pressure; the search's peak sets stay bitwise equal
+(tests/test_fdas.py pins this), while the raw correlation of a split
+bank agrees with the unsplit one to f32 rounding, since XLA's CPU FFT
+rounds per batch shape.
 """
 
 from __future__ import annotations
@@ -64,11 +66,8 @@ def _pad_trial(tim, *, size, nsamps_valid):
 
 # FFT-batch row alignment: every batched FFT inside correlate_bank
 # runs over a template axis padded to this multiple, so the flattened
-# transform count is lane-aligned for ANY template-batch size. Without
-# it the backend's remainder path (the `batch mod unroll` tail rows)
-# computes the same transforms through a differently-vectorised code
-# path, and a template-batch split stops being bitwise-neutral — the
-# property the OOM ladder's halving rung relies on.
+# transform count is lane-aligned for ANY template-batch size and no
+# data row goes through the backend's vector-remainder path.
 _ROW_ALIGN = 8
 
 
@@ -86,10 +85,10 @@ def correlate_bank(fser, tmpl, *, segment):
     stay in the sizes the fft machinery is fastest at and the compiled
     shape is independent of nbins' factorisation.
 
-    Each template row's output depends only on that row (rows are
-    padded to a lane-aligned count, see _ROW_ALIGN), so any row-batch
-    split of the bank is bitwise-identical to the unsplit call —
-    pinned by tests/test_fdas.py.
+    Each template row's output depends only on that row: bitwise at a
+    fixed batch shape, and to f32 rounding across the shapes a split
+    of the bank gives (XLA's CPU FFT rounds per batch shape) — pinned
+    by tests/test_fdas.py.
     """
     nbins = fser.shape[-1]
     ntmpl, width = tmpl.shape

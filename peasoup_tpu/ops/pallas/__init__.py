@@ -1,24 +1,56 @@
 """Pallas TPU kernels for the hot ops.
 
-Each kernel has a pure-jnp twin in ``peasoup_tpu.ops`` used as the
-oracle in tests (interpret mode on CPU) and as the fallback on
-non-TPU backends or when a kernel's preconditions don't hold.
+Each kernel has a pure-jnp twin in ``peasoup_tpu.ops``: the oracle in
+tests (interpret mode on CPU) and the path on non-TPU backends or when
+a kernel's shape preconditions don't hold.
+
+On a TPU backend the ``probe_pallas_*`` gates compile and run their
+kernel at the caller's shape and check it against the twin. A kernel
+that fails there raises :class:`KernelUnavailable`: the TPU route never
+drops quietly to a slower twin.
 """
 
 from __future__ import annotations
 
+from contextlib import contextmanager
 from functools import lru_cache
 
 import jax
 
 
+class KernelUnavailable(RuntimeError):
+    """A Pallas kernel the TPU route selected failed to compile, run,
+    or match its jnp oracle."""
+
+
 def backend_supports_pallas() -> bool:
     """Compiled Mosaic kernels need a real TPU backend; everywhere else
-    the kernels still run via the interpreter (tests) or fall back."""
+    the kernels still run via the interpreter (tests) or the twins."""
     try:
         return jax.default_backend() == "tpu"
     except RuntimeError:
         return False
+
+
+@contextmanager
+def _on_chip(kernel: str, **shape):
+    """Wrap one probe's compile + run + oracle check: any failure is
+    re-raised naming the kernel, its shape and the compiler's message."""
+    try:
+        yield
+    except Exception as exc:
+        where = ", ".join(f"{k}={v}" for k, v in shape.items())
+        raise KernelUnavailable(
+            f"Pallas kernel {kernel!r} failed on this TPU"
+            f"{f' at {where}' if where else ''}: "
+            f"{type(exc).__name__}: {exc}"
+        ) from exc
+
+
+def _oracle(ok: bool) -> bool:
+    if not ok:
+        raise AssertionError("kernel output differs from its jnp oracle")
+    return True
 
 
 @lru_cache(maxsize=None)
@@ -26,16 +58,14 @@ def probe_pallas_resample(n: int, block: int) -> bool:
     """REAL compile+run probe of the resample kernel at the shape the
     caller is about to use (cached per (n, block)).
 
-    The kernels are interpret-tested everywhere, but Mosaic's compiled
-    feature set differs per backend/toolchain; a production search must
-    degrade to the jnp twin rather than crash, so eligibility is
-    established by actually compiling and running the kernel with the
-    production n and block (grid trimmed to one DM x one accel trial —
+    The kernels are interpret-tested everywhere, but only the chip's
+    compiler shows what Mosaic accepts, so the kernel is compiled and
+    run with the production n and block (grid trimmed to one DM x one accel trial —
     the VMEM window, DMA shapes, and roll lowering are what vary with
     shape, and those are set by (n, block))."""
     if not backend_supports_pallas() or block <= 0:
         return False
-    try:
+    with _on_chip("resample", n=n, block=block):
         import numpy as np
         import jax
         import jax.numpy as jnp
@@ -50,19 +80,12 @@ def probe_pallas_resample(n: int, block: int) -> bool:
         x = jnp.asarray(np.arange(n, dtype=np.float32).reshape(1, n))
         afs = jnp.asarray(np.asarray([[af, -af]], dtype=np.float32))
         out = np.asarray(resample_block_pallas(x, afs, block=block))
-        if out.shape != (1, 2, n):
-            return False
         # the kernel's index math is the same f32 ops as the jnp twin:
         # anything but bitwise equality means a broken lowering
         ref = np.asarray(resample_accel(x[0], afs[0]))
-        return bool(np.array_equal(out[0], ref))
-    except Exception as exc:  # any Mosaic/compile failure -> jnp path
-        import warnings
-
-        warnings.warn(f"Pallas resample kernel unavailable at n={n}, "
-                      f"block={block}; using jnp fallback: "
-                      f"{type(exc).__name__}: {exc}")
-        return False
+        return _oracle(
+            out.shape == (1, 2, n) and np.array_equal(out[0], ref)
+        )
 
 
 @lru_cache(maxsize=None)
@@ -73,7 +96,7 @@ def probe_pallas_peaks(nbins: int, nlev: int, max_peaks: int) -> bool:
     exercises crossings, clusters, gaps, and window edges."""
     if not backend_supports_pallas():
         return False
-    try:
+    with _on_chip("peaks", nbins=nbins, nlev=nlev, max_peaks=max_peaks):
         import numpy as np
         import jax.numpy as jnp
 
@@ -138,22 +161,7 @@ def probe_pallas_peaks(nbins: int, nlev: int, max_peaks: int) -> bool:
                 ok = np.array_equal(
                     ci[r, lv, :k], ji[r, :k]
                 ) and np.array_equal(cs[r, lv, :k], js[r, :k])
-        if not ok:
-            import warnings
-
-            warnings.warn(
-                f"Pallas peaks kernel FAILED the oracle check at "
-                f"nbins={nbins}; using jnp fallback"
-            )
-        return ok
-    except Exception as exc:  # any Mosaic/compile failure -> jnp path
-        import warnings
-
-        warnings.warn(
-            f"Pallas peaks kernel unavailable at nbins={nbins}; using "
-            f"jnp fallback: {type(exc).__name__}: {exc}"
-        )
-        return False
+        return _oracle(ok)
 
 
 @lru_cache(maxsize=None)
@@ -167,11 +175,11 @@ def probe_pallas_interbin(size: int, block: int) -> bool:
     vary by toolchain (static pltpu.roll, clamped block index maps,
     VMEM carry scratch) are shape-independent, so a small probe gates
     every production shape — at the PRODUCTION block width (Mosaic
-    failures can be block-geometry-specific, e.g. the documented
-    PEASOUP_PEAKS_SUB SIGABRT), with the probe's m scaled up to fit."""
+    failures can be block-geometry-specific), with the probe's m scaled
+    up to fit."""
     if not backend_supports_pallas():
         return False
-    try:
+    with _on_chip("interbin", size=size, block=block):
         import numpy as np
         import jax.numpy as jnp
 
@@ -204,22 +212,7 @@ def probe_pallas_interbin(size: int, block: int) -> bool:
             and np.array_equal(got[:, : m + 1], ref)
             and not got[:, m + 1 :].any()
         )
-        if not ok:
-            import warnings
-
-            warnings.warn(
-                "Pallas interbin kernel FAILED the bitwise oracle check; "
-                "using the unfused path"
-            )
-        return ok
-    except Exception as exc:  # any Mosaic/compile failure -> unfused path
-        import warnings
-
-        warnings.warn(
-            f"Pallas interbin kernel unavailable; using the unfused "
-            f"path: {type(exc).__name__}: {exc}"
-        )
-        return False
+        return _oracle(ok)
 
 
 @lru_cache(maxsize=None)
@@ -233,7 +226,7 @@ def probe_pallas_harmpeaks(nbins: int, nharms: int, max_peaks: int) -> bool:
     map, inexact dot, mis-sliced window)."""
     if not backend_supports_pallas():
         return False
-    try:
+    with _on_chip("harmpeaks", nbins=nbins, nharms=nharms, max_peaks=max_peaks):
         import numpy as np
         import jax.numpy as jnp
 
@@ -289,23 +282,7 @@ def probe_pallas_harmpeaks(nbins: int, nharms: int, max_peaks: int) -> bool:
                 ok = np.array_equal(
                     ci[r, lv, :k], ji[r, :k]
                 ) and np.array_equal(cs[r, lv, :k], js[r, :k])
-        if not ok:
-            import warnings
-
-            warnings.warn(
-                f"Pallas harmonic+peaks mega-kernel FAILED the bitwise "
-                f"oracle check at nbins={nbins}; using the conv+peaks path"
-            )
-        return ok
-    except Exception as exc:  # any Mosaic/compile failure -> conv path
-        import warnings
-
-        warnings.warn(
-            f"Pallas harmonic+peaks mega-kernel unavailable at "
-            f"nbins={nbins}; using the conv+peaks path: "
-            f"{type(exc).__name__}: {exc}"
-        )
-        return False
+        return _oracle(ok)
 
 
 @lru_cache(maxsize=None)
@@ -335,7 +312,7 @@ def probe_pallas_dftspec(n: int, npad: int) -> bool:
     """
     if not backend_supports_pallas():
         return False
-    try:
+    with _on_chip("dftspec", n=n, npad=npad):
         import numpy as np
         import jax.numpy as jnp
 
@@ -377,23 +354,7 @@ def probe_pallas_dftspec(n: int, npad: int) -> bool:
                 and float(np.quantile(rel, 0.999)) <= ACC_Q999_REL
                 and not got[:, m + 1 :].any()
             )
-        if not ok:
-            import warnings
-
-            warnings.warn(
-                f"Pallas fused-DFT kernel FAILED the oracle gates at "
-                f"n={n}; using the einsum + interbin-kernel chain"
-            )
-        return ok
-    except Exception as exc:  # any Mosaic/compile failure -> einsum chain
-        import warnings
-
-        warnings.warn(
-            f"Pallas fused-DFT kernel unavailable at n={n}: "
-            f"{type(exc).__name__}: {exc}; using the einsum + "
-            f"interbin-kernel chain"
-        )
-        return False
+        return _oracle(ok)
 
 
 @lru_cache(maxsize=None)
@@ -410,7 +371,7 @@ def probe_pallas_boxcar(n_widths: int, span: int) -> bool:
     with the production (n_widths, span) geometry."""
     if not backend_supports_pallas() or span <= 0:
         return False
-    try:
+    with _on_chip("boxcar", n_widths=n_widths, span=span):
         import numpy as np
         import jax.numpy as jnp
 
@@ -441,22 +402,7 @@ def probe_pallas_boxcar(n_widths: int, span: int) -> bool:
             np.array_equal(np.asarray(got_b), np.asarray(ref_b))
             and np.array_equal(np.asarray(got_w), np.asarray(ref_w))
         )
-        if not ok:
-            import warnings
-
-            warnings.warn(
-                f"Pallas boxcar kernel FAILED the bitwise oracle check "
-                f"at n_widths={n_widths}, span={span}; using jnp twin"
-            )
-        return ok
-    except Exception as exc:  # any Mosaic/compile failure -> jnp twin
-        import warnings
-
-        warnings.warn(
-            f"Pallas boxcar kernel unavailable at n_widths={n_widths}, "
-            f"span={span}; using jnp twin: {type(exc).__name__}: {exc}"
-        )
-        return False
+        return _oracle(ok)
 
 
 @lru_cache(maxsize=None)
@@ -467,13 +413,14 @@ def probe_pallas_spchain(n_widths: int, span: int, dec: int) -> bool:
     BITWISE equality with the jnp twin
     (ops.singlepulse.boxcar_dec_best_twin). Beyond the boxcar kernel's
     feature set this needs the (span/dec, dec) retile of the sweep
-    tile, whose Mosaic support varies by toolchain — exactly what the
-    probe arbitrates before the driver may route to the kernel."""
+    tile, which the kernel makes by a transpose (spchain.fold_fits)."""
+    from .spchain import fold_fits
+
     if not backend_supports_pallas() or span <= 0 or dec <= 0:
         return False
-    if span % dec:
+    if not fold_fits(span, dec):
         return False
-    try:
+    with _on_chip("spchain", n_widths=n_widths, span=span, dec=dec):
         import numpy as np
         import jax.numpy as jnp
 
@@ -506,24 +453,7 @@ def probe_pallas_spchain(n_widths: int, span: int, dec: int) -> bool:
             np.array_equal(np.asarray(g), np.asarray(r))
             for g, r in zip(got, ref)
         )
-        if not ok:
-            import warnings
-
-            warnings.warn(
-                f"Pallas single-pulse chain kernel FAILED the bitwise "
-                f"oracle check at n_widths={n_widths}, span={span}, "
-                f"dec={dec}; using the unfused path"
-            )
-        return ok
-    except Exception as exc:  # any Mosaic/compile failure -> unfused path
-        import warnings
-
-        warnings.warn(
-            f"Pallas single-pulse chain kernel unavailable at "
-            f"n_widths={n_widths}, span={span}, dec={dec}; using the "
-            f"unfused path: {type(exc).__name__}: {exc}"
-        )
-        return False
+        return _oracle(ok)
 
 
 @lru_cache(maxsize=None)
@@ -539,7 +469,7 @@ def probe_pallas_specchain() -> bool:
     gates every production shape."""
     if not backend_supports_pallas():
         return False
-    try:
+    with _on_chip("specchain"):
         import numpy as np
         import jax.numpy as jnp
 
@@ -575,22 +505,7 @@ def probe_pallas_specchain() -> bool:
         ) and bool(
             (np.abs(s0_got - s0_ref) <= s0_envelope(s0_ref)).all()
         )
-        if not ok:
-            import warnings
-
-            warnings.warn(
-                "Pallas spectrum chain kernel FAILED the bitwise oracle "
-                "check; using the unfused path"
-            )
-        return ok
-    except Exception as exc:  # any Mosaic/compile failure -> unfused path
-        import warnings
-
-        warnings.warn(
-            f"Pallas spectrum chain kernel unavailable; using the "
-            f"unfused path: {type(exc).__name__}: {exc}"
-        )
-        return False
+        return _oracle(ok)
 
 
 from .resample import resample_block_pallas, resample_block  # noqa: E402
@@ -605,7 +520,7 @@ def probe_pallas_dedisperse() -> bool:
     for every production shape."""
     if not backend_supports_pallas():
         return False
-    try:
+    with _on_chip("dedisperse"):
         import numpy as np
         import jax.numpy as jnp
 
@@ -630,20 +545,4 @@ def probe_pallas_dedisperse() -> bool:
                 out_nsamps=out_nsamps, scale=0.9,
             )
         )
-        ok = bool(np.array_equal(got, ref))
-        if not ok:
-            import warnings
-
-            warnings.warn(
-                "Pallas dedispersion kernel FAILED the oracle check; "
-                "using the jnp path"
-            )
-        return ok
-    except Exception as exc:  # any Mosaic/compile failure -> jnp path
-        import warnings
-
-        warnings.warn(
-            f"Pallas dedispersion kernel unavailable; using jnp path: "
-            f"{type(exc).__name__}: {exc}"
-        )
-        return False
+        return _oracle(bool(np.array_equal(got, ref)))

@@ -101,7 +101,7 @@ def _build(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(d, tpad // span),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[
             pl.BlockSpec(
                 (None, span), lambda dd, gg, *_: (dd, gg),

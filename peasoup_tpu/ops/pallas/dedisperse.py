@@ -41,11 +41,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# toolchain compat: TPUMemorySpace -> MemorySpace rename; older
-# toolchains spell the off-chip space ANY (no HBM member). PSK203 pins
-# this against the installed toolchain.
-_MEMSPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-_HBM = getattr(_MEMSPACE, "HBM", _MEMSPACE.ANY)
 
 _DT = 8  # DM trials per output block (f32 sublane quantum)
 _CC = 16  # channels per grid step (windows DMA'd per step)
@@ -165,7 +160,7 @@ def _build(
                 (_DT, c), lambda dd, tt, cc: (dd, 0),
                 memory_space=pltpu.SMEM,
             ),
-            pl.BlockSpec(memory_space=_HBM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
         ],
         out_specs=pl.BlockSpec(
             (_DT, nb, 128), lambda dd, tt, cc: (dd, tt, 0),

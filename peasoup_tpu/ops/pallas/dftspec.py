@@ -73,11 +73,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# toolchain compat: TPUCompilerParams -> CompilerParams rename; both
-# accept vmem_limit_bytes. PSK203 pins this against the toolchain.
-_COMPILER_PARAMS = (
-    getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-)
 
 _SUB = 8  # rows per stripe (f32 sublane quantum)
 _MAX_M = 1 << 17  # VMEM gate: per-plane stripe buffer = 8*m*4 bytes
@@ -464,7 +459,7 @@ def _build(rpad: int, n: int, npad: int, interpret: bool):
             pltpu.VMEM((_SUB, n2, n1), jnp.float32),
             pltpu.VMEM((_SUB, n2, n1), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=interpret,

@@ -2,9 +2,9 @@
 
 Every kernel in :mod:`peasoup_tpu.ops.pallas` ships as a TRIPLE — the
 kernel itself, a bitwise (or envelope-gated) **jnp twin** used as the
-oracle and the fallback implementation, and a **compile-and-run probe**
-in ``ops/pallas/__init__.py`` that arbitrates, per toolchain and per
-production shape, whether the driver may route to the kernel at all.
+oracle and as the path off TPU, and a **compile-and-run probe** in
+``ops/pallas/__init__.py`` that checks the kernel against the twin at
+the production shape on a TPU and raises if it fails.
 The convention was enforced by review only; this registry makes it a
 machine-checked contract: the audit's kernel engine
 (:mod:`peasoup_tpu.analysis.kernels`) cross-references every entry
@@ -31,14 +31,14 @@ class KernelSpec:
 
     ``probe`` names the ``probe_pallas_*`` gate in
     ``ops/pallas/__init__.py``; ``twin`` is the dotted path of the jnp
-    oracle the probe must compare against; ``fallback`` documents the
-    ladder the driver descends when the probe rejects.
+    oracle the probe must compare against; ``fallback`` names the path
+    that runs off TPU.
     ``scalar_prefetch`` is the kernel's ``num_scalar_prefetch`` count
     (0 = no scalar-prefetch grid), cross-checked against the module AST
-    (PSK206). ``retile_fallback`` marks kernels that retile the lane
-    dimension in-kernel (the ``(span/dec, dec)`` reshape family) and
-    therefore MUST sit behind a probe-gated retile ladder (PSK207
-    flags lane retiles in kernels without it).
+    (PSK206). ``lane_retile`` marks kernels that retile the lane
+    dimension in-kernel; Mosaic refuses some retiles, so each marked
+    kernel is compiled for v5e by tests/test_tpu_compile.py (PSK207
+    flags lane retiles in kernels without the mark).
     """
 
     name: str
@@ -51,7 +51,7 @@ class KernelSpec:
     # builds the Mosaic-lowered variant for TPU toolchain checks
     build: Callable[..., tuple[Callable, tuple, dict[str, Any]]]
     scalar_prefetch: int = 0
-    retile_fallback: bool = False
+    lane_retile: bool = False
 
 
 def _build_dedisperse(interpret: bool = True):
@@ -302,12 +302,12 @@ _KERNELS: tuple[KernelSpec, ...] = (
         probe="probe_pallas_spchain",
         twin="peasoup_tpu.ops.singlepulse.boxcar_dec_best_twin",
         fallback=(
-            "retiled fused spans -> boxcar kernel + jnp dec-fold -> "
-            "jnp twin (pipeline.single_pulse.select_sp_kernels ladder)"
+            "jnp twin off TPU; the boxcar kernel + jnp dec-fold where "
+            "the fold does not fit the span (select_sp_kernels)"
         ),
         build=_build_spchain,
         scalar_prefetch=3,
-        retile_fallback=True,
+        lane_retile=True,
     ),
     KernelSpec(
         name="pallas.specchain",
@@ -341,7 +341,7 @@ _KERNELS: tuple[KernelSpec, ...] = (
         fallback="einsum four-step DFT + interbin kernel chain",
         build=_build_dftspec,
         scalar_prefetch=0,
-        retile_fallback=True,
+        lane_retile=True,
     ),
     KernelSpec(
         name="pallas.peaks",
@@ -367,9 +367,8 @@ _KERNELS: tuple[KernelSpec, ...] = (
         build=_build_harmpeaks,
         scalar_prefetch=0,
         # the MXU one-hot gather retiles its (SUB*K, BLOCK) dot output
-        # back to the (SUB, BLOCK) tile; the probe + conv+peaks path
-        # is the ladder that absorbs toolchains rejecting it
-        retile_fallback=True,
+        # back to the (SUB, BLOCK) tile
+        lane_retile=True,
     ),
 )
 

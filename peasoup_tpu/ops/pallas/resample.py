@@ -66,11 +66,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# toolchain compat: the memory-space enum was renamed TPUMemorySpace ->
-# MemorySpace (and gained an HBM member — older toolchains spell the
-# off-chip space ANY). The audit's kernel engine (PSK203) pins this.
-_MEMSPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-_HBM = getattr(_MEMSPACE, "HBM", _MEMSPACE.ANY)
 
 _MARGIN = 64  # head apron per padded row
 _SELECT_SPAN = 4  # distinct shift values handled per sub-block
@@ -171,7 +166,7 @@ def _build(d: int, a: int, n: int, blk: int, interpret: bool):
             # whole (D, A) table in SMEM: TPU lowering rejects (1, 1)
             # blocks; the kernel indexes it by program_id instead
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=_HBM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
         ],
         out_specs=pl.BlockSpec(
             # (8, blk) tile keeps the block tail TPU-compliant; the

@@ -17,10 +17,9 @@ Index math is the identical f32/i32 chain as the jnp twin
 strict-> running max, then first-max argmax via a lane-iota min — so
 outputs are BITWISE equal to it; the probe
 (ops.pallas.probe_pallas_spchain) gates on exactly that. The dec-fold
-retile of the (1, span) sweep into (span/dec, dec) sublane x lane form
-is the one feature beyond ops/pallas/boxcar.py's set, and Mosaic
-support for it varies by toolchain — which is precisely why the probe
-compiles and runs the real kernel before the driver may route to it.
+needs the (1, span) sweep as (span/dec, dec) blocks, a lane retile
+Mosaic refuses; the kernel gets there through a transpose instead
+(see _kernel), which tests/test_tpu_compile.py compiles for v5e.
 """
 
 from __future__ import annotations
@@ -34,6 +33,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _QUANT = 1024
+_LANES = 128
+
+
+def fold_fits(span: int, dec: int) -> bool:
+    """Whether the in-kernel dec-fold can tile ``span`` (see _kernel):
+    dec-blocks must sit whole inside a 128-lane row and span whole
+    sublane groups once transposed."""
+    return span % _LANES == 0 and _LANES % dec == 0 and dec % 8 == 0
 
 
 def _kernel(
@@ -41,9 +48,9 @@ def _kernel(
     scales_ref,  # (W,) f32 SMEM (scalar prefetch)
     nvalid_ref,  # (1,) i32 SMEM (scalar prefetch)
     csum_ref,  # flat (D * row_stride,) f32 HBM
-    bmax_ref,  # (1, span // dec) f32 VMEM out tile
-    barg_ref,  # (1, span // dec) i32 VMEM out tile (in-block argmax)
-    bw_ref,  # (1, span // dec) i32 VMEM out tile (width at argmax)
+    bmax_ref,  # (128 // dec, span // 128) f32 VMEM out tile
+    barg_ref,  # (128 // dec, span // 128) i32 (in-block argmax)
+    bw_ref,  # (128 // dec, span // 128) i32 (width at the argmax)
     win_ref,  # (span + wext,) f32 VMEM scratch
     sem,
     *,
@@ -82,22 +89,27 @@ def _kernel(
         best = jnp.where(better, snr, best)
         bw = jnp.where(better, jnp.int32(k), bw)
     # dec-fold on the resident tile: block max, FIRST-max argmax (the
-    # jnp twin's jnp.argmax semantics) via a lane-iota min, and the
-    # width index at that argmax via a one-hot sum
-    nbd = span // dec
-    blk = best.reshape(nbd, dec)
-    bw_blk = bw.reshape(nbd, dec)
-    bmax = jnp.max(blk, axis=1, keepdims=True)  # (nbd, 1)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (nbd, dec), 1)
+    # jnp twin's jnp.argmax semantics) via a sublane-iota min, and the
+    # width index at that argmax via a one-hot sum. Mosaic cannot
+    # retile the (1, span) sweep into (span/dec, dec) lanes, so the tile
+    # is transposed instead: (span/128, 128) -> (128, span/128) puts
+    # the dec samples of each block in dec consecutive sublanes of one
+    # column, and every fold is a sublane reduction. Out tile [k, r] is
+    # block 128/dec * r + k; the caller restores block order.
+    rows = span // _LANES
+    k = _LANES // dec
+    blk = best.reshape(rows, _LANES).T.reshape(k, dec, rows)
+    bw_blk = bw.reshape(rows, _LANES).T.reshape(k, dec, rows)
+    bmax = jnp.max(blk, axis=1, keepdims=True)  # (k, 1, rows)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (k, dec, rows), 1)
     barg = jnp.min(
-        jnp.where(blk == bmax, lane, jnp.int32(dec)), axis=1, keepdims=True
+        jnp.where(blk == bmax, sub, jnp.int32(dec)), axis=1, keepdims=True
     )
-    wsel = jnp.sum(
-        jnp.where(lane == barg, bw_blk, jnp.int32(0)), axis=1, keepdims=True
+    bmax_ref[...] = bmax[:, 0, :]
+    barg_ref[...] = barg[:, 0, :]
+    bw_ref[...] = jnp.sum(
+        jnp.where(sub == barg, bw_blk, jnp.int32(0)), axis=1
     )
-    bmax_ref[:] = bmax.reshape(-1)
-    barg_ref[:] = barg.reshape(-1)
-    bw_ref[:] = wsel.reshape(-1)
 
 
 @lru_cache(maxsize=None)
@@ -115,14 +127,14 @@ def _build(
         n_widths=n_widths,
         interpret=interpret,
     )
-    nbd = span // dec
+    tile = (_LANES // dec, span // _LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(d, tpad // span),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[
             pl.BlockSpec(
-                (None, nbd), lambda dd, gg, *_: (dd, gg),
+                (None, None, *tile), lambda dd, gg, *_: (dd, gg, 0, 0),
                 memory_space=pltpu.VMEM,
             )
             for _ in range(3)
@@ -136,9 +148,8 @@ def _build(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((d, tpad // dec), jnp.float32),
-            jax.ShapeDtypeStruct((d, tpad // dec), jnp.int32),
-            jax.ShapeDtypeStruct((d, tpad // dec), jnp.int32),
+            jax.ShapeDtypeStruct((d, tpad // span, *tile), dt)
+            for dt in (jnp.float32, jnp.int32, jnp.int32)
         ],
         interpret=interpret,
     )
@@ -159,12 +170,12 @@ def boxcar_dec_best_pallas(
     ops.singlepulse.boxcar_dec_best_twin. Returns (block max S/N
     (D, tpad/dec) f32, in-block argmax (D, tpad/dec) i32, width index
     at the argmax (D, tpad/dec) i32). ``span`` must divide ``tpad``
-    and ``dec`` must divide ``span``."""
+    and ``dec`` must satisfy :func:`fold_fits`."""
     d, row = csum_pad.shape
     wext = row - tpad
     if (
         tpad % span
-        or span % dec
+        or not fold_fits(span, dec)
         or row % _QUANT
         or wext <= int(max(widths))
     ):
@@ -173,9 +184,13 @@ def boxcar_dec_best_pallas(
             f"span={span} dec={dec} wext={wext} widths<={max(widths)}"
         )
     fn = _build(d, tpad, span, wext, dec, len(widths), interpret)
-    return fn(
+    outs = fn(
         jnp.asarray(widths, dtype=jnp.int32),
         jnp.asarray(scales, dtype=jnp.float32),
         jnp.asarray([nvalid], dtype=jnp.int32),
         csum_pad.reshape(-1),
+    )
+    # out tile [k, r] holds block 128/dec * r + k of its span
+    return tuple(
+        o.swapaxes(-1, -2).reshape(d, tpad // dec) for o in outs
     )

@@ -34,16 +34,10 @@ def _shard_map_nocheck(local_fn, mesh, in_specs, out_specs):
     # created inside (scan carries, iotas) start unvarying while the
     # delays are device-varying — the check would demand pvary casts
     # inside shared single-device code
-    try:
-        return jax.shard_map(
-            local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:  # older jax spells it check_rep
-        return jax.shard_map(
-            local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+    return jax.shard_map(
+        local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 @lru_cache(maxsize=None)

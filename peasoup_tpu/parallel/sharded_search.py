@@ -37,13 +37,16 @@ def make_sharded_search_fn(
     fused_interbin: bool = False,
     mega_harm: bool = False,
     fused_dft: bool = False,
+    fused_spec: bool = False,
 ):
     """Jitted (D, ...) -> (D, ...) search with D sharded over ``axis``.
 
     D must be a multiple of the mesh axis size (pad the trial block and
     the afs rows; padded rows are searched but discarded by the host).
-    Each chip runs the block-batched core on its local trials; with
-    ``pallas_block`` > 0 the Pallas resample kernel runs per chip.
+    Each chip runs the block-batched core on its local trials with the
+    same kernel flags as the one-chip search, so both give bitwise
+    equal peaks; with ``pallas_block`` > 0 the Pallas resample kernel
+    runs per chip.
     Cached (mesh/threshold/axis/block are hashable) so repeat runs reuse
     the compiled executable like make_batched_search_fn.
     """
@@ -56,7 +59,7 @@ def make_sharded_search_fn(
         "sharded_search_built", n_chips=int(mesh.shape[axis]), axis=axis,
         pallas_block=int(pallas_block), pallas_peaks=bool(pallas_peaks),
         mega_harm=bool(mega_harm), fused_dft=bool(fused_dft),
-        process_index=int(jax.process_index()),
+        fused_spec=bool(fused_spec), process_index=int(jax.process_index()),
     )
 
     @partial(
@@ -85,8 +88,11 @@ def make_sharded_search_fn(
                 pallas_block=pallas_block, select_smax=select_smax,
                 pallas_peaks=pallas_peaks, fused_interbin=fused_interbin,
                 mega_harm=mega_harm, fused_dft=fused_dft,
+                fused_spec=fused_spec,
             )
 
+        # check_vma off: the local body is collective-free, and the
+        # Pallas kernels in it declare outputs without a vma
         return jax.shard_map(
             local,
             mesh=mesh,
@@ -94,6 +100,7 @@ def make_sharded_search_fn(
             out_specs=AccelSearchPeaks(
                 idxs=P(axis), snrs=P(axis), counts=P(axis), ccounts=P(axis)
             ),
+            check_vma=False,
         )(tims, afs, zapmask, windows)
 
     return sharded_search

@@ -21,7 +21,7 @@ from .warmup import _sink_scope
 
 PERF_SCHEMA = "peasoup_tpu.perf"
 # v2: per-program "stage" + top-level "stages" totals (the roofline
-# taxonomy shared with BENCH, perf/roofline.py) and the resolved
+# classification shared with BENCH, perf/roofline.py) and the resolved
 # "dedisp" alternative record
 PERF_VERSION = 2
 
@@ -148,7 +148,7 @@ def run_microbench(
         rec["stage"] = stage_for_program(spec.name)
         recs[spec.name] = rec
     ok = [r for r in recs.values() if not r["error"]]
-    # per-stage execute totals: the same taxonomy BENCH's device trace
+    # per-stage execute totals: the same classification BENCH's device trace
     # uses (perf/roofline.py STAGES), so a ratchet regression and a
     # BENCH round name the same bucket
     stages: dict = {}

@@ -1,6 +1,6 @@
 """Per-stage roofline accounting shared by BENCH and the microbench.
 
-One stage taxonomy — ``unpack / dedisperse / spectrum_chain / resample
+One stage classification — ``unpack / dedisperse / spectrum_chain / resample
 / harmonics / peaks / fold / other`` — classifies BOTH the profiler
 trace's device events (tools/scope_trace stage_profile, driven by the
 jit names and named scopes the drivers emit) and the registry's

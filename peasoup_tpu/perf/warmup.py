@@ -314,18 +314,14 @@ def shape_ctx_for_bucket(bucket, pipeline: str, overrides: dict):
             dm_block = int(
                 max(1, min(256, (search.TOTAL_HBM // 4) // max(1, per_trial)))
             )
-        if cfg.use_pallas:
-            # THE driver's kernel-selection ladder (fused chain at the
-            # full span, retiled fused variants, boxcar kernel, jnp
-            # twin) so warmup compiles exactly what the job dispatches
-            try:
-                from ..pipeline.single_pulse import select_sp_kernels
+        # the driver's kernel selection (fused chain, boxcar kernel,
+        # jnp twin) so warmup compiles exactly what the job dispatches;
+        # on a TPU a kernel that fails its probe raises here too
+        from ..pipeline.single_pulse import select_sp_kernels
 
-                pallas_span, sp_fused_span, _ = select_sp_kernels(
-                    widths, span, tpad, cfg.decimate, cfg.use_pallas
-                )
-            except Exception:
-                pallas_span = sp_fused_span = 0
+        pallas_span, sp_fused_span = select_sp_kernels(
+            widths, span, cfg.decimate, cfg.use_pallas
+        )
     elif pipeline == "search":
         import numpy as np
 

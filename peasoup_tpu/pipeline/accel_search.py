@@ -392,7 +392,7 @@ def search_block_core(
     routes the once-per-trial deredden -> zap -> interbin tail through
     the fused Pallas pass (probe-gated by the caller).
     """
-    # named scopes mirror the roofline stage taxonomy
+    # named scopes mirror the roofline stage classification
     # (tools/scope_trace STAGE_RULES), so profiler traces attribute
     # this one jitted program's device time per stage
     with jax.named_scope("Spectrum-Chain"):

@@ -12,10 +12,10 @@ per DM trial, correlated against the (f-dot, f-ddot) template bank
 compile covers the whole run.
 
 OOM degradation: template rows are independent, so halving the
-template batch is bitwise-neutral — that is the FIRST ladder rung;
-halving the DM block (vmap rows, equally independent) is the second.
-Both shrink paths reproduce the untroubled run's candidates exactly
-(tests/test_fdas.py pins the bitwise invariance).
+template batch leaves the peak sets bitwise equal — that is the FIRST
+ladder rung; halving the DM block (vmap rows, equally independent) is
+the second. Both shrink paths reproduce the untroubled run's candidates
+exactly (tests/test_fdas.py pins the bitwise invariance).
 """
 
 from __future__ import annotations

@@ -34,12 +34,14 @@ from ..ops.dedisperse import (
     fil_to_device,
     output_scale,
 )
+from ..ops.pallas import backend_supports_pallas
 from ..ops.resample import accel_factor, select_span
 from ..ops.zap import birdie_mask
 from ..plan.accel_plan import AccelerationPlan
 from ..plan.dm_plan import DMPlan
 from ..plan.fft_plan import choose_fft_size
 from ..utils import ProgressBar, trace_span
+from ..utils.device import device_bytes_limit
 from .accel_search import make_batched_search_fn
 from .checkpoint import SearchCheckpoint
 from .distill import AccelerationDistiller, DMDistiller, HarmonicDistiller
@@ -191,7 +193,7 @@ def _level_windows(
 
 
 def _is_oom(exc: Exception) -> bool:
-    """Device out-of-memory signature — now the shared taxonomy's
+    """Device out-of-memory signature — now the shared classification's
     :func:`peasoup_tpu.resilience.errors.is_resource_exhausted`
     (kept as a module function: the single-pulse driver and tests
     import it from here, and its contract is pinned against the real
@@ -411,7 +413,7 @@ class PeasoupSearch:
     # device-resident trials), the cap on live peak-output buffers
     # queued per dispatch wave, and the trials size beyond which the
     # trial block spills to host RAM instead of living in HBM
-    TOTAL_HBM = 12_000_000_000  # fallback when the device reports no limit
+    TOTAL_HBM = 12_000_000_000  # off-TPU default (the CPU test mesh)
     MEM_BUDGET = 6_000_000_000
     WAVE_BUDGET = 1_000_000_000
     TRIALS_DEVICE_LIMIT = 4_000_000_000
@@ -430,18 +432,14 @@ class PeasoupSearch:
         # transfer; chunks whose true total exceeds it pay a second
         # exact-size fetch and raise the speculation for later waves
         self._learned_total_pad = 4096
-        # size budgets from the real chip when it tells us (memory_stats
-        # is absent on some backends, e.g. the CPU mesh in tests)
-
-        devs = jax.local_devices()
+        # size budgets from the real chip (memory_stats is absent on
+        # some backends, e.g. the CPU mesh in tests, which keep the
+        # class defaults; a TPU must report its size or be given one)
         limit = config.hbm_bytes or int(
             os.environ.get("PEASOUP_HBM_BYTES", 0) or 0
         )
         if not limit:
-            try:
-                limit = (devs[0].memory_stats() or {}).get("bytes_limit", 0)
-            except Exception:
-                limit = 0
+            limit = device_bytes_limit()
         if limit:
             self.TOTAL_HBM = int(limit)
             self.MEM_BUDGET = int(limit) // 2
@@ -542,9 +540,8 @@ class PeasoupSearch:
         # explicit --subbands is an operator decision the planner
         # respects; otherwise resolve exact-vs-subband + tuned shape
         # knobs from the per-device tuning cache (warm buckets load
-        # with zero measurement calls). Failures degrade to the
-        # config's manual knobs — planning is an optimisation, never a
-        # correctness dependency.
+        # with zero measurement calls). A planning failure stops the
+        # run rather than quietly searching with other knobs.
         subbands = cfg.subbands
         subband_smear = cfg.subband_smear
         dedisp_block = cfg.dedisp_block
@@ -554,15 +551,11 @@ class PeasoupSearch:
         self._tuned_dm_block = 0
         self._tuned_accel_bucket = 0
         if cfg.tune and cfg.subbands == 0 and not cfg.dedisp_engine:
-            try:
-                from ..perf.tuning import resolve_plan_for_filterbank
+            from ..perf.tuning import resolve_plan_for_filterbank
 
-                dplan = resolve_plan_for_filterbank(
-                    fil, "search", cfg, cache_path=cfg.tuning_cache or None
-                )
-            except Exception as exc:
-                log.warning("dedispersion planning failed: %.200s", exc)
-                dplan = None
+            dplan = resolve_plan_for_filterbank(
+                fil, "search", cfg, cache_path=cfg.tuning_cache or None
+            )
             if dplan is not None:
                 if dplan.engine == "subband":
                     subbands = dplan.subbands
@@ -857,16 +850,18 @@ class PeasoupSearch:
         # program and beats even the Pallas kernel (which still streams
         # a separate pass over HBM)
         select_smax = select_span(af_max, size)
-        pallas_block = 0
-        if cfg.use_pallas and not 0 < select_smax <= 8:
-            from ..ops.pallas import probe_pallas_resample
-            from ..ops.pallas.resample import choose_block
+        from ..ops.pallas.resample import choose_block
 
+        resample_fits = not 0 < select_smax <= 8 and choose_block(
+            af_max, size
+        ) > 0
+        pallas_block = 0
+        if cfg.use_pallas and resample_fits:
+            from ..ops.pallas import probe_pallas_resample
+
+            # real compile+run probe, oracle-checked (off TPU: the twin)
             pallas_block = choose_block(af_max, size)
-            # real compile+run probe, oracle-checked: degrade to the
-            # jnp twin instead of crashing (or silently corrupting) on
-            # Mosaic toolchains that mis-handle this kernel
-            if pallas_block and not probe_pallas_resample(size, pallas_block):
+            if not probe_pallas_resample(size, pallas_block):
                 pallas_block = 0
         # fused threshold+compact+cluster kernel: output is cluster
         # peaks, so overflow means cluster count > max_peaks (rare)
@@ -889,17 +884,19 @@ class PeasoupSearch:
         # path (its output is pre-padded to PEAKS_BLOCK), a pow2 size
         # whose half divides the block, and the bitwise oracle probe.
         # PEASOUP_FUSED_FFT=0 restores the stock XLA FFT chain.
+        from ..ops.fft import _MIN_N
+        from ..ops.pallas.peaks import PEAKS_BLOCK
+
+        interbin_fits = (
+            size >= _MIN_N
+            and not (size & (size - 1))
+            and (size // 2) % PEAKS_BLOCK == 0
+        )
         fused_interbin = False
         if pallas_peaks and os.environ.get("PEASOUP_FUSED_FFT", "1") != "0":
-            from ..ops.fft import _MIN_N
             from ..ops.pallas import probe_pallas_interbin
-            from ..ops.pallas.peaks import PEAKS_BLOCK
 
-            if (
-                size >= _MIN_N
-                and not (size & (size - 1))
-                and (size // 2) % PEAKS_BLOCK == 0
-            ):
+            if interbin_fits:
                 fused_interbin = probe_pallas_interbin(size, PEAKS_BLOCK)
         self._fused_interbin = fused_interbin
         # harmonic+peaks mega-kernel (ops/pallas/harmpeaks.py): fuses
@@ -924,23 +921,18 @@ class PeasoupSearch:
         # accuracy, gated by probe_pallas_dftspec's two-layer oracle
         # (per-bin envelope vs the contraction-exact twin + the
         # documented accuracy-class bound vs the HIGHEST chain);
-        # shape-gated here so survey-scale m falls back to the einsum
-        # chain instead of raising at trace time. PEASOUP_FUSED_DFT=0
+        # shape-gated here so survey-scale m takes the einsum chain
+        # instead of raising at trace time. PEASOUP_FUSED_DFT=0
         # restores the einsum + interbin-kernel chain (exact HIGHEST).
-        # RESIDUAL RISK, shared with the peaks/harmpeaks probes at
-        # escalated shapes: this probe compiles a Mosaic kernel
-        # in-process at the production (n, npad); a toolchain that
-        # SIGABRTs (rather than raising) on a bad compile kills the
-        # process here instead of degrading — the env kill switch is
-        # the documented escape hatch on such toolchains.
+        from ..ops.pallas.dftspec import dftspec_supported
+
+        npad_spec = -(-size_spec // PEAKS_BLOCK) * PEAKS_BLOCK
+        dftspec_fits = dftspec_supported(size, npad_spec)
         fused_dft = False
         if fused_interbin and os.environ.get("PEASOUP_FUSED_DFT", "1") != "0":
             from ..ops.pallas import probe_pallas_dftspec
-            from ..ops.pallas.dftspec import dftspec_supported
-            from ..ops.pallas.peaks import PEAKS_BLOCK
 
-            npad_spec = -(-size_spec // PEAKS_BLOCK) * PEAKS_BLOCK
-            if dftspec_supported(size, npad_spec):
+            if dftspec_fits:
                 fused_dft = probe_pallas_dftspec(size, npad_spec)
         self._fused_dft = fused_dft
         # fused once-per-trial spectrum chain (ops/pallas/specchain.py):
@@ -954,6 +946,14 @@ class PeasoupSearch:
 
             fused_spec = probe_pallas_specchain()
         self._fused_spec = fused_spec
+        # which kernels the shapes admit, beside the ones the search
+        # programs were built with (the route event after the waves)
+        self._route_fits = {
+            "interbin_fits": bool(interbin_fits),
+            "dftspec_fits": bool(dftspec_fits),
+            "select_smax": int(select_smax),
+            "resample_fits": bool(resample_fits),
+        }
 
         # --- search-side mesh wiring (mesh chosen before dedispersion) --
         if mesh is not None:
@@ -968,6 +968,7 @@ class PeasoupSearch:
                     pallas_peaks=pp, fused_interbin=fused_interbin and pp,
                     mega_harm=self._mega_harm and pp,
                     fused_dft=self._fused_dft and pp,
+                    fused_spec=self._fused_spec,
                 )
 
             # stage blocks directly onto the mesh (no hop through chip 0)
@@ -1118,9 +1119,9 @@ class PeasoupSearch:
         # stepped repeatedly; at the floor the run falls THROUGH —
         # first to an exact (max_smear=0, bitwise-equal) subband
         # dedispersion with host-spilled trials, freeing the
-        # device-resident trial block, then to the CPU backend (host
-        # RAM dwarfs HBM; slow beats dead). Exhaustion below the CPU
-        # rung propagates to the campaign attempt budget.
+        # device-resident trial block, then (off TPU only: a TPU run
+        # never leaves its chip) to the CPU backend. Exhaustion
+        # propagates to the campaign attempt budget.
         ladder = DegradationLadder(
             "search.memory", ("dm_block_shrink", "subband", "cpu_backend")
         )
@@ -1237,7 +1238,7 @@ class PeasoupSearch:
                         "subband", nsub=nsub, error=f"{exc!s:.200}"
                     )
                     continue
-                if not cpu_mode:
+                if not cpu_mode and not backend_supports_pallas():
                     # CPU rung: host-resident trials, single-device jnp
                     # programs (the Pallas kernels and the mesh are
                     # device-side optimisations, both bitwise-gated);
@@ -1286,6 +1287,16 @@ class PeasoupSearch:
             progress.stop()
         timers["search_device"] = time.perf_counter() - t0
         tel.capture_device_memory("search")
+        tel.event(
+            "search_route", backend=jax.default_backend(),
+            pallas_peaks=bool(self._pallas_peaks),
+            mega_harm=bool(self._mega_harm),
+            fused_interbin=bool(self._fused_interbin),
+            fused_dft=bool(self._fused_dft),
+            fused_spec=bool(self._fused_spec),
+            resample_block=int(self._cur_pallas_block),
+            **self._route_fits,
+        )
 
         # --- host candidate bookkeeping (ascending DM order) ----------------
         # idxs/snrs arrive ALREADY clustered (identify_unique_peaks ran
@@ -1452,54 +1463,11 @@ class PeasoupSearch:
                 with job_span(
                     "wave", wave=wi, chunks=len(todo),
                 ), trace_span("DM-Loop"):  # NVTX parity: pipeline_multi.cu:144
-                    try:
-                        self._search_wave(
-                            todo, dispatch_lists, trials, tim_len, zapmask_dev,
-                            windows, self._active_search_block,
-                            per_dm_results, **disp,
-                        )
-                    except Exception as exc:
-                        # the oracle probe runs at a reduced shape; if
-                        # the Pallas kernel still fails at the full
-                        # production shape (e.g. SMEM accel-table
-                        # pressure — reported as RESOURCE_EXHAUSTED
-                        # like a plain HBM OOM), fall back to the jnp
-                        # resample and redo the wave. A true HBM OOM
-                        # repeats on the retry below, whose exception
-                        # is unwrapped and reaches the outer
-                        # shrink-retry; only with no Pallas active is
-                        # an error re-raised immediately
-                        if self._cur_pallas_block == 0:
-                            raise
-                        log.warning(
-                            "search wave failed with the Pallas resample "
-                            "enabled (%r); retrying without Pallas", exc,
-                        )
-                        current_telemetry().event(
-                            "pallas_resample_disabled",
-                            pallas_block=self._cur_pallas_block,
-                            error=f"{exc!r:.200}",
-                        )
-                        # ladder bookkeeping: Pallas kernel -> jnp twin
-                        # is an ordered, observable degradation too
-                        from ..resilience import DegradationLadder
-
-                        DegradationLadder(
-                            "search.pallas", ("jnp_twin",)
-                        ).step(
-                            "jnp_twin",
-                            pallas_block=self._cur_pallas_block,
-                            error=f"{exc!r:.200}",
-                        )
-                        self._cur_pallas_block = 0
-                        self._active_search_block = build_search(
-                            0, getattr(self, "_pallas_peaks", False)
-                        )
-                        self._search_wave(
-                            todo, dispatch_lists, trials, tim_len, zapmask_dev,
-                            windows, self._active_search_block,
-                            per_dm_results, **disp,
-                        )
+                    self._search_wave(
+                        todo, dispatch_lists, trials, tim_len, zapmask_dev,
+                        windows, self._active_search_block,
+                        per_dm_results, **disp,
+                    )
                 if ckpt is not None:
                     with job_span("checkpoint", wave=wi):
                         ckpt.save(per_dm_results)
@@ -1914,49 +1882,28 @@ class PeasoupSearch:
                     new=int(max_peaks), observed=int(ov.max()),
                 )
                 # the redispatch below runs on the CURRENT active search
-                # block, which an earlier chunk's escalation may have
-                # degraded after this chunk was dispatched — resync the
-                # entry-local flag so the overflow semantics (raw counts
-                # for the jnp path, cluster counts for the kernels) and
-                # the probe gate match the block actually used
+                # block: resync the entry-local flag so the overflow
+                # semantics (raw counts for the jnp path, cluster counts
+                # for the kernels) match the block actually used
                 fused = getattr(self, "_pallas_peaks", False)
                 if fused:
-                    # the kernels were only oracle-probed at the startup
-                    # compaction size; re-probe the escalated shape and
-                    # degrade (mega-kernel -> conv+peaks -> jnp) rather
-                    # than running an unvalidated kernel
+                    # the kernels were probed at the startup compaction
+                    # size: probe the escalated shape too (on a TPU a
+                    # failure raises, naming the kernel)
                     from ..ops.pallas import (
                         probe_pallas_harmpeaks, probe_pallas_peaks,
                     )
 
-                    mega_was = getattr(self, "_mega_harm", False)
-                    if mega_was and not probe_pallas_harmpeaks(
-                        self._peaks_probe_nbins, self._peaks_probe_nlev - 1,
-                        max_peaks,
-                    ):
-                        self._mega_harm = False
-                        current_telemetry().event(
-                            "mega_harm_disabled", max_peaks=int(max_peaks)
+                    if getattr(self, "_mega_harm", False):
+                        probe_pallas_harmpeaks(
+                            self._peaks_probe_nbins,
+                            self._peaks_probe_nlev - 1, max_peaks,
                         )
-                    if not getattr(
-                        self, "_mega_harm", False
-                    ) and not probe_pallas_peaks(
-                        self._peaks_probe_nbins, self._peaks_probe_nlev,
-                        max_peaks,
-                    ):
-                        fused = False
-                        self._pallas_peaks = False
-                        current_telemetry().event(
-                            "pallas_peaks_disabled", max_peaks=int(max_peaks)
+                    else:
+                        probe_pallas_peaks(
+                            self._peaks_probe_nbins,
+                            self._peaks_probe_nlev, max_peaks,
                         )
-                    if not fused or mega_was != getattr(
-                        self, "_mega_harm", False
-                    ):
-                        search_block = self._build_search(
-                            self._cur_pallas_block, fused
-                        )
-                        self._active_search_block = search_block
-                        args = args[:5] + (search_block,)
                 peaks, padded = self._dispatch_chunk(
                     chunk, *args, max_peaks, **disp
                 )

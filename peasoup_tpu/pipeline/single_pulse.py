@@ -227,52 +227,28 @@ def candidates_from_clusters(
 def select_sp_kernels(
     widths: tuple[int, ...],
     span: int,
-    tpad: int,
     decimate: int,
     use_pallas: bool,
-) -> tuple[int, int, str | None]:
+) -> tuple[int, int]:
     """Resolve the single-pulse device-kernel route: ``(pallas_span,
-    fused_span, fallback_rung)``, preferring the fused sweep+dec-fold
-    chain (ops/pallas/spchain.py) at the full tile span, then — when
-    the toolchain probe rejects its (span/dec, dec) retile — RETILED
-    fused variants at successively halved spans (the reshape that
-    Mosaic refuses at one tile geometry is often fine at a smaller
-    one; dec-fold semantics are span-independent, so the bitwise
-    oracle still gates each candidate), then the plain boxcar kernel,
-    then the jnp twin. All routes are bitwise-identical by the probe
-    contract; the rung is a *performance* degradation only.
-
-    ``fallback_rung`` names the resilience degradation rung taken
-    (None when the preferred kernel probed clean — or when the backend
-    has no Pallas support at all, where the twin is the design point,
-    not a degradation)."""
+    fused_span)``. The fused sweep+dec-fold chain
+    (ops/pallas/spchain.py) runs when its dec-fold can tile the span
+    (``fold_fits``), the plain boxcar kernel otherwise, and ``(0, 0)``
+    selects the jnp twin on backends without Pallas. On a TPU a kernel
+    that fails its compile+run probe raises (ops.pallas
+    KernelUnavailable) instead of dropping to a slower route."""
     if not use_pallas or span <= 0:
-        return 0, 0, None
-    from ..ops.pallas import (
-        backend_supports_pallas,
-        probe_pallas_boxcar,
-        probe_pallas_spchain,
-    )
-    from ..ops.singlepulse import _QUANT
+        return 0, 0
+    from ..ops.pallas import probe_pallas_boxcar, probe_pallas_spchain
+    from ..ops.pallas.spchain import fold_fits
 
-    if span % decimate == 0 and probe_pallas_spchain(
+    if fold_fits(span, decimate) and probe_pallas_spchain(
         len(widths), span, decimate
     ):
-        return 0, span, None
-    expected = backend_supports_pallas()
-    if expected and decimate > 0 and span % decimate == 0:
-        s = span // 2
-        while s >= max(decimate, _QUANT) and s % _QUANT == 0:
-            if (
-                s % decimate == 0
-                and tpad % s == 0
-                and probe_pallas_spchain(len(widths), s, decimate)
-            ):
-                return 0, s, "spchain_retile"
-            s //= 2
+        return 0, span
     if probe_pallas_boxcar(len(widths), span):
-        return span, 0, "boxcar_kernel" if expected else None
-    return 0, 0, "jnp_twin" if expected else None
+        return span, 0
+    return 0, 0
 
 
 def make_checkpoint_key(
@@ -310,15 +286,13 @@ class SinglePulseSearch:
         self.config = config
         import os
 
-        devs = jax.local_devices()
+        from ..utils.device import device_bytes_limit
+
         limit = config.hbm_bytes or int(
             os.environ.get("PEASOUP_HBM_BYTES", 0) or 0
         )
         if not limit:
-            try:
-                limit = (devs[0].memory_stats() or {}).get("bytes_limit", 0)
-            except Exception:
-                limit = 0
+            limit = device_bytes_limit()
         if limit:
             self.TOTAL_HBM = int(limit)
             self.TRIALS_DEVICE_LIMIT = int(limit) // 3
@@ -434,16 +408,12 @@ class SinglePulseSearch:
         # --- auto-tuned dedispersion shape knobs -----------------------
         dedisp_block = cfg.dedisp_block
         if cfg.tune:
-            try:
-                from ..perf.tuning import resolve_plan_for_filterbank
+            # a planning failure stops the run (as in PeasoupSearch)
+            from ..perf.tuning import resolve_plan_for_filterbank
 
-                dplan = resolve_plan_for_filterbank(
-                    fil, "spsearch", cfg,
-                    cache_path=cfg.tuning_cache or None,
-                )
-            except Exception as exc:
-                log.warning("dedispersion tuning failed: %.200s", exc)
-                dplan = None
+            dplan = resolve_plan_for_filterbank(
+                fil, "spsearch", cfg, cache_path=cfg.tuning_cache or None,
+            )
             if dplan is not None:
                 dedisp_block = dplan.dedisp_block or dedisp_block
                 tel.event("dedisp_plan", **dplan.summary())
@@ -484,36 +454,20 @@ class SinglePulseSearch:
                     and 4 * fil.nsamps * fil.nchans < 3_000_000_000
                 )
                 if shard_dd:
-                    try:
-                        from ..parallel.sharded_dedisperse import (
-                            dedisperse_sharded,
-                        )
+                    from ..parallel.sharded_dedisperse import (
+                        dedisperse_sharded,
+                    )
 
-                        trials = dedisperse_sharded(
-                            fil_to_device(fil),
-                            dm_plan.delay_samples(),
-                            dm_plan.killmask,
-                            dm_plan.out_nsamps,
-                            mesh,
-                            scale=scale,
-                            block=dedisp_block,
-                        )
-                        jax.block_until_ready(trials)
-                    except Exception as exc:
-                        # shard_map availability varies by jax release;
-                        # a single-device dedispersion is always correct
-                        # (the search blocks re-shard onto the mesh)
-                        log.warning(
-                            "sharded dedispersion unavailable (%.200s); "
-                            "falling back to the single-device engine",
-                            exc,
-                        )
-                        tel.event(
-                            "sp_sharded_dedisp_fallback",
-                            error=f"{exc!s:.200}",
-                        )
-                        shard_dd = False
-                if not shard_dd:
+                    trials = dedisperse_sharded(
+                        fil_to_device(fil),
+                        dm_plan.delay_samples(),
+                        dm_plan.killmask,
+                        dm_plan.out_nsamps,
+                        mesh,
+                        scale=scale,
+                        block=dedisp_block,
+                    )
+                else:
                     dd = dedisperse if spill else dedisperse_device
                     trials = dd(
                         fil.data if spill else fil_to_device(fil),
@@ -526,9 +480,7 @@ class SinglePulseSearch:
                 if not spill:
                     # async dispatch (mirrors pipeline/search.py): the
                     # first boxcar waves overlap the dedispersion tail;
-                    # PEASOUP_SYNC_DEDISP=1 restores the barrier. The
-                    # sharded path above keeps its own sync — it gates
-                    # the shard_map-availability fallback.
+                    # PEASOUP_SYNC_DEDISP=1 restores the barrier
                     import os as _os
 
                     if _os.environ.get("PEASOUP_SYNC_DEDISP"):
@@ -546,32 +498,19 @@ class SinglePulseSearch:
         tel.set_stage("searching")
         nsamps = dm_plan.out_nsamps
         tpad, span = plan_pad(nsamps)
-        # prefer the fused sweep+dec-fold mega-kernel (the best planes
-        # never round-trip HBM at full resolution); when its retile
-        # probe rejects the full span, try retiled spans, then the
-        # plain boxcar kernel, then the jnp twin — all bitwise
-        # identical, so a fallback rung is a logged performance
-        # degradation, never a correctness event
-        pallas_span, fused_span, rung = select_sp_kernels(
-            widths, span, tpad, cfg.decimate, cfg.use_pallas
+        pallas_span, fused_span = select_sp_kernels(
+            widths, span, cfg.decimate, cfg.use_pallas
         )
-        if rung is not None:
-            from ..resilience import DegradationLadder
-
-            DegradationLadder(
-                "spsearch.kernel",
-                ("spchain_retile", "boxcar_kernel", "jnp_twin"),
-            ).step(
-                rung, span=int(span), fused_span=int(fused_span),
-                pallas_span=int(pallas_span), decimate=int(cfg.decimate),
-            )
-            log.warning(
-                "fused spchain kernel rejected at span=%d; degraded to "
-                "rung %s (fused_span=%d, pallas_span=%d)",
-                span, rung, fused_span, pallas_span,
-            )
         self._pallas_span = pallas_span
         self._fused_span = fused_span
+        from ..ops.pallas.spchain import fold_fits
+
+        tel.event(
+            "sp_route", backend=jax.default_backend(),
+            fused_span=int(fused_span), pallas_span=int(pallas_span),
+            span=int(span), decimate=int(cfg.decimate),
+            fold_fits=bool(fold_fits(span, cfg.decimate)),
+        )
         sharding = None
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec

@@ -1,4 +1,4 @@
-"""Unified resilience layer: error taxonomy, retry/degradation policy,
+"""Unified resilience layer: error classification, retry/degradation policy,
 corrupt-artifact recovery, deterministic fault injection, and the
 process-global ``resilience`` status accounting.
 

@@ -1,4 +1,4 @@
-"""The error taxonomy every recovery decision routes through.
+"""The error classification every recovery decision routes through.
 
 Survey pipelines that run unattended for months (the GSP/CRAFTS and
 FAST drift-scan operations, arXiv:2110.12749 / 1912.12807) survive by
@@ -153,7 +153,7 @@ def is_transient(exc: BaseException) -> bool:
 
 
 def classify(exc: BaseException) -> str:
-    """Map an exception to its taxonomy class. Order matters: the
+    """Map an exception to its error class. Order matters: the
     resource_exhausted check runs first because jax wraps OOM in the
     same type it uses for everything else."""
     if is_resource_exhausted(exc):

@@ -60,7 +60,7 @@ def _tel():
 class RetryPolicy:
     """Bounded retry with exponential backoff + deterministic jitter.
 
-    ``retry_on`` lists the taxonomy classes worth retrying (transient
+    ``retry_on`` lists the error classes worth retrying (transient
     only, by default: retrying an OOM at the same shape just OOMs
     again, and corrupt artifacts have their own recovery). The jitter
     is seeded from (site, attempt) so two identical runs sleep

@@ -48,7 +48,7 @@ class ResilienceStats:
             tab = self._tables[table]
             tab[key] = tab.get(key, 0) + by
 
-    # --- recording (one verb per taxonomy outcome) --------------------
+    # --- recording (one verb per error-class outcome) ----------------------
     def retry(self, site: str) -> None:
         self._incr("retries", site)
 

@@ -652,12 +652,12 @@ def run_fleet_soak(
     )
     logs_dir = os.path.join(workdir, "fleet_logs")
     os.makedirs(logs_dir, exist_ok=True)
-    # one shared persistent compilation cache: the first worker pays
-    # the compiles, every later worker (and the late joiner) cold-starts
-    # warm — fleet wall time stays minutes, not hours
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
-        workdir, "xla_cache"
-    )
+    # one shared persistent compilation cache, placed by the package's
+    # rule: the first worker pays the compiles, every later worker (and
+    # the late joiner) cold-starts warm — fleet wall time stays minutes
+    from ..utils.cache import default_cache_dir
+
+    cache_dir = default_cache_dir()
 
     procs: dict[str, dict] = {}
 

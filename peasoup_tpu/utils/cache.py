@@ -1,32 +1,37 @@
 """Persistent XLA compilation cache wiring.
 
-At survey scale a fresh process pays minutes of XLA compiles (~70 s per
-subband-stage shape, ~30 s for the fold phase at 2^21 samples —
-NOTES.md); the persistent cache amortises them across processes. Every
-entry point (the CLIs via apply_platform_env, bench.py) calls
-:func:`enable_compilation_cache` before building programs.
-``JAX_COMPILATION_CACHE_DIR`` overrides the location."""
+At survey scale a fresh process pays minutes of XLA compiles; the
+persistent cache amortises them across processes. Every entry point
+(the CLIs via apply_platform_env, bench.py, chip_smoke.py, the test
+suite's conftest) calls :func:`enable_compilation_cache` before building
+programs.
+
+Where the cache lives is the caller's choice: ``JAX_COMPILATION_CACHE_DIR``
+when it is set, otherwise one fixed directory inside the checkout
+(``<repo>/.jax_cache``, git-ignored). The path is part of every entry's
+key, so it is never built from a temp name, a pid or the time."""
 
 from __future__ import annotations
 
 import os
 
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
 
 def default_cache_dir() -> str:
-    return os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(
-            os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-            "peasoup_tpu", "jax",
-        ),
-    )
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or REPO_CACHE_DIR
 
 
 def enable_compilation_cache() -> str | None:
     """Point jax at the persistent on-disk compilation cache and return
-    its path (None when it could not be enabled). Safe to call
-    repeatedly, before or after backend init; failures are non-fatal
-    (an uncached run is just slower)."""
+    its path (None when it could not be enabled). With
+    ``JAX_COMPILATION_CACHE_DIR`` set, that directory is the one jax
+    uses; the call only re-reads it, in case it changed after jax was
+    imported. Safe to call repeatedly, before or after backend init;
+    failures are non-fatal (an uncached run is just slower)."""
     cache = default_cache_dir()
     try:
         os.makedirs(cache, exist_ok=True)
@@ -40,7 +45,7 @@ def enable_compilation_cache() -> str | None:
                 "jax_persistent_cache_min_compile_time_secs", 0.0
             )
         return cache
-    except Exception:  # read-only home etc.: run without the cache
+    except Exception:  # read-only checkout etc.: run without the cache
         return None
 
 

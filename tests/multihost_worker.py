@@ -27,18 +27,11 @@ if "xla_force_host_platform_device_count" not in flags:
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    cache = os.path.join(
-        os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-        "peasoup_tpu", "jax-tests",
-    )
-    os.makedirs(cache, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-except Exception:
-    pass
-
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+from peasoup_tpu.utils.cache import enable_compilation_cache  # noqa: E402
+
+enable_compilation_cache()
 
 
 def main() -> int:

@@ -12,10 +12,10 @@ The injection recipes are the SAME conventions the device code claims:
   ``phi(u) = b0*u + z*u^2/2 + w*u^3/6`` (u = t/T), so a detection at
   trial (z, w) proves the bank's sign/centre conventions end to end.
 
-The halving tests pin the OOM ladder's contract: any template-batch
-split of the bank is BITWISE-identical to the unsplit dispatch
-(ops/fdas.py pads the FFT row batch to _ROW_ALIGN so the backend's
-vector-remainder path never sees a data row).
+The halving tests pin the OOM ladder's contract: the search program's
+peak sets are BITWISE-identical under any template-batch split, while
+correlate_bank's raw output across batch shapes agrees to f32 rounding
+(XLA's CPU FFT rounds per batch shape).
 """
 
 import os
@@ -189,35 +189,41 @@ class TestCorrelateBank:
         np.testing.assert_allclose(out, direct, rtol=2e-4, atol=2e-4)
 
     def test_row_split_bitwise(self):
-        """Any row-batch split of the bank is bitwise-identical to the
-        unsplit call — the invariant the OOM ladder's template-batch
-        halving rung relies on."""
+        """Each template row's output depends on that row alone: at one
+        batch shape, changing every other row leaves it bitwise equal.
+        A split of the bank changes the batch shape, and XLA's CPU FFT
+        (JAX 0.9) rounds per batch shape, so a split agrees with the
+        unsplit call to f32 rounding (measured 1.8e-7 of the peak), not
+        bitwise. The search program's peak sets stay bitwise under the
+        OOM ladder's template-batch halving (next test)."""
         import jax.numpy as jnp
 
         from peasoup_tpu.ops.fdas import correlate_bank
 
         rng = np.random.default_rng(0)
         nbins = 2049
-        fser = (
+        fser = jnp.asarray((
             rng.standard_normal(nbins) + 1j * rng.standard_normal(nbins)
-        ).astype(np.complex64)
+        ).astype(np.complex64))
         bank = build_template_bank(16.0)
         tmpl = np.asarray(bank.templates)
         seg = auto_segment(bank.templates.shape[1])
-        full = np.asarray(
-            correlate_bank(jnp.asarray(fser), jnp.asarray(tmpl), segment=seg)
-        )
+        full = np.asarray(correlate_bank(fser, jnp.asarray(tmpl), segment=seg))
+        others = tmpl[::-1].copy()
+        others[3] = tmpl[3]
+        row3 = np.asarray(
+            correlate_bank(fser, jnp.asarray(others), segment=seg)
+        )[3]
+        assert np.array_equal(full[3].view(np.float32), row3.view(np.float32))
+        peak = np.abs(full).max()
         for at in (1, 5, 9):
-            parts = [
-                np.asarray(correlate_bank(
-                    jnp.asarray(fser), jnp.asarray(t), segment=seg
-                ))
+            split = np.concatenate([
+                np.asarray(correlate_bank(fser, jnp.asarray(t), segment=seg))
                 for t in (tmpl[:at], tmpl[at:])
-            ]
-            split = np.concatenate(parts, axis=0)
-            assert np.array_equal(
-                full.view(np.float32), split.view(np.float32)
-            ), f"split at {at} not bitwise"
+            ])
+            assert np.abs(split - full).max() <= 1e-6 * peak, (
+                f"split at {at} beyond f32 rounding"
+            )
 
     def test_program_bitwise_under_template_batch_halving(self):
         """The FULL jitted program, dispatched driver-style (batches

@@ -6,7 +6,7 @@ the ULP contract for float inputs, the planner's third alternative
 measured engine race (winner only when faster), the DM-scaled smear
 budgets, the search-side knob grid's warm-bucket zero-measurement
 contract, fused-kernel bitwise gates in interpret mode, and the
-roofline stage taxonomy."""
+roofline stage classification."""
 
 import numpy as np
 import pytest
@@ -493,7 +493,7 @@ class TestFusedChains:
 
 
 # --------------------------------------------------------------------------
-# roofline stage taxonomy
+# roofline stage classification
 # --------------------------------------------------------------------------
 
 class TestRoofline:

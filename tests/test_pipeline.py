@@ -234,6 +234,65 @@ class TestEndToEnd:
             assert a.freq == b.freq and a.snr == b.snr
 
 
+class TestKernelsFailLoudly:
+    """On a TPU a Pallas kernel that fails its compile+run probe stops
+    the search with the kernel's name and the compiler's message; off
+    TPU the jnp twins are the path and the kernels are never built."""
+
+    @pytest.fixture
+    def refusing_kernel(self, monkeypatch):
+        import peasoup_tpu.ops.pallas as pallas_mod
+        from peasoup_tpu.ops.pallas import dedisperse as pallas_dd
+
+        def refuse(*args, **kwargs):
+            raise RuntimeError("Mosaic refused this block shape")
+
+        monkeypatch.setattr(pallas_dd, "dedisperse_pallas", refuse)
+        pallas_mod.probe_pallas_dedisperse.cache_clear()
+        yield pallas_mod
+        pallas_mod.probe_pallas_dedisperse.cache_clear()
+
+    def test_tpu_backend_raises(self, synthetic, refusing_kernel, monkeypatch):
+        path, _, _ = synthetic
+        monkeypatch.setattr(
+            refusing_kernel, "backend_supports_pallas", lambda: True
+        )
+        cfg = SearchConfig(dm_end=20.0, nharmonics=1, limit=10)
+        with pytest.raises(
+            refusing_kernel.KernelUnavailable,
+            match="'dedisperse'.*Mosaic refused this block shape",
+        ):
+            PeasoupSearch(cfg).run(read_filterbank(path))
+
+    def test_cpu_backend_runs_the_twin(self, synthetic, refusing_kernel):
+        path, period, _ = synthetic
+        cfg = SearchConfig(dm_end=60.0, nharmonics=3, limit=10)
+        res = PeasoupSearch(cfg).run(read_filterbank(path))
+        assert abs(1.0 / res.candidates[0].freq / period - 1) < 2e-3
+
+    def test_tpu_without_a_memory_size_raises(self, monkeypatch):
+        """A TPU that reports no bytes_limit is an error, not 12 GB."""
+        import jax
+
+        from peasoup_tpu.utils.device import device_bytes_limit
+
+        class Chip:
+            platform, device_kind = "tpu", "TPU v5 lite"
+
+            def memory_stats(self):
+                return {"bytes_in_use": 1}
+
+        monkeypatch.setattr(jax, "local_devices", lambda: [Chip()])
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            device_bytes_limit()
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            PeasoupSearch(SearchConfig())
+        # an explicit budget is the caller's to give
+        assert PeasoupSearch(SearchConfig(hbm_bytes=1 << 30)).TOTAL_HBM == (
+            1 << 30
+        )
+
+
 class TestDistillers:
     def test_harmonic_distiller_absorbs(self):
         c1 = Candidate(freq=10.0, snr=50.0, nh=4)

@@ -1,4 +1,4 @@
-"""Resilience-layer tests: the error taxonomy, the retry policy, the
+"""Resilience-layer tests: the error classification, the retry policy, the
 degradation ladder, unified corrupt-artifact recovery, the fault
 registry's grammar/determinism/zero-cost contract, the threaded call
 sites (filterbank reads, queue claims, sqlite ingest, checkpoint
@@ -32,10 +32,10 @@ def _clean_faults():
 
 
 # --------------------------------------------------------------------------
-# taxonomy
+# classification
 # --------------------------------------------------------------------------
 
-class TestTaxonomy:
+class TestClassification:
     @pytest.mark.parametrize(
         "exc,want",
         [

@@ -150,113 +150,137 @@ class TestPallasBoxcar:
             )
 
 
-class TestSpchainRetileFallback:
-    """The Mosaic retile fallback ladder (ISSUE 13 satellite): when the
-    toolchain probe rejects the fused spchain kernel's (span/dec, dec)
-    reshape at the full tile span, the driver tries RETILED spans
-    before dropping to the boxcar kernel, and only then the jnp twin —
-    each fallback logged as a resilience degradation rung (and none of
-    it on backends without Pallas at all, where the twin is the design
-    point)."""
+class TestSpKernelSelection:
+    """The single-pulse kernel route: the fused spchain kernel when the
+    tile span is a multiple of the decimation, else the boxcar kernel,
+    the jnp twin only where the backend has no Pallas — and on a TPU a
+    kernel whose probe fails raises instead of dropping a rung."""
 
     def _patch(self, monkeypatch, supports, spchain_ok, boxcar_ok):
         import peasoup_tpu.ops.pallas as pallas_mod
+
+        def probe(ok):
+            def run(*args):
+                if not supports:
+                    return False
+                if not ok:
+                    raise pallas_mod.KernelUnavailable("forced")
+                return True
+
+            return run
 
         monkeypatch.setattr(
             pallas_mod, "backend_supports_pallas", lambda: supports
         )
         monkeypatch.setattr(
-            pallas_mod, "probe_pallas_spchain",
-            lambda nw, span, dec: spchain_ok(span),
+            pallas_mod, "probe_pallas_spchain", probe(spchain_ok)
         )
         monkeypatch.setattr(
-            pallas_mod, "probe_pallas_boxcar",
-            lambda nw, span: boxcar_ok,
+            pallas_mod, "probe_pallas_boxcar", probe(boxcar_ok)
         )
 
-    def test_full_span_accepted_no_rung(self, monkeypatch):
+    def test_full_span_takes_the_fused_kernel(self, monkeypatch):
         from peasoup_tpu.pipeline.single_pulse import select_sp_kernels
 
-        self._patch(monkeypatch, True, lambda s: True, True)
+        self._patch(monkeypatch, True, True, True)
         widths = default_widths(6)
-        assert select_sp_kernels(widths, 8192, 16384, 32, True) == (
-            0, 8192, None,
-        )
+        assert select_sp_kernels(widths, 8192, 32, True) == (0, 8192)
 
-    def test_retiled_span_fallback(self, monkeypatch):
-        """Full span rejected, half span accepted: the fused kernel
-        still runs — retiled — and the rung names the retile."""
+    def test_span_off_the_decimation_takes_the_boxcar_kernel(
+        self, monkeypatch
+    ):
         from peasoup_tpu.pipeline.single_pulse import select_sp_kernels
 
-        self._patch(
-            monkeypatch, True, lambda s: s <= 4096, True
-        )
+        self._patch(monkeypatch, True, True, True)
         widths = default_widths(6)
-        assert select_sp_kernels(widths, 8192, 16384, 32, True) == (
-            0, 4096, "spchain_retile",
-        )
+        assert select_sp_kernels(widths, 8192, 24, True) == (8192, 0)
 
-    def test_boxcar_fallback_when_no_retile_fits(self, monkeypatch):
+    def test_failed_probe_raises_on_tpu(self, monkeypatch):
+        from peasoup_tpu.ops.pallas import KernelUnavailable
         from peasoup_tpu.pipeline.single_pulse import select_sp_kernels
 
-        self._patch(monkeypatch, True, lambda s: False, True)
-        widths = default_widths(6)
-        assert select_sp_kernels(widths, 8192, 16384, 32, True) == (
-            8192, 0, "boxcar_kernel",
-        )
+        self._patch(monkeypatch, True, False, True)
+        with pytest.raises(KernelUnavailable):
+            select_sp_kernels(default_widths(6), 8192, 32, True)
 
-    def test_jnp_twin_last_rung(self, monkeypatch):
+    def test_twin_on_backends_without_pallas(self, monkeypatch):
         from peasoup_tpu.pipeline.single_pulse import select_sp_kernels
 
-        self._patch(monkeypatch, True, lambda s: False, False)
+        self._patch(monkeypatch, False, False, False)
         widths = default_widths(6)
-        assert select_sp_kernels(widths, 8192, 16384, 32, True) == (
-            0, 0, "jnp_twin",
-        )
+        assert select_sp_kernels(widths, 8192, 32, True) == (0, 0)
 
-    def test_no_rung_on_backends_without_pallas(self, monkeypatch):
-        """CPU (or any backend the probes decline wholesale): the twin
-        is the design point — no degradation is logged."""
+    def test_use_pallas_off_probes_nothing(self, monkeypatch):
         from peasoup_tpu.pipeline.single_pulse import select_sp_kernels
 
-        self._patch(monkeypatch, False, lambda s: False, False)
+        self._patch(monkeypatch, True, False, False)
         widths = default_widths(6)
-        assert select_sp_kernels(widths, 8192, 16384, 32, True) == (
-            0, 0, None,
-        )
-        # and with use_pallas off nothing probes at all
-        assert select_sp_kernels(widths, 8192, 16384, 32, False) == (
-            0, 0, None,
-        )
+        assert select_sp_kernels(widths, 8192, 32, False) == (0, 0)
 
-    def test_driver_logs_degradation_event(self, monkeypatch, tmp_path):
-        """End-to-end: a pallas-capable backend whose probes reject
-        everything runs the twin AND flips the resilience degradation
-        table — operators see the fallback, candidates stay correct."""
+    # a campaign bucket small enough to plan on the CPU
+    WARM_BUCKET = (8, 8, 4096, 0.000256, 1400.0, -16.0)
+
+    def test_warmup_compiles_the_route_the_job_takes(self, monkeypatch):
+        """Campaign warmup resolves the same route as the driver: with
+        the spchain probe passing, the spsearch ctx carries the fused
+        kernel at the full span, not the twin."""
+        from peasoup_tpu.ops.singlepulse import plan_pad
+        from peasoup_tpu.perf.warmup import shape_ctx_for_bucket
+
+        self._patch(monkeypatch, True, True, True)
+        ctx = shape_ctx_for_bucket(
+            self.WARM_BUCKET, "spsearch", {"dm_end": 20.0, "n_widths": 6}
+        )
+        _, span = plan_pad(ctx.out_nsamps)
+        assert (ctx.pallas_span, ctx.sp_fused_span) == (0, span)
+
+    def test_warmup_raises_when_the_kernel_fails(self, monkeypatch):
+        from peasoup_tpu.ops.pallas import KernelUnavailable
+        from peasoup_tpu.perf.warmup import shape_ctx_for_bucket
+
+        self._patch(monkeypatch, True, False, True)
+        with pytest.raises(KernelUnavailable):
+            shape_ctx_for_bucket(
+                self.WARM_BUCKET, "spsearch",
+                {"dm_end": 20.0, "n_widths": 6},
+            )
+
+    def test_driver_raises_when_the_kernel_fails(self, monkeypatch, tmp_path):
+        """End-to-end: a TPU backend whose spchain probe fails stops the
+        search with the kernel's error — no silent twin run."""
         from peasoup_tpu.io.sigproc import read_filterbank
-        from peasoup_tpu.resilience.stats import STATS
+        from peasoup_tpu.ops.pallas import KernelUnavailable
 
         path, _, _ = make_sp_fil(
             tmp_path, nsamps=1 << 12, dm_end=20.0, t0=1500
         )
         fil = read_filterbank(path)
         cfg = SinglePulseConfig(dm_end=20.0, min_snr=7.0, n_widths=6)
-        ref = SinglePulseSearch(cfg).run(fil)
-        self._patch(monkeypatch, True, lambda s: False, False)
-        STATS.reset()
-        got = SinglePulseSearch(cfg).run(fil)
-        deg = STATS.snapshot()["degradations"]
-        assert deg.get("spsearch.kernel:jnp_twin") == 1, deg
-        assert [
-            (c.dm_idx, c.sample, c.width, c.snr) for c in got.candidates
-        ] == [
-            (c.dm_idx, c.sample, c.width, c.snr) for c in ref.candidates
-        ]
+        self._patch(monkeypatch, True, False, False)
+        with pytest.raises(KernelUnavailable):
+            SinglePulseSearch(cfg).run(fil)
+
+    def test_driver_reports_its_route(self, tmp_path):
+        """The driver emits one sp_route event naming the kernel it ran
+        (on the CPU: the jnp twin, fused_span = pallas_span = 0)."""
+        from peasoup_tpu.io.sigproc import read_filterbank
+        from peasoup_tpu.obs.telemetry import RunTelemetry
+
+        path, _, _ = make_sp_fil(
+            tmp_path, nsamps=1 << 12, dm_end=20.0, t0=1500
+        )
+        cfg = SinglePulseConfig(dm_end=20.0, min_snr=7.0, n_widths=6)
+        tel = RunTelemetry()
+        with tel.activate():
+            SinglePulseSearch(cfg).run(read_filterbank(path))
+        [route] = [e for e in tel.events if e["kind"] == "sp_route"]
+        assert route["backend"] == "cpu"
+        assert (route["fused_span"], route["pallas_span"]) == (0, 0)
+        assert route["decimate"] == cfg.decimate and route["span"] > 0
 
     def test_retiled_kernel_bitwise_vs_twin(self, rng):
-        """A retiled (smaller-than-plan) span is still bitwise the
-        twin — the geometry the fallback ladder routes to is gated by
-        the same oracle as the full span."""
+        """A span smaller than the plan's is still bitwise the twin:
+        dec-fold semantics do not depend on the tile span."""
         from peasoup_tpu.ops.pallas.spchain import boxcar_dec_best_pallas
         from peasoup_tpu.ops.singlepulse import boxcar_dec_best_twin
 
