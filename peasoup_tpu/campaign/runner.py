@@ -597,6 +597,7 @@ def run_observation(
         n_cands = len(result.candidates)
 
     tel.gauge("candidates.written", n_cands)
+    tel.capture_device_memory()
     # scientific data-quality gauges (obs/health.py) over the block
     # already in memory: advisory — a failure degrades to "no gauges",
     # never to a failed job

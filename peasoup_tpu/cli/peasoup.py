@@ -181,17 +181,17 @@ def main(argv: list[str] | None = None) -> int:
 
     with tel.activate(), live_observability(
         tel, args, outdir, manifest_path
-    ):
-        t0 = time.perf_counter()
-        tel.set_stage("reading")
-        if args.progress_bar:
-            print(f"Reading data from {args.inputfile}")
-        fil = read_filterbank(args.inputfile)
-        reading = time.perf_counter() - t0
+    ), tel.stage("run") as run_span:
+        with tel.stage("reading") as sp_read:
+            if args.progress_bar:
+                print(f"Reading data from {args.inputfile}")
+            fil = read_filterbank(args.inputfile)
 
         with tel.device_capture():
             result = run_search(fil, cfg)
-        result.timers["reading"] = reading
+        result.timers["reading"] = sp_read.seconds
+        # the spans already timed their stages: this adds the rest
+        # (search_device, total)
         tel.merge_timers(result.timers)
 
         import jax
@@ -205,27 +205,28 @@ def main(argv: list[str] | None = None) -> int:
         if jax.process_index() != 0:
             return 0  # every process holds the identical result; rank 0 writes
 
-        tel.set_stage("writing")
-        t0 = time.perf_counter()
-        writer = CandidateFileWriter(outdir)
-        writer.write_binary(result.candidates, "candidates.peasoup")
-        result.timers["writing"] = time.perf_counter() - t0
-        tel.add_timer("writing", result.timers["writing"])
+        with tel.stage("writing") as sp_write:
+            writer = CandidateFileWriter(outdir)
+            writer.write_binary(result.candidates, "candidates.peasoup")
+        result.timers["writing"] = sp_write.seconds
 
-        stats = OutputFileWriter()
-        stats.add_misc_info()
-        stats.add_header(fil.header)
-        stats.add_search_parameters(cfg, args.inputfile)
-        stats.add_dm_list(result.dm_list)
-        stats.add_acc_list(result.acc_list_dm0)
-        stats.add_device_info()
-        stats.add_candidates(result.candidates, writer.byte_mapping)
-        stats.add_timing_info(result.timers)
-        stats.to_file(f"{outdir.rstrip('/')}/overview.xml")
+        with tel.stage("write.overview"):
+            stats = OutputFileWriter()
+            stats.add_misc_info()
+            stats.add_header(fil.header)
+            stats.add_search_parameters(cfg, args.inputfile)
+            stats.add_dm_list(result.dm_list)
+            stats.add_acc_list(result.acc_list_dm0)
+            stats.add_device_info()
+            stats.add_candidates(result.candidates, writer.byte_mapping)
+            stats.add_timing_info(result.timers)
+            stats.to_file(f"{outdir.rstrip('/')}/overview.xml")
 
         # the machine-readable twin of overview.xml, written beside it
         # unless --metrics-json redirects it
         tel.gauge("candidates.written", len(result.candidates))
+        tel.capture_device_memory()
+        run_span.end()
         tel.set_stage("done")
         tel.write(manifest_path)
     if args.verbose or args.progress_bar:

@@ -28,6 +28,26 @@ _LEVELS = {
     "error": logging.ERROR,
 }
 
+
+class _StderrHandler(logging.StreamHandler):
+    """A stream handler that, unless given a stream, writes to whatever
+    ``sys.stderr`` is when it writes: a stderr replaced after
+    configuration (a caller's capture that is later closed) is never
+    written after it goes away."""
+
+    def __init__(self, stream=None):
+        logging.Handler.__init__(self)
+        self._stream = stream
+
+    @property
+    def stream(self):
+        return self._stream if self._stream is not None else sys.stderr
+
+    @stream.setter
+    def stream(self, value):
+        self._stream = value
+
+
 # one library-owned handler, reused across configure() calls so repeated
 # CLI invocations in one process (tests) never stack duplicate handlers
 _handler: logging.StreamHandler | None = None
@@ -77,7 +97,7 @@ def configure(
     logger = get_logger()
     resolved = resolve_level(level, verbose)
     if _handler is None:
-        _handler = logging.StreamHandler(stream or sys.stderr)
+        _handler = _StderrHandler(stream)
         _handler.setFormatter(
             logging.Formatter("[%(levelname)s] %(name)s: %(message)s")
         )

@@ -4,8 +4,12 @@ One :class:`RunTelemetry` object lives for the duration of a pipeline
 run (`PeasoupSearch.run`, the FFA search, the coincidencer). It
 collects:
 
-- **stage timers** — monotonic (``perf_counter``) per-stage wall time;
-  the keys mirror the ``<execution_times>`` table in overview.xml,
+- **stage timers and spans** — per-stage wall time; the keys mirror
+  the ``<execution_times>`` table in overview.xml. Every
+  :meth:`RunTelemetry.stage` is also a span: stamped with
+  ``time.time_ns()`` (the clock of JAX profiler traces), recorded with
+  its parent in the manifest's ``spans`` list, and mirrored into any
+  running profiler trace as a ``peasoup.<name>`` annotation,
 - **counters / gauges** — trial counts, candidate counts per stage,
   per-device memory high-water marks (``device.memory_stats()`` where
   the backend reports them),
@@ -36,10 +40,12 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import json
 import os
 import socket
 import sys
+import threading
 import time
 
 MANIFEST_SCHEMA = "peasoup_tpu.telemetry"
@@ -49,7 +55,20 @@ MANIFEST_SCHEMA = "peasoup_tpu.telemetry"
 # .get() keys newer than a manifest's version — see tools/report.py.
 # v3: optional status sections (e.g. the streaming driver's
 # ``streaming`` block) snapshotted into the manifest at write time.
-MANIFEST_VERSION = 3
+# v4: optional ``spans`` list ({name, id, parent, start_ns, dur_ns} on
+# the time.time_ns() clock); event ``t`` offsets are on that clock too.
+MANIFEST_VERSION = 4
+
+# span records kept per run; later spans are counted in the
+# ``telemetry.spans_dropped`` counter (their timers still accumulate)
+MAX_SPANS = 4096
+# jax.monitoring duration events recorded as spans that end when the
+# event fires (the work is already done, so no profiler annotation)
+_JIT_SPAN_NAMES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
 
 _ACTIVE: contextvars.ContextVar["RunTelemetry | None"] = (
     contextvars.ContextVar("peasoup_tpu_telemetry", default=None)
@@ -96,6 +115,10 @@ def _install_jit_listener() -> None:
                 and not any(k in event for k in _JIT_EVENT_SKIP)
             ):
                 tel.record_jit(event, float(duration))
+                if event in _JIT_SPAN_NAMES:
+                    tel.record_past_span(
+                        _JIT_SPAN_NAMES[event], float(duration)
+                    )
 
         monitoring.register_event_duration_secs_listener(_on_duration)
 
@@ -118,8 +141,54 @@ def persistent_cache_counters(tel: "RunTelemetry") -> tuple[int, int]:
     )
 
 
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` (a no-op context without JAX):
+    inactive unless a profiler trace is running."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation as _annotation_cls
+        except Exception:
+            _annotation_cls = lambda name: contextlib.nullcontext()  # noqa: E731
+    return _annotation_cls(name)
+
+
+class Span:
+    """One span of a run, opened by :meth:`RunTelemetry.begin` (or a
+    ``with RunTelemetry.stage(...)`` block) and closed by :meth:`end`."""
+
+    __slots__ = ("name", "id", "parent", "start_ns", "end_ns", "_tel",
+                 "_annotation")
+
+    def __init__(self, tel: "RunTelemetry", name: str, start_ns: int):
+        self._tel = tel
+        self.name = name
+        self.start_ns = start_ns
+        self.end_ns: int | None = None
+        self.id: int | None = None
+        self.parent: int | None = None
+        self._annotation = None
+
+    @property
+    def seconds(self) -> float:
+        """Duration so far (until now while the span is open)."""
+        end = self.end_ns if self.end_ns is not None else time.time_ns()
+        return (end - self.start_ns) / 1e9
+
+    def end(self) -> float:
+        """Close the span and return its seconds. Spans still open
+        inside it on this thread (left by an exception) close with it;
+        closing twice is harmless."""
+        self._tel._end(self)
+        return self.seconds
+
+
 class RunTelemetry:
-    """Counters, gauges, stage timers and an event log for one run."""
+    """Counters, gauges, stage timers, spans and an event log for one
+    run."""
 
     def __init__(
         self,
@@ -133,8 +202,11 @@ class RunTelemetry:
             + f"-{os.getpid()}"
         )
         self.capture_device_trace = capture_device_trace
-        self.created_unix = time.time()
-        self._t0 = time.perf_counter()
+        # one clock for spans and event offsets: time.time_ns(), which
+        # JAX profiler traces use too (up to one offset per trace)
+        self._t0_ns = time.time_ns()
+        self.created_unix = self._t0_ns / 1e9
+        self._t0 = time.perf_counter()  # uptime base of the live layer
         self.context: dict = {}
         self.counters: dict[str, float] = {}
         self.gauges: dict[str, float] = {}
@@ -145,6 +217,11 @@ class RunTelemetry:
         # live state read by the heartbeat/flight-recorder layer
         self.current_stage: str | None = None
         self._stage_stack: list[str] = []
+        self._lock = threading.Lock()  # stage stack and span timers
+        self.spans: list[dict] = []
+        self._span_ids = itertools.count()
+        self._thread = threading.local()  # .spans: this thread's open spans
+        self._span_timed: set[str] = set()  # timer keys a span wrote
         self.progress_state: dict = {}
         self._listeners: list = []
         # named live-status providers (name -> zero-arg callable or
@@ -173,6 +250,15 @@ class RunTelemetry:
         if self.enabled:
             self.counters[name] = self.counters.get(name, 0) + by
 
+    def fetched(self, host_array):
+        """Count a blocking device-to-host transfer that produced
+        ``host_array`` in ``device.fetches`` and ``device.fetch_bytes``;
+        returns the array."""
+        if self.enabled:
+            self.incr("device.fetches")
+            self.incr("device.fetch_bytes", int(host_array.nbytes))
+        return host_array
+
     def gauge(self, name: str, value: float) -> None:
         """Last-write-wins point-in-time value."""
         if self.enabled:
@@ -186,10 +272,13 @@ class RunTelemetry:
     def event(self, kind: str, **fields) -> dict | None:
         """Append a structured record to the adaptive-event log. Field
         values must be JSON-serialisable (stringify exceptions)."""
+        return self._event_at(time.time_ns(), kind, fields)
+
+    def _event_at(self, t_ns: int, kind: str, fields: dict) -> dict | None:
         if not self.enabled:
             return None
         rec = {
-            "t": round(time.perf_counter() - self._t0, 6),
+            "t": round((t_ns - self._t0_ns) / 1e9, 6),
             "kind": kind,
             **fields,
         }
@@ -230,7 +319,7 @@ class RunTelemetry:
         if fn in self._listeners:
             self._listeners.remove(fn)
 
-    def set_stage(self, name: str) -> None:
+    def set_stage(self, name: str, at_ns: int | None = None) -> None:
         """Mark the run's current pipeline stage (drivers that time
         stages manually call this at each phase boundary; drivers using
         :meth:`stage` get it for free). Recorded as a ``stage`` event so
@@ -238,7 +327,10 @@ class RunTelemetry:
         if not self.enabled or name == self.current_stage:
             return
         self.current_stage = name
-        self.event("stage", name=name)
+        self._event_at(
+            time.time_ns() if at_ns is None else at_ns, "stage",
+            {"name": name},
+        )
 
     def set_progress(
         self, done: float, total: float | None = None, unit: str = ""
@@ -251,30 +343,110 @@ class RunTelemetry:
             "done": float(done),
             "total": float(total) if total is not None else None,
             "unit": unit,
-            "t": round(time.perf_counter() - self._t0, 6),
+            "t": round((time.time_ns() - self._t0_ns) / 1e9, 6),
             "updated_unix": time.time(),
         }
 
     @contextlib.contextmanager
     def stage(self, name: str):
-        """Accumulating monotonic stage timer (same key space as the
-        overview.xml ``<execution_times>`` table). Also tracks the
-        run's *current* stage for the live status.json heartbeat."""
-        t0 = time.perf_counter()
-        if self.enabled:
-            self._stage_stack.append(name)
-            self.set_stage(name)
+        """A span around the with-block, yielding its :class:`Span`
+        (see :meth:`begin`). An exception leaving the block closes the
+        span but keeps the current stage where it failed, for the
+        flight recorder's ``stage_at_abort``."""
+        sp = self.begin(name)
         try:
-            yield
-        finally:
-            if self.enabled:
-                self.timers[name] = self.timers.get(name, 0.0) + (
-                    time.perf_counter() - t0
-                )
-                if self._stage_stack and self._stage_stack[-1] == name:
-                    self._stage_stack.pop()
-                if self._stage_stack:
-                    self.set_stage(self._stage_stack[-1])
+            yield sp
+        except BaseException:
+            self._end(sp, failed=True)
+            raise
+        self._end(sp)
+
+    def begin(self, name: str) -> Span:
+        """Open a span; close it with :meth:`Span.end`, which returns its
+        seconds. A span is an accumulating stage timer (same key space
+        as the overview.xml ``<execution_times>`` table), the run's
+        *current* stage until it closes (the live status.json heartbeat
+        and ``stage`` events), a ``peasoup.<name>`` profiler annotation,
+        and a record in the manifest's ``spans`` list whose ``parent``
+        is the innermost span open on the same thread. The disabled
+        sink only measures the span's seconds."""
+        sp = Span(self, name, time.time_ns())
+        if not self.enabled:
+            return sp
+        sp._annotation = _annotation("peasoup." + name)
+        sp._annotation.__enter__()
+        stack = self._open_spans()
+        sp.parent = stack[-1].id if stack else None
+        sp.id = next(self._span_ids)
+        stack.append(sp)
+        with self._lock:
+            self._stage_stack.append(name)
+        self.set_stage(name, at_ns=sp.start_ns)
+        return sp
+
+    def _open_spans(self) -> list:
+        stack = getattr(self._thread, "spans", None)
+        if stack is None:
+            stack = self._thread.spans = []
+        return stack
+
+    def _end(self, sp: Span, failed: bool = False) -> None:
+        if sp.end_ns is not None:
+            return
+        end_ns = time.time_ns()
+        if not self.enabled:
+            sp.end_ns = end_ns
+            return
+        stack = self._open_spans()
+        if sp in stack:
+            while stack[-1] is not sp:  # unclosed inner spans close here
+                self._close(stack.pop(), end_ns, failed=True)
+            stack.pop()
+        self._close(sp, end_ns, failed)
+
+    def _close(self, sp: Span, end_ns: int, failed: bool) -> None:
+        sp.end_ns = end_ns
+        sp._annotation.__exit__(None, None, None)
+        self._record_span(sp.name, sp.id, sp.parent, sp.start_ns,
+                          end_ns - sp.start_ns)
+        with self._lock:
+            self.timers[sp.name] = self.timers.get(sp.name, 0.0) + (
+                (end_ns - sp.start_ns) / 1e9
+            )
+            self._span_timed.add(sp.name)
+            # the innermost entry of this name: spans of other threads
+            # may have opened above it
+            for i in range(len(self._stage_stack) - 1, -1, -1):
+                if self._stage_stack[i] == sp.name:
+                    del self._stage_stack[i]
+                    break
+            back = self._stage_stack[-1] if self._stage_stack else None
+        if back is not None and not failed:
+            self.set_stage(back, at_ns=end_ns)
+
+    def _record_span(self, name, sid, parent, start_ns, dur_ns) -> None:
+        # ids are taken in opening order, so a kept span's parent is
+        # always kept too
+        if sid < MAX_SPANS:
+            self.spans.append({"name": name, "id": sid, "parent": parent,
+                               "start_ns": start_ns,
+                               "dur_ns": max(0, dur_ns)})
+        else:
+            self.incr("telemetry.spans_dropped")
+
+    def record_past_span(self, name: str, seconds: float) -> None:
+        """Record a span that ends now and lasted ``seconds`` (work
+        reported after it finished, such as a jit compile), under the
+        innermost span open on this thread. It takes no timer, stage or
+        profiler annotation."""
+        if not self.enabled:
+            return
+        end_ns = time.time_ns()
+        stack = self._open_spans()
+        dur_ns = max(0, int(seconds * 1e9))
+        self._record_span(name, next(self._span_ids),
+                          stack[-1].id if stack else None,
+                          end_ns - dur_ns, dur_ns)
 
     def add_timer(self, name: str, seconds: float) -> None:
         """Merge an externally measured duration into a stage timer."""
@@ -282,8 +454,13 @@ class RunTelemetry:
             self.timers[name] = self.timers.get(name, 0.0) + seconds
 
     def merge_timers(self, timers: dict[str, float]) -> None:
+        """Merge a pipeline's timer table (its overview.xml timings). A
+        key that a span of this run already timed is skipped: the
+        table's value is that span's, and merging it would count it
+        twice."""
         for k, v in timers.items():
-            self.add_timer(k, float(v))
+            if k not in self._span_timed:
+                self.add_timer(k, float(v))
 
     def record_jit(self, event: str, seconds: float) -> None:
         if self.enabled:
@@ -291,9 +468,11 @@ class RunTelemetry:
             st[0] += 1
             st[1] += seconds
 
-    def capture_device_memory(self, tag: str) -> None:
-        """Per-device memory high-water marks where the backend reports
-        them (``memory_stats`` is absent on some backends, e.g. CPU)."""
+    def capture_device_memory(self, tag: str | None = None) -> None:
+        """The local devices' memory high-water mark where the backend
+        reports it (``memory_stats`` is absent on some backends, e.g.
+        CPU) as ``memory.peak_bytes``, and as ``memory.<tag>.peak_bytes``
+        when a ``tag`` is given."""
         if not self.enabled:
             return
         try:
@@ -317,7 +496,8 @@ class RunTelemetry:
                 ),
             )
         if peak:
-            self.gauge_max(f"memory.{tag}.peak_bytes", peak)
+            if tag is not None:
+                self.gauge_max(f"memory.{tag}.peak_bytes", peak)
             self.gauge_max("memory.peak_bytes", peak)
 
     # --- activation ---------------------------------------------------
@@ -389,7 +569,9 @@ class RunTelemetry:
             "version": MANIFEST_VERSION,
             "run_id": self.run_id,
             "created_unix": self.created_unix,
-            "duration_s": round(time.perf_counter() - self._t0, 6),
+            "duration_s": round(
+                max(0, time.time_ns() - self._t0_ns) / 1e9, 6
+            ),
             "hostname": socket.gethostname(),
             "pid": os.getpid(),
             # per-host shard tags, duplicated from platform so the
@@ -410,6 +592,8 @@ class RunTelemetry:
             "events": self.events,
             "device_trace": self.device_trace,
         }
+        if self.spans:
+            man["spans"] = self.spans
         for name, val in self.snapshot_sections().items():
             if name not in man:  # sections can never shadow core keys
                 man[name] = val

@@ -40,6 +40,7 @@ def fold_bins_np(
 
 
 @partial(jax.jit, static_argnames=("nbins", "nints"))
+@jax.named_scope("fold.profile")
 def fold_time_series(
     x: jnp.ndarray,  # (..., used_nsamps) resampled time series
     flat_bins: jnp.ndarray,  # (..., used_nsamps) int32 from fold_bins_np

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.telemetry import current as current_telemetry
+
 
 def _shift_array(nbins: int, nints: int) -> np.ndarray:
     """(nshifts, nints, nbins) complex64 phase ramps (kernels.cu:665-684)."""
@@ -48,6 +50,7 @@ def _templates_fft(nbins: int) -> tuple[np.ndarray, int]:
 
 
 @partial(jax.jit, static_argnames=("nbins", "nints"))
+@jax.named_scope("fold.optimise")
 def _optimise_device(
     folds: jnp.ndarray,  # (K, nints, nbins) float32
     shiftar_re: jnp.ndarray,  # (nshifts, nints, nbins) float32
@@ -160,11 +163,12 @@ class FoldOptimiser:
             nbins=self.nbins,
             nints=self.nints,
         )
-        opt_template = np.asarray(opt_template)
-        opt_bin = np.asarray(opt_bin)
-        opt_shift = np.asarray(opt_shift)
-        opt_subs = np.asarray(opt_subs)
-        opt_prof = np.asarray(opt_prof)
+        tel = current_telemetry()
+        opt_template = tel.fetched(np.asarray(opt_template))
+        opt_bin = tel.fetched(np.asarray(opt_bin))
+        opt_shift = tel.fetched(np.asarray(opt_shift))
+        opt_subs = tel.fetched(np.asarray(opt_subs))
+        opt_prof = tel.fetched(np.asarray(opt_prof))
         tobs_k = np.broadcast_to(
             np.asarray(tobs, dtype=np.float64), (folds.shape[0],)
         )

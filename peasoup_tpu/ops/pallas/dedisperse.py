@@ -270,6 +270,7 @@ def _jit_full(
     tr = _tr_rows(t_in, b // 128, k_max)
 
     @jax.jit
+    @jax.named_scope("dedisperse_pallas")
     def run(fil_tc, delays, killmask):
         x = fil_tc.astype(jnp.float32) * killmask.astype(jnp.float32)[None, :]
         # (C, TR, 128) blocked channel rows (tail zero rows = window

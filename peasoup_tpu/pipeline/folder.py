@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.candidates import Candidate
+from ..obs.telemetry import current as current_telemetry
 from ..ops.fold import fold_bins_np, fold_time_series
 from ..ops.fold_optimise import FoldOptimiser
 from ..ops.rednoise import whiten_fseries
@@ -26,6 +27,7 @@ from ..plan.fft_plan import prev_power_of_two
 
 
 @partial(jax.jit, static_argnames=("size", "pos5", "pos25"))
+@jax.named_scope("fold.deredden")
 def _deredden_tim(tim: jax.Array, *, size: int, pos5: int, pos25: int) -> jax.Array:
     """u8 trial -> dereddened f32 time series, scaled like the
     reference's unnormalised inverse FFT (x size) so fold amplitudes
@@ -138,53 +140,19 @@ class MultiFolder:
         max_inflight = 4
         pending = []
         all_folds, all_periods, all_cand_idx = [], [], []
+        tel = current_telemetry()
 
         def drain_one():
             folds, k, periods, cand_ids = pending.pop(0)
-            all_folds.append(np.asarray(folds)[:k])
+            with tel.stage("fold.fetch"):
+                all_folds.append(tel.fetched(np.asarray(folds))[:k])
             all_periods.extend(periods[:k])
             all_cand_idx.extend(cand_ids)
 
         for dm_idx, cand_ids in dm_map.items():
-            xd = _deredden_tim(
-                jnp.asarray(self.trials[dm_idx]),
-                size=self.nsamps,
-                pos5=self.pos5,
-                pos25=self.pos25,
-            )
-            # pad the candidate batch to a fixed width so every DM group
-            # reuses one compiled (K_pad, N) resample+fold program
-            k = len(cand_ids)
-            k_pad = int(np.ceil(k / self.fold_bucket) * self.fold_bucket)
-            ids_pad = cand_ids + [cand_ids[0]] * (k_pad - k)
-            # batched resample (the folder uses the quadratic v1 kernel,
-            # folder.hpp:396 -> kernels.cu:308-332)
-            # (a*tsamp) is an f32 product in the reference's launcher
-            # (float a, float tsamp, kernels.cu:367) — accel_factor
-            # replays it
-            afs = accel_factor(
-                np.asarray([cands[ci].acc for ci in ids_pad]), self.tsamp
-            ).astype(np.float32)
-            xr = jax.vmap(lambda af: resample_accel_quadratic(xd, af))(
-                jnp.asarray(afs)
-            )  # (K_pad, N)
-            periods = np.array(
-                [1.0 / cands[ci].freq for ci in ids_pad], dtype=np.float64
-            )
-            used = self.nints * (self.nsamps // self.nints)
-            flat_bins = np.stack(
-                [
-                    fold_bins_np(self.nsamps, self.tsamp, p, self.nbins, self.nints)
-                    for p in periods
-                ]
-            )
-            folds = fold_time_series(
-                xr[:, :used],
-                jnp.asarray(flat_bins),
-                nbins=self.nbins,
-                nints=self.nints,
-            )
-            pending.append((folds, k, periods, cand_ids))
+            tel.incr("fold.groups")
+            with tel.stage("fold.dispatch"):
+                pending.append(self._dispatch_group(dm_idx, cand_ids, cands))
             if len(pending) >= max_inflight:
                 drain_one()
         while pending:
@@ -192,16 +160,8 @@ class MultiFolder:
 
         if not all_cand_idx:
             return []
-        folds = np.concatenate(all_folds, axis=0)
-        k = folds.shape[0]
-        k_pad = int(np.ceil(k / self.fold_bucket) * self.fold_bucket)
-        if k_pad > k:  # fixed batch width -> one compiled optimiser
-            reps = int(np.ceil(k_pad / k))
-            folds = np.concatenate([folds] * reps, axis=0)[:k_pad]
-            all_periods = (list(all_periods) * reps)[:k_pad]
-        results = self.optimiser.optimise(
-            folds, np.asarray(all_periods), self.tobs
-        )[:k]
+        with tel.stage("fold.optimise"):
+            results = self._optimise(all_folds, all_periods)
         return [
             {
                 "cand_idx": ci,
@@ -211,3 +171,59 @@ class MultiFolder:
             }
             for ci, res in zip(all_cand_idx, results)
         ]
+
+    def _dispatch_group(self, dm_idx: int, cand_ids: list[int], cands):
+        """Enqueue one DM group's deredden, resample and fold; returns
+        (device folds, real count, periods, candidate indices)."""
+        xd = _deredden_tim(
+            jnp.asarray(self.trials[dm_idx]),
+            size=self.nsamps,
+            pos5=self.pos5,
+            pos25=self.pos25,
+        )
+        # pad the candidate batch to a fixed width so every DM group
+        # reuses one compiled (K_pad, N) resample+fold program
+        k = len(cand_ids)
+        k_pad = int(np.ceil(k / self.fold_bucket) * self.fold_bucket)
+        ids_pad = cand_ids + [cand_ids[0]] * (k_pad - k)
+        # batched resample (the folder uses the quadratic v1 kernel,
+        # folder.hpp:396 -> kernels.cu:308-332)
+        # (a*tsamp) is an f32 product in the reference's launcher
+        # (float a, float tsamp, kernels.cu:367) — accel_factor
+        # replays it
+        afs = accel_factor(
+            np.asarray([cands[ci].acc for ci in ids_pad]), self.tsamp
+        ).astype(np.float32)
+        xr = jax.vmap(lambda af: resample_accel_quadratic(xd, af))(
+            jnp.asarray(afs)
+        )  # (K_pad, N)
+        periods = np.array(
+            [1.0 / cands[ci].freq for ci in ids_pad], dtype=np.float64
+        )
+        used = self.nints * (self.nsamps // self.nints)
+        flat_bins = np.stack(
+            [
+                fold_bins_np(self.nsamps, self.tsamp, p, self.nbins, self.nints)
+                for p in periods
+            ]
+        )
+        folds = fold_time_series(
+            xr[:, :used],
+            jnp.asarray(flat_bins),
+            nbins=self.nbins,
+            nints=self.nints,
+        )
+        return folds, k, periods, cand_ids
+
+    def _optimise(self, all_folds: list, all_periods: list) -> list[dict]:
+        """One batched optimiser pass over every fold of the call."""
+        folds = np.concatenate(all_folds, axis=0)
+        k = folds.shape[0]
+        k_pad = int(np.ceil(k / self.fold_bucket) * self.fold_bucket)
+        if k_pad > k:  # fixed batch width -> one compiled optimiser
+            reps = int(np.ceil(k_pad / k))
+            folds = np.concatenate([folds] * reps, axis=0)[:k_pad]
+            all_periods = (list(all_periods) * reps)[:k_pad]
+        return self.optimiser.optimise(
+            folds, np.asarray(all_periods), self.tobs
+        )[:k]

@@ -40,7 +40,7 @@ from ..ops.zap import birdie_mask
 from ..plan.accel_plan import AccelerationPlan
 from ..plan.dm_plan import DMPlan
 from ..plan.fft_plan import choose_fft_size
-from ..utils import ProgressBar, trace_span
+from ..utils import ProgressBar
 from ..utils.device import device_bytes_limit
 from .accel_search import make_batched_search_fn
 from .checkpoint import SearchCheckpoint
@@ -500,10 +500,9 @@ class PeasoupSearch:
         t_total = time.perf_counter()
 
         # --- dedispersion plan + execution ---------------------------------
-        t0 = time.perf_counter()
-        tel.set_stage("plan")
+        sp = tel.begin("plan")
         dm_plan = self.build_dm_plan(fil)
-        timers["plan"] = time.perf_counter() - t0
+        timers["plan"] = sp.end()
         global_ndm = dm_plan.ndm
         dm_lo = 0
         if dm_slice is not None:
@@ -596,8 +595,7 @@ class PeasoupSearch:
                     dplan.engine, dplan.subbands, dplan.dedisp_block,
                     dplan.gain, dplan.predicted_loss, dplan.source,
                 )
-        t0 = time.perf_counter()
-        tel.set_stage("dedispersion")
+        sp_dedisp = tel.begin("dedispersion")
         # --- device selection: shard DM trials over local chips --------
         # (the reference's analogue: one worker per GPU up to -t,
         # pipeline_multi.cu:276-277). Selected BEFORE dedispersion so the
@@ -665,88 +663,91 @@ class PeasoupSearch:
             trials = np.zeros((0, dm_plan.out_nsamps), dtype=np.uint8)
             spill = True  # host ndarray semantics; nothing device-resident
             self._trials_sharded = False
-        with trace_span("Dedisperse"):  # NVTX parity: pipeline_multi.cu:318
-            scale = output_scale(fil.nbits, int(dm_plan.killmask.sum()))
-            # sharded dedispersion wants the whole masked f32 filterbank
-            # replicated per chip; bigger inputs fall back to the
-            # channel-chunked single-device engines
-            shard_dd = shardable and not spill and not skip_dedisp
-            self._trials_sharded = shard_dd
-            if skip_dedisp:
-                pass
-            elif shard_dd:
-                from ..parallel.sharded_dedisperse import dedisperse_sharded
 
-                trials = dedisperse_sharded(
-                    fil_to_device(fil),
-                    dm_plan.delay_samples(),
-                    dm_plan.killmask,
-                    dm_plan.out_nsamps,
-                    mesh,
-                    scale=scale,
-                    block=dedisp_block,
-                )
-            elif subbands > 0:
-                # the subband engine stages the filterbank on DEVICE
-                # regardless of trial spill (to_host only routes the
-                # OUTPUTS), so always take the packed-upload + on-device
-                # unpack path: 4x less H2D for 2-bit survey data
-                trials = dedisperse_subband(
-                    fil_to_device(fil),
-                    dm_plan.delay_samples(),
-                    dm_plan.killmask,
-                    dm_plan.out_nsamps,
-                    nsub=subbands,
-                    max_smear=subband_smear,
-                    scale=scale,
-                    to_host=spill,
-                    use_matmul=subband_matmul,
-                    budgets=smear_budgets,
-                )
-            elif dedisp_engine == "matmul" and not spill:
-                # the MXU banded-matmul engine (tuned winner or forced
-                # via --dedisp_engine): bitwise-equal to the gather
-                # scan, so the spill/sharded paths degrading to gather
-                # elsewhere never changes candidates
-                from ..ops.dedisperse import dedisperse_matmul
+        def upload():
+            with tel.stage("dedispersion.upload"):
+                return fil_to_device(fil)
 
-                trials = dedisperse_matmul(
-                    fil_to_device(fil),
-                    dm_plan.delay_samples(),
-                    dm_plan.killmask,
-                    dm_plan.out_nsamps,
-                    scale=scale,
-                )
+        scale = output_scale(fil.nbits, int(dm_plan.killmask.sum()))
+        # sharded dedispersion wants the whole masked f32 filterbank
+        # replicated per chip; bigger inputs fall back to the
+        # channel-chunked single-device engines
+        shard_dd = shardable and not spill and not skip_dedisp
+        self._trials_sharded = shard_dd
+        if skip_dedisp:
+            pass
+        elif shard_dd:
+            from ..parallel.sharded_dedisperse import dedisperse_sharded
+
+            trials = dedisperse_sharded(
+                upload(),
+                dm_plan.delay_samples(),
+                dm_plan.killmask,
+                dm_plan.out_nsamps,
+                mesh,
+                scale=scale,
+                block=dedisp_block,
+            )
+        elif subbands > 0:
+            # the subband engine stages the filterbank on DEVICE
+            # regardless of trial spill (to_host only routes the
+            # OUTPUTS), so always take the packed-upload + on-device
+            # unpack path: 4x less H2D for 2-bit survey data
+            trials = dedisperse_subband(
+                upload(),
+                dm_plan.delay_samples(),
+                dm_plan.killmask,
+                dm_plan.out_nsamps,
+                nsub=subbands,
+                max_smear=subband_smear,
+                scale=scale,
+                to_host=spill,
+                use_matmul=subband_matmul,
+                budgets=smear_budgets,
+            )
+        elif dedisp_engine == "matmul" and not spill:
+            # the MXU banded-matmul engine (tuned winner or forced
+            # via --dedisp_engine): bitwise-equal to the gather
+            # scan, so the spill/sharded paths degrading to gather
+            # elsewhere never changes candidates
+            from ..ops.dedisperse import dedisperse_matmul
+
+            trials = dedisperse_matmul(
+                upload(),
+                dm_plan.delay_samples(),
+                dm_plan.killmask,
+                dm_plan.out_nsamps,
+                scale=scale,
+            )
+        else:
+            dd = dedisperse if spill else dedisperse_device
+            trials = dd(
+                fil.data if spill else upload(),
+                dm_plan.delay_samples(),
+                dm_plan.killmask,
+                dm_plan.out_nsamps,
+                scale=scale,
+                block=dedisp_block,
+            )
+        if not spill and not skip_dedisp:
+            # ASYNC dispatch: the trials stay in flight while the
+            # host builds the wave plan and dispatches the first
+            # search chunks, so dedispersion of the tail overlaps
+            # the search of the head (XLA orders the per-trial
+            # dependencies). The dedispersion timer therefore
+            # records DISPATCH wall only; completion is absorbed
+            # into search_device. PEASOUP_SYNC_DEDISP=1 restores
+            # the old barrier (and the timer's old meaning) —
+            # results are bitwise identical either way, pinned by
+            # tests/test_dedisp_plan.py.
+            if os.environ.get("PEASOUP_SYNC_DEDISP"):
+                jax.block_until_ready(trials)
             else:
-                dd = dedisperse if spill else dedisperse_device
-                trials = dd(
-                    fil.data if spill else fil_to_device(fil),
-                    dm_plan.delay_samples(),
-                    dm_plan.killmask,
-                    dm_plan.out_nsamps,
-                    scale=scale,
-                    block=dedisp_block,
+                tel.event(
+                    "dedisp_async_dispatch",
+                    dispatch_s=round(sp_dedisp.seconds, 4),
                 )
-            if not spill and not skip_dedisp:
-                # ASYNC dispatch: the trials stay in flight while the
-                # host builds the wave plan and dispatches the first
-                # search chunks, so dedispersion of the tail overlaps
-                # the search of the head (XLA orders the per-trial
-                # dependencies). The dedispersion timer therefore
-                # records DISPATCH wall only; completion is absorbed
-                # into search_device. PEASOUP_SYNC_DEDISP=1 restores
-                # the old barrier (and the timer's old meaning) —
-                # results are bitwise identical either way, pinned by
-                # tests/test_dedisp_plan.py.
-                if os.environ.get("PEASOUP_SYNC_DEDISP"):
-                    jax.block_until_ready(trials)
-                else:
-                    tel.event(
-                        "dedisp_async_dispatch",
-                        dispatch_s=round(time.perf_counter() - t0, 4),
-                    )
-        timers["dedispersion"] = time.perf_counter() - t0
-        tel.capture_device_memory("dedispersion")
+        timers["dedispersion"] = sp_dedisp.end()
 
         # --- search setup ---------------------------------------------------
         size = choose_fft_size(fil.nsamps, cfg.size)
@@ -799,8 +800,10 @@ class PeasoupSearch:
         # every chunk of a wave is DISPATCHED asynchronously, then the
         # wave's counts come back in ONE packed D2H, and the peak arrays
         # in ONE more, trimmed to the observed per-chunk maximum count.
-        t0 = time.perf_counter()
-        tel.set_stage("searching")
+        sp_search = tel.begin("searching")
+        # accel lists, dedupe, kernel probes and routing, the search
+        # program and the wave plan
+        sp_setup = tel.begin("search.setup")
         accel_lists = [
             acc_plan.generate_accel_list(float(dm)) for dm in dm_plan.dm_list
         ]
@@ -1110,6 +1113,7 @@ class PeasoupSearch:
                 waves.append(wave)
             return waves
 
+        sp_setup.end()
         progress = ProgressBar() if cfg.progress_bar else None
         if progress:
             progress.start()
@@ -1129,14 +1133,15 @@ class PeasoupSearch:
         cpu_mode = False
         fell_subband = False
         while True:
-            chunks = build_chunks(shrink)
-            waves = build_waves(chunks)
-            tel.event(
-                "wave_plan", n_waves=len(waves), n_chunks=len(chunks),
-                shrink=shrink,
-                max_dm_block=max((d for _, d in chunks), default=0),
-                backend="cpu" if cpu_mode else "default",
-            )
+            with tel.stage("search.setup"):
+                chunks = build_chunks(shrink)
+                waves = build_waves(chunks)
+                tel.event(
+                    "wave_plan", n_waves=len(waves), n_chunks=len(chunks),
+                    shrink=shrink,
+                    max_dm_block=max((d for _, d in chunks), default=0),
+                    backend="cpu" if cpu_mode else "default",
+                )
             try:
                 faults.fire(
                     "device.oom",
@@ -1285,8 +1290,7 @@ class PeasoupSearch:
                 raise
         if progress:
             progress.stop()
-        timers["search_device"] = time.perf_counter() - t0
-        tel.capture_device_memory("search")
+        timers["search_device"] = sp_search.seconds
         tel.event(
             "search_route", backend=jax.default_backend(),
             pallas_peaks=bool(self._pallas_peaks),
@@ -1305,8 +1309,7 @@ class PeasoupSearch:
         # call over every (dm, accel) trial of the run — Candidate
         # objects exist only for its survivors (the reference builds one
         # struct per raw detection, pipeline_multi.cu:233-238).
-        t_host = time.perf_counter()
-        tel.set_stage("search_host")
+        sp_host = tel.begin("search_host")
         from .. import native
 
         dm_trial_cands = CandidateCollection()
@@ -1350,8 +1353,8 @@ class PeasoupSearch:
                     dm, dm_idx + 1, dm_plan.ndm, len(accs),
                     len(dm_trial_cands),
                 )
-        timers["search_host"] = time.perf_counter() - t_host
-        timers["searching"] = time.perf_counter() - t0
+        timers["search_host"] = sp_host.end()
+        timers["searching"] = sp_search.end()
         tel.gauge("candidates.per_dm_distill", len(dm_trial_cands))
 
         if dm_lo:
@@ -1390,8 +1393,7 @@ class PeasoupSearch:
         cfg = self.config
         tel = current_telemetry()
         timers = part.timers
-        t0 = time.perf_counter()
-        tel.set_stage("distilling")
+        sp = tel.begin("distilling")
         dm_still = DMDistiller(cfg.freq_tol, keep_related=True)
         harm_still = HarmonicDistiller(
             cfg.freq_tol, cfg.max_harm, keep_related=True, fractional_harms=False
@@ -1401,19 +1403,18 @@ class PeasoupSearch:
         tel.gauge("candidates.post_dm_distill", len(cands))
         cands = harm_still.distill(cands)
         tel.gauge("candidates.post_harmonic_distill", len(cands))
-        timers["distilling"] = time.perf_counter() - t0
+        timers["distilling"] = sp.end()
 
-        t0 = time.perf_counter()
-        tel.set_stage("scoring")
+        sp = tel.begin("scoring")
         scorer = CandidateScorer(
             fil.tsamp, fil.cfreq, fil.foff, abs(fil.foff) * fil.nchans
         )
         scorer.score_all(cands)
-        timers["scoring"] = time.perf_counter() - t0
+        timers["scoring"] = sp.end()
 
-        t0 = time.perf_counter()
+        timers["folding"] = 0.0
         if cfg.npdmp > 0:
-            tel.set_stage("folding")
+            sp = tel.begin("folding")
             folder = MultiFolder(
                 part.trials, part.trials_nsamps, fil.tsamp,
                 pos5_freq=cfg.boundary_5_freq, pos25_freq=cfg.boundary_25_freq,
@@ -1424,7 +1425,7 @@ class PeasoupSearch:
                 outcomes = fold_exchange(outcomes)
             cands = folder.apply_outcomes(cands, outcomes)
             tel.gauge("candidates.folded", min(cfg.npdmp, len(cands)))
-        timers["folding"] = time.perf_counter() - t0
+            timers["folding"] = sp.end()
 
         cands = cands[: cfg.limit]
         tel.gauge("candidates.final", len(cands))
@@ -1457,20 +1458,23 @@ class PeasoupSearch:
                 if not all(d in per_dm_results for d in c[0])
             ]
             if todo:
-                # fleet-trace span (obs/trace.py, no-op outside a
-                # campaign job): each search wave is one unit of the
-                # job's connected timeline
-                with job_span(
-                    "wave", wave=wi, chunks=len(todo),
-                ), trace_span("DM-Loop"):  # NVTX parity: pipeline_multi.cu:144
-                    self._search_wave(
-                        todo, dispatch_lists, trials, tim_len, zapmask_dev,
-                        windows, self._active_search_block,
-                        per_dm_results, **disp,
-                    )
-                if ckpt is not None:
-                    with job_span("checkpoint", wave=wi):
-                        ckpt.save(per_dm_results)
+                tel.incr("search.waves")
+                with tel.stage("search.wave"):
+                    # fleet-trace span (obs/trace.py, no-op outside a
+                    # campaign job): each search wave is one unit of
+                    # the job's connected timeline
+                    with job_span("wave", wave=wi, chunks=len(todo)):
+                        self._search_wave(
+                            todo, dispatch_lists, trials, tim_len,
+                            zapmask_dev, windows,
+                            self._active_search_block, per_dm_results,
+                            **disp,
+                        )
+                    if ckpt is not None:
+                        with job_span("checkpoint", wave=wi), tel.stage(
+                            "checkpoint"
+                        ):
+                            ckpt.save(per_dm_results)
                 # revoke seam: a preempt/retire observed by the lease
                 # renewer stops here, right after the checkpoint save,
                 # so the resumed run restores exactly this state and
@@ -1780,6 +1784,7 @@ class PeasoupSearch:
             if self._dm_sharding is not None
             else jnp.asarray(afs)
         )
+        current_telemetry().incr("search.chunks")
         peaks = search_block(
             tims_dev,
             afs_dev,
@@ -1815,27 +1820,31 @@ class PeasoupSearch:
         )
         args = (dispatch_lists, trials, tim_len, zapmask_dev, windows,
                 search_block)
-
+        tel = current_telemetry()
 
         mp0 = max(cfg.max_peaks, self._learned_max_peaks)
         spec_pad = self._learned_total_pad
         pend = []
         packs = []
-        for chunk in wave:
-            peaks, padded = self._dispatch_chunk(chunk, *args, mp0, **disp)
-            # record which peaks mode produced this chunk: a mid-wave
-            # degrade must not re-judge earlier fused-kernel chunks by
-            # raw-crossing counts
-            pend.append(
-                [chunk, mp0, peaks, padded,
-                 getattr(self, "_pallas_peaks", False)]
-            )
-            packs.append(
-                pack_chunk_results(
-                    peaks.idxs, peaks.snrs, peaks.counts, peaks.ccounts,
-                    total_pad=spec_pad,
+        with tel.stage("search.dispatch"):
+            for chunk in wave:
+                peaks, padded = self._dispatch_chunk(
+                    chunk, *args, mp0, **disp
                 )
-            )
+                # record which peaks mode produced this chunk: a
+                # mid-wave degrade must not re-judge earlier
+                # fused-kernel chunks by raw-crossing counts
+                pend.append(
+                    [chunk, mp0, peaks, padded,
+                     getattr(self, "_pallas_peaks", False)]
+                )
+                packs.append(
+                    pack_chunk_results(
+                        peaks.idxs, peaks.snrs, peaks.counts,
+                        peaks.ccounts, total_pad=spec_pad,
+                    )
+                )
+            packed = packs[0] if len(packs) == 1 else jnp.concatenate(packs)
 
         # ONE packed transfer for the whole wave: each chunk contributes
         # [raw counts | cluster counts | speculatively compacted peak
@@ -1843,9 +1852,8 @@ class PeasoupSearch:
         # compaction overflowed are re-dispatched with the next
         # power-of-two size (the reference sizes for 100000 up front,
         # peakfinder.hpp:61) -- rare, and only they pay extra round trips
-        packed_all = np.asarray(
-            packs[0] if len(packs) == 1 else jnp.concatenate(packs)
-        )
+        with tel.stage("search.fetch"):
+            packed_all = tel.fetched(np.asarray(packed))
         counts_list = []
         ccounts_list = []
         spec_pieces = []
@@ -1866,7 +1874,13 @@ class PeasoupSearch:
             # overflow: raw crossings outgrew the compaction (jnp
             # path) or clusters outgrew it (fused-kernel path)
             ov = ccounts if fused else counts
+            # escalations, their probes and fetches (the enclosing
+            # search.wave span closes this one if an escalation raises)
+            sp = None
+            if ov.max() > max_peaks:
+                sp = tel.begin("search.redispatch")
             while ov.max() > max_peaks:
+                tel.incr("search.redispatches")
                 old_mp = max_peaks
                 max_peaks = 1 << int(np.ceil(np.log2(ov.max())))
                 self._learned_max_peaks = max(
@@ -1877,7 +1891,7 @@ class PeasoupSearch:
                     "%d -> %d (observed %d)", old_mp, max_peaks,
                     int(ov.max()),
                 )
-                current_telemetry().event(
+                tel.event(
                     "max_peaks_escalated", old=int(old_mp),
                     new=int(max_peaks), observed=int(ov.max()),
                 )
@@ -1907,11 +1921,13 @@ class PeasoupSearch:
                 peaks, padded = self._dispatch_chunk(
                     chunk, *args, max_peaks, **disp
                 )
-                counts = np.asarray(peaks.counts)
-                ccounts = np.asarray(peaks.ccounts)
+                counts = tel.fetched(np.asarray(peaks.counts))
+                ccounts = tel.fetched(np.asarray(peaks.ccounts))
                 ov = ccounts if fused else counts
                 entry[1:] = [max_peaks, peaks, padded, fused]
                 redisp = True
+            if sp is not None:
+                sp.end()
             counts_list.append(counts)
             ccounts_list.append(ccounts)
             redispatched.append(redisp)
@@ -1922,46 +1938,49 @@ class PeasoupSearch:
         # (first-ever wave, busier data, or escalation) compact at the
         # exact pow2-padded size and pay one extra transfer — and learn
         # the size so the next wave's speculation covers it.
-        for i, ((chunk, max_peaks, peaks, padded, _), ccounts) in enumerate(
-            zip(pend, ccounts_list)
-        ):
-            cc0 = np.minimum(ccounts, max_peaks)
-            total = int(cc0.sum())
-            total_pad = 1 << max(6, int(np.ceil(np.log2(max(1, total)))))
-            # learn upward, but cap the speculation: one RFI-storm chunk
-            # must not permanently inflate every later chunk's payload
-            # beyond what the saved round trip is worth (~512 KiB)
-            self._learned_total_pad = min(
-                max(self._learned_total_pad, total_pad), 1 << 16
-            )
-            if not redispatched[i] and total <= spec_pad:
-                piece = spec_pieces[i]
-                total_pad = spec_pad
-            else:
-                piece = np.asarray(
-                    compact_peaks_device(
-                        peaks.idxs, peaks.snrs, peaks.ccounts,
-                        total_pad=total_pad,
-                    )
+        with tel.stage("search.unpack"):
+            for i, (entry, ccounts) in enumerate(zip(pend, ccounts_list)):
+                chunk, max_peaks, peaks, padded, _ = entry
+                cc0 = np.minimum(ccounts, max_peaks)
+                total = int(cc0.sum())
+                total_pad = 1 << max(6, int(np.ceil(np.log2(max(1, total)))))
+                # learn upward, but cap the speculation: one RFI-storm chunk
+                # must not permanently inflate every later chunk's payload
+                # beyond what the saved round trip is worth (~512 KiB)
+                self._learned_total_pad = min(
+                    max(self._learned_total_pad, total_pad), 1 << 16
                 )
-            vi = piece[:total_pad]
-            vs = piece[total_pad : 2 * total_pad].view(np.float32)
-            cc = cc0  # (d, nlev, padded)
-            # per-row entry ranges within the chunk's ragged stream
-            row_ends = np.cumsum(cc.reshape(cc.shape[0], -1).sum(axis=1))
-            dm_indices = chunk[0]
-            for row in range(len(dm_indices)):
-                lo = int(row_ends[row - 1]) if row else 0
-                hi = int(row_ends[row])
-                dm_idx = dm_indices[row]
-                emap = self._accel_expand[dm_idx]
-                if emap is None:
-                    per_dm_results[dm_idx] = (vi[lo:hi], vs[lo:hi], cc[row])
+                if not redispatched[i] and total <= spec_pad:
+                    piece = spec_pieces[i]
+                    total_pad = spec_pad
                 else:
-                    # deduped dispatch: replicate the representative's
-                    # results onto every identity accel column (bitwise
-                    # what brute force would have produced)
-                    per_dm_results[dm_idx] = _expand_accel_results(
-                        vi[lo:hi], vs[lo:hi], cc[row], emap,
-                        self._accel_full_pad[dm_idx],
-                    )
+                    tel.incr("search.spec_misses")
+                    piece = tel.fetched(np.asarray(
+                        compact_peaks_device(
+                            peaks.idxs, peaks.snrs, peaks.ccounts,
+                            total_pad=total_pad,
+                        )
+                    ))
+                vi = piece[:total_pad]
+                vs = piece[total_pad : 2 * total_pad].view(np.float32)
+                cc = cc0  # (d, nlev, padded)
+                # per-row entry ranges within the chunk's ragged stream
+                row_ends = np.cumsum(cc.reshape(cc.shape[0], -1).sum(axis=1))
+                dm_indices = chunk[0]
+                for row in range(len(dm_indices)):
+                    lo = int(row_ends[row - 1]) if row else 0
+                    hi = int(row_ends[row])
+                    dm_idx = dm_indices[row]
+                    emap = self._accel_expand[dm_idx]
+                    if emap is None:
+                        per_dm_results[dm_idx] = (
+                            vi[lo:hi], vs[lo:hi], cc[row]
+                        )
+                    else:
+                        # deduped dispatch: replicate the representative's
+                        # results onto every identity accel column (bitwise
+                        # what brute force would have produced)
+                        per_dm_results[dm_idx] = _expand_accel_results(
+                            vi[lo:hi], vs[lo:hi], cc[row], emap,
+                            self._accel_full_pad[dm_idx],
+                        )

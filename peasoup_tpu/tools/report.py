@@ -189,11 +189,52 @@ def _render_merged_sections(man: dict) -> list[str]:
     return lines
 
 
+def render_span_tree(man: dict) -> str:
+    """A v4 manifest's ``spans`` as a tree: each span's start from the
+    first span, its duration, and its self time (the duration its
+    children on the same thread do not cover). A span whose parent was
+    not recorded is drawn as a root."""
+    spans = man.get("spans") or []
+    ids = {s["id"] for s in spans}
+    children: dict = {}
+    for s in spans:
+        parent = s.get("parent") if s.get("parent") in ids else None
+        children.setdefault(parent, []).append(s)
+    for kids in children.values():
+        kids.sort(key=lambda s: s["start_ns"])
+    t0 = min(s["start_ns"] for s in spans)
+    rows: list[tuple[str, dict, int]] = []  # (indented name, span, self)
+
+    def walk(s: dict, depth: int) -> None:
+        kids = children.get(s["id"], [])
+        self_ns = max(0, s["dur_ns"] - sum(k["dur_ns"] for k in kids))
+        rows.append(("  " * depth + s["name"], s, self_ns))
+        for k in kids:
+            walk(k, depth + 1)
+
+    for root in children.get(None, []):
+        walk(root, 0)
+    width = max([len("span")] + [len(r[0]) for r in rows])
+    lines = [
+        f"span tree: run {man.get('run_id', '?')}  ({len(spans)} spans)",
+        f"  {'span':<{width}}  {'start':>10}  {'total':>10}  {'self':>10}",
+    ]
+    for name, s, self_ns in rows:
+        lines.append(
+            f"  {name:<{width}}  {(s['start_ns'] - t0) / 1e9:9.4f}s"
+            f"  {s['dur_ns'] / 1e9:9.4f}s  {self_ns / 1e9:9.4f}s self"
+        )
+    return "\n".join(lines) + "\n"
+
+
 def render_timeline(man: dict, width: int = 60) -> str:
-    """Historical timeline view of one run: the ``stage`` transition
-    events rendered as a gantt over the run's wall clock, with the
-    remaining adaptive events as markers — "what was this run doing
-    when" from the manifest alone (no live heartbeat needed)."""
+    """Historical timeline view of one run from the manifest alone (no
+    live heartbeat needed): the span tree with self times where the
+    manifest has spans (v4), else the ``stage`` transition events
+    rendered as a gantt over the run's wall clock, with the remaining
+    adaptive events as markers."""
+    if man.get("spans"):
+        return render_span_tree(man)
     events = man.get("events") or []
     duration = float(man.get("duration_s") or 0.0)
     stages: list[tuple[str, float, float]] = []  # (name, t0, t1)
@@ -454,8 +495,9 @@ def main(argv: list[str] | None = None) -> int:
     )
     p.add_argument(
         "--timeline", action="store_true",
-        help="render one manifest's stage transitions as a wall-clock "
-        "gantt (the historical what-was-it-doing-when view)",
+        help="render one manifest's span tree with each span's self "
+        "time (older manifests: a wall-clock gantt of its stage "
+        "transitions)",
     )
     args = p.parse_args(argv)
     if args.timeline:

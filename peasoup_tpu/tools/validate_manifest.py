@@ -59,7 +59,8 @@ def main(argv: list[str] | None = None) -> int:
         tel.incr("widgets", 3)
         tel.gauge("level", 1.5)
         with tel.stage("probe"):
-            pass
+            with tel.stage("probe.inner"):
+                pass
         tel.set_progress(1, 2, unit="steps")
         tel.event("adaptive_thing", old=1, new=2)
         tel.record_jit("/jax/core/compile", 0.1)

@@ -8,9 +8,11 @@ the overview.xml <execution_times> table.
 
 TPU equivalent: ``trace_span`` emits a ``jax.profiler.TraceAnnotation``
 (visible in TensorBoard/perfetto traces captured with
-``jax.profiler.trace``) and the same span names are used by the search
-driver; ``Stopwatch`` keeps the reference's accumulate-across-starts
-semantics for the XML timing table.
+``jax.profiler.trace``), used by the single-pulse search; the
+periodicity search's host spans are its telemetry spans
+(``obs/telemetry.py``, ``peasoup.<stage>``). ``Stopwatch`` keeps the
+reference's accumulate-across-starts semantics for the XML timing
+table.
 """
 
 from __future__ import annotations

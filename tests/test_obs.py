@@ -147,6 +147,36 @@ def test_configure_is_idempotent_and_gates_levels():
     assert "peasoup_tpu.pipeline.search" in out
 
 
+def test_logger_follows_a_replaced_stderr(monkeypatch):
+    """Configured without a stream, the handler writes to the current
+    sys.stderr: one closed after configuration (a caller's capture) is
+    neither written nor flushed again."""
+    import io
+    import sys
+
+    from peasoup_tpu.obs import log as obs_log
+
+    logger = obs.get_logger()
+    old = obs_log._handler
+    if old is not None:
+        logger.removeHandler(old)
+    monkeypatch.setattr(obs_log, "_handler", None)
+    gone = io.TextIOWrapper(io.BytesIO())  # as a captured stderr
+    monkeypatch.setattr(sys, "stderr", gone)
+    try:
+        obs.configure_logging("warning")
+        gone.close()
+        now = io.StringIO()
+        monkeypatch.setattr(sys, "stderr", now)
+        obs.get_logger("obs.heartbeat").warning("after close")
+        assert "after close" in now.getvalue()
+        obs.configure_logging("warning", stream=io.StringIO())  # no raise
+    finally:
+        logger.removeHandler(obs_log._handler)
+        if old is not None:
+            logger.addHandler(old)
+
+
 def test_get_logger_naming():
     assert obs.get_logger().name == "peasoup_tpu"
     assert obs.get_logger("obs").name == "peasoup_tpu.obs"

@@ -1,10 +1,11 @@
-"""End-to-end profiler smoke test (VERDICT r1 item 8).
+"""End-to-end profiler smoke test.
 
 Captures a jax.profiler.trace of a tiny search run and asserts the
-reference's four NVTX span names (SURVEY section 5: "Dedisperse",
-"DM-Loop" as host TraceAnnotations; "Acceleration-Loop",
-"Harmonic summing" as named_scope op metadata inside the jitted
-program) are all present in the captured trace.
+spans that stand for the reference's NVTX ranges are present: the
+telemetry spans ``peasoup.dedispersion`` and ``peasoup.search.wave``
+("Dedisperse", "DM-Loop") as host annotations, and "Acceleration-Loop"
+and "Harmonic summing" as named_scope op metadata inside the jitted
+program.
 """
 
 import glob
@@ -18,14 +19,16 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from peasoup_tpu.io import read_filterbank
+from peasoup_tpu.obs import RunTelemetry
 from peasoup_tpu.pipeline import PeasoupSearch, SearchConfig
 
 from test_pipeline import make_synthetic_fil
 
 
 def test_trace_contains_host_spans(tmp_path):
-    """Host-side TraceAnnotations ("Dedisperse", "DM-Loop") appear in a
-    captured jax.profiler trace of a real tiny run."""
+    """The telemetry spans' host annotations (``peasoup.dedispersion``,
+    ``peasoup.search.wave``) appear in a captured jax.profiler trace of
+    a real tiny run."""
     path, _, _ = make_synthetic_fil(tmp_path, nsamps=1 << 13, nchans=8)
     fil = read_filterbank(str(path))
     cfg = SearchConfig(dm_end=20.0, nharmonics=2, npdmp=0, limit=20)
@@ -33,7 +36,7 @@ def test_trace_contains_host_spans(tmp_path):
     search.run(fil)  # compile outside the trace
 
     tdir = str(tmp_path / "trace")
-    with jax.profiler.trace(tdir):
+    with jax.profiler.trace(tdir), RunTelemetry().activate():
         search.run(fil)
 
     files = glob.glob(
@@ -45,7 +48,7 @@ def test_trace_contains_host_spans(tmp_path):
         events = json.load(gzip.open(f))
         text += json.dumps(events)
 
-    for span in ("Dedisperse", "DM-Loop"):
+    for span in ("peasoup.dedispersion", "peasoup.search.wave"):
         assert span in text, f"span {span!r} missing from profiler trace"
 
 
@@ -79,3 +82,58 @@ def test_jitted_program_carries_device_scopes():
         pytest.skip("Lowered.as_text lacks debug_info on this jax")
     assert "Acceleration-Loop" in text
     assert "Harmonic summing" in text
+
+
+def _op_paths(text: str, program: str) -> list[str]:
+    """The op-name paths under ``jit(<program>)/`` in a lowered
+    program's location table."""
+    import re
+
+    return re.findall(r'loc\("(jit\(%s\)/[^"]*)"' % re.escape(program), text)
+
+
+@pytest.mark.parametrize("program", ["deredden", "profile", "optimise",
+                                     "dedisperse_pallas"])
+def test_fold_and_dedispersion_programs_carry_stable_scopes(program):
+    """The fold programs and the Pallas dedispersion entry carry a
+    named_scope each, and every op keeps the device stage the trace
+    reduction's rules gave it before the scope existed."""
+    import jax.numpy as jnp
+
+    from benchmark.reduce import stage_of
+    from peasoup_tpu.ops.fold import fold_time_series
+    from peasoup_tpu.ops.fold_optimise import FoldOptimiser, _optimise_device
+    from peasoup_tpu.ops.pallas.dedisperse import _jit_full
+    from peasoup_tpu.pipeline.folder import _deredden_tim
+
+    if program == "deredden":
+        scope, before, name = "fold.deredden", "spectrum_chain", "_deredden_tim"
+        text = _deredden_tim.lower(
+            jnp.zeros(256, jnp.uint8), size=256, pos5=2, pos25=20,
+        ).as_text(debug_info=True)
+    elif program == "profile":
+        scope, before, name = "fold.profile", "fold", "fold_time_series"
+        text = fold_time_series.lower(
+            jnp.zeros((2, 64), jnp.float32), jnp.zeros((2, 64), jnp.int32),
+            nbins=8, nints=2,
+        ).as_text(debug_info=True)
+    elif program == "optimise":
+        scope, before, name = "fold.optimise", "fold", "_optimise_device"
+        opt = FoldOptimiser(8, 2)
+        text = _optimise_device.lower(
+            jnp.zeros((2, 2, 8), jnp.float32), opt.shiftar_re,
+            opt.shiftar_im, opt.templates_re, opt.templates_im,
+            nbins=8, nints=2,
+        ).as_text(debug_info=True)
+    else:
+        scope, before, name = "dedisperse_pallas", "dedisperse", "run"
+        run = _jit_full(8, 256, 16, 256, 4, 8, 16, 512, 200, True, 1.0, True)
+        text = run.lower(
+            jnp.zeros((512, 16), jnp.uint8), jnp.zeros((8, 16), jnp.int32),
+            jnp.ones(16, bool),
+        ).as_text(debug_info=True)
+    paths = _op_paths(text, name)
+    assert paths
+    for p in paths:
+        assert p.startswith(f"jit({name})/{scope}")
+        assert stage_of(p) == stage_of(p.replace(scope, "", 1)) == before
