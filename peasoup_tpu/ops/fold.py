@@ -7,10 +7,14 @@ frac(jj*tsamp/period)*nbins in f64, and a count array initialised to 1
 
 TPU design: the phase->bin map is data-independent integer-valued
 metadata; it is computed EXACTLY in host f64 (TPU f64 is emulated and
-slow) and shipped as an i32 array, while the fold itself is an on-device
-segment-sum — which batches naturally over many candidates (the
-reference's abandoned fold_subintegration_kernel intent,
-src/folding_kernels.cu).
+slow) and shipped as one unsigned byte per sample (up to 256 bins). The
+subint needs no table: subint i is the contiguous block i of
+``used // nints`` samples. So the fold is dense — each (subint, bin)
+cell is a compare-select-sum over its subint's block, which the TPU
+compiler fuses into one pass that never writes the one-hot to memory —
+with no scatter (a serial update loop on a TPU), and it batches over
+many candidates (the reference's abandoned fold_subintegration_kernel
+intent, src/folding_kernels.cu).
 """
 
 from __future__ import annotations
@@ -22,55 +26,53 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def phase_bin_dtype(nbins: int) -> np.dtype:
+    """The bin table's dtype: the smallest unsigned integer that holds
+    nbins - 1 (uint8 up to 256 bins)."""
+    return np.min_scalar_type(nbins - 1)
+
+
 def fold_bins_np(
     nsamps: int, tsamp: float, period: float, nbins: int, nints: int
 ) -> np.ndarray:
-    """Exact (f64) flattened (subint*nbins + phase_bin) index per sample.
+    """Exact (f64) phase bin of each sample.
 
     Samples beyond nints*(nsamps//nints) are dropped, like the kernel's
-    per-block ranges. Returns (nints*(nsamps//nints),) int32.
+    per-block ranges. Returns (nints*(nsamps//nints),) of
+    :func:`phase_bin_dtype`.
     """
-    nsps = nsamps // nints
-    used = nsps * nints
+    used = nints * (nsamps // nints)
     jj = np.arange(used, dtype=np.float64)
     frac = np.mod(jj * (tsamp / period), 1.0)
-    bins = np.floor(frac * nbins).astype(np.int32)
-    subs = (np.arange(used) // nsps).astype(np.int32)
-    return subs * nbins + bins
+    return np.floor(frac * nbins).astype(phase_bin_dtype(nbins))
 
 
 @partial(jax.jit, static_argnames=("nbins", "nints"))
 @jax.named_scope("fold.profile")
 def fold_time_series(
     x: jnp.ndarray,  # (..., used_nsamps) resampled time series
-    flat_bins: jnp.ndarray,  # (..., used_nsamps) int32 from fold_bins_np
+    phase_bins: jnp.ndarray,  # (..., used_nsamps) from fold_bins_np
     *,
     nbins: int,
     nints: int,
 ) -> jnp.ndarray:
-    """Segment-sum fold -> (..., nints, nbins), value = sum/(1+hits)."""
-    nseg = nints * nbins
-
-    def one(xi, bi):
-        sums = jax.ops.segment_sum(xi, bi, num_segments=nseg)
-        counts = jax.ops.segment_sum(jnp.ones_like(xi), bi, num_segments=nseg)
-        return (sums / (counts + 1.0)).reshape(nints, nbins)
-
-    batch = x.shape[:-1]
-    if batch:
-        flat = x.reshape(-1, x.shape[-1])
-        fb = flat_bins.reshape(-1, x.shape[-1])
-        out = jax.vmap(one)(flat, fb)
-        return out.reshape(*batch, nints, nbins)
-    return one(x, flat_bins)
+    """Dense fold -> (..., nints, nbins), value = sum/(1+hits)."""
+    blocks = (*x.shape[:-1], nints, 1, x.shape[-1] // nints)
+    hit = phase_bins.reshape(blocks) == jnp.arange(
+        nbins, dtype=phase_bins.dtype
+    )[:, None]
+    sums = jnp.where(hit, x.reshape(blocks), 0.0).sum(axis=-1)
+    hits = hit.sum(axis=-1, dtype=x.dtype)
+    return sums / (hits + 1.0)
 
 
 def fold_time_series_np(
     x: np.ndarray, nsamps: int, tsamp: float, period: float, nbins: int, nints: int
 ) -> np.ndarray:
     """NumPy f64 oracle of the CUDA fold, count-bias included."""
-    flat = fold_bins_np(nsamps, tsamp, period, nbins, nints)
-    used = len(flat)
+    bins = fold_bins_np(nsamps, tsamp, period, nbins, nints)
+    used = len(bins)
+    flat = np.arange(used) // (used // nints) * nbins + bins
     sums = np.bincount(flat, weights=x[:used].astype(np.float64), minlength=nints * nbins)
     counts = np.bincount(flat, minlength=nints * nbins) + 1.0
     return (sums / counts).reshape(nints, nbins)
@@ -90,7 +92,7 @@ def _param_fold_time_series(ctx):
         return None
     return (
         fold_time_series,
-        (sds((used,), "float32"), sds((used,), "int32")),
+        (sds((used,), "float32"), sds((used,), phase_bin_dtype(ctx.fold_nbins))),
         {"nbins": ctx.fold_nbins, "nints": ctx.fold_nints},
     )
 
@@ -99,7 +101,7 @@ register_program(
     "ops.fold.fold_time_series",
     lambda: (
         fold_time_series,
-        (sds((1024,), "float32"), sds((1024,), "int32")),
+        (sds((1024,), "float32"), sds((1024,), "uint8")),
         {"nbins": 16, "nints": 4},
     ),
     param=_param_fold_time_series,

@@ -22,7 +22,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .fold import fold_time_series
+from .fold import fold_time_series, phase_bin_dtype
 from .resample import resample_accel_quadratic
 
 
@@ -30,7 +30,7 @@ from .resample import resample_accel_quadratic
 def survey_fold_batch(
     xd: jnp.ndarray,  # (B, N) f32 dereddened series, one per candidate
     afs: jnp.ndarray,  # (B,) f32 acceleration factors (a*tsamp/2c)
-    flat_bins: jnp.ndarray,  # (B, used) i32 from fold_bins_np per row
+    phase_bins: jnp.ndarray,  # (B, used) from fold_bins_np per row
     *,
     nbins: int,
     nints: int,
@@ -38,13 +38,13 @@ def survey_fold_batch(
     """Resample + fold a batch of candidates -> (B, nints, nbins).
 
     Exactly the folder's per-candidate chain (quadratic resample then
-    the segment-sum fold with the reference's 1+hits count bias), just
+    the dense fold with the reference's 1+hits count bias), just
     batched with per-row series instead of a shared one.
     """
     xr = jax.vmap(resample_accel_quadratic)(xd, afs)  # (B, N)
-    used = flat_bins.shape[-1]
+    used = phase_bins.shape[-1]
     return fold_time_series(
-        xr[:, :used], flat_bins, nbins=nbins, nints=nints
+        xr[:, :used], phase_bins, nbins=nbins, nints=nints
     )
 
 
@@ -63,7 +63,7 @@ def _param_survey_fold(ctx):
         (
             sds((ctx.fold_batch, ctx.fold_nsamps), "float32"),
             sds((ctx.fold_batch,), "float32"),
-            sds((ctx.fold_batch, used), "int32"),
+            sds((ctx.fold_batch, used), phase_bin_dtype(ctx.fold_nbins)),
         ),
         {"nbins": ctx.fold_nbins, "nints": ctx.fold_nints},
     )
@@ -76,7 +76,7 @@ register_program(
         (
             sds((4, 1024), "float32"),
             sds((4,), "float32"),
-            sds((4, 1024), "int32"),
+            sds((4, 1024), "uint8"),
         ),
         {"nbins": 16, "nints": 4},
     ),

@@ -6,6 +6,13 @@ once, then ALL of that trial's candidates are resampled and folded in
 one batched device call, and every fold across all groups is optimised
 in a single batched FoldOptimiser pass — versus the reference's strictly
 sequential per-candidate fold+optimise loop.
+
+A group's batch is padded to a multiple of ``fold_bucket`` rows so one
+compiled program serves every group. Padding costs the device a
+resample and a dense fold per row (cheap: no scatter), and costs the
+host only a row copy: the exact f64 phase-bin table is built once per
+real candidate and the padding rows copy row 0's. The telemetry counters
+``fold.rows`` and ``fold.rows_padded`` count the two kinds.
 """
 
 from __future__ import annotations
@@ -182,10 +189,15 @@ class MultiFolder:
             pos25=self.pos25,
         )
         # pad the candidate batch to a fixed width so every DM group
-        # reuses one compiled (K_pad, N) resample+fold program
+        # reuses one compiled (K_pad, N) resample+fold program; padding
+        # rows repeat row 0, and only the real rows are kept on fetch
         k = len(cand_ids)
         k_pad = int(np.ceil(k / self.fold_bucket) * self.fold_bucket)
-        ids_pad = cand_ids + [cand_ids[0]] * (k_pad - k)
+        rows = np.r_[np.arange(k), np.zeros(k_pad - k, dtype=int)]
+        ids_pad = [cand_ids[r] for r in rows]
+        tel = current_telemetry()
+        tel.incr("fold.rows", k)
+        tel.incr("fold.rows_padded", k_pad - k)
         # batched resample (the folder uses the quadratic v1 kernel,
         # folder.hpp:396 -> kernels.cu:308-332)
         # (a*tsamp) is an f32 product in the reference's launcher
@@ -200,16 +212,18 @@ class MultiFolder:
         periods = np.array(
             [1.0 / cands[ci].freq for ci in ids_pad], dtype=np.float64
         )
+        # the exact f64 bin table once per real candidate; the padding
+        # rows copy row 0's
         used = self.nints * (self.nsamps // self.nints)
-        flat_bins = np.stack(
+        phase_bins = np.stack(
             [
                 fold_bins_np(self.nsamps, self.tsamp, p, self.nbins, self.nints)
-                for p in periods
+                for p in periods[:k]
             ]
-        )
+        )[rows]
         folds = fold_time_series(
             xr[:, :used],
-            jnp.asarray(flat_bins),
+            jnp.asarray(phase_bins),
             nbins=self.nbins,
             nints=self.nints,
         )

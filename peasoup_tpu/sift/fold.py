@@ -131,8 +131,7 @@ class SurveyFolder:
             xd_cache: dict[tuple[int, int], np.ndarray] = {}
             rows_xd = np.empty((len(entries), size), dtype=np.float32)
             afs = np.empty(len(entries), dtype=np.float32)
-            used = self.nints * (size // self.nints)
-            bins = np.empty((len(entries), used), dtype=np.int32)
+            bin_rows = []
             for i, (oi, cand) in enumerate(entries):
                 obs = observations[oi]
                 _, tsamp32, _, pos5, pos25 = geoms[oi]
@@ -150,10 +149,11 @@ class SurveyFolder:
                 afs[i] = accel_factor(
                     np.asarray([cand.acc]), tsamp32
                 ).astype(np.float32)[0]
-                bins[i] = fold_bins_np(
+                bin_rows.append(fold_bins_np(
                     size, tsamp32, cand.period, self.nbins, self.nints
-                )
+                ))
             del xd_cache
+            bins = np.stack(bin_rows)
 
             lo = 0
             while lo < len(entries):

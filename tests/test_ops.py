@@ -572,6 +572,100 @@ class TestFold:
         prof = fold_time_series_np(x, n, tsamp, period, nbins, nints).mean(axis=0)
         assert abs(int(np.argmax(prof)) - 16) <= 1  # 0.25 phase -> bin 16
 
+    # tsamp = 2**-14 s is exact in binary, so a period of 16 samples
+    # puts every sample on one of 16 exact phases: most bins stay empty
+    @pytest.mark.parametrize(
+        "nbins, nints, periods, tsamp, n_pad",
+        [
+            (16, 4, (0.025, 0.0371), 0.000064, 0),
+            (16, 16, (0.025, 0.0371), 0.000064, 0),
+            (64, 4, (0.025, 0.0371), 0.000064, 0),
+            (64, 16, (0.025, 0.0371), 0.000064, 0),
+            (64, 16, (16 * 2.0**-14,), 2.0**-14, 0),
+            (64, 16, (0.025, 0.0371, 0.0062), 0.000064, 5),
+        ],
+        ids=["b16-i4", "b16-i16", "b64-i4", "b64-i16", "empty-bins",
+             "padding-rows"],
+    )
+    def test_dense_fold_matches_oracle(self, rng, nbins, nints, periods,
+                                       tsamp, n_pad):
+        """Batched dense fold against the f64 oracle, row by row;
+        padding rows that repeat row 0 fold to row 0's output exactly."""
+        n = 1 << 14
+        x = rng.normal(loc=3.0, size=(len(periods), n)).astype(np.float32)
+        bins = np.stack(
+            [fold_bins_np(n, tsamp, p, nbins, nints) for p in periods]
+        )
+        rows = np.r_[np.arange(len(periods)), np.zeros(n_pad, dtype=int)]
+        out = np.asarray(
+            fold_time_series(
+                jnp.asarray(x[rows][:, : bins.shape[1]]),
+                jnp.asarray(bins[rows]),
+                nbins=nbins,
+                nints=nints,
+            )
+        )
+        assert out.shape == (len(rows), nints, nbins)
+        for r, p in enumerate(periods):
+            oracle = fold_time_series_np(x[r], n, tsamp, p, nbins, nints)
+            np.testing.assert_allclose(out[r], oracle, rtol=1e-4)
+        if tsamp == 2.0**-14:
+            assert (out == 0).sum() == nints * (nbins - 16)
+        for r in range(len(periods), len(rows)):
+            np.testing.assert_array_equal(out[r], out[0])
+
+    @pytest.mark.parametrize("program", ["fold_time_series",
+                                         "survey_fold_batch"])
+    def test_fold_programs_hold_no_scatter(self, program):
+        from peasoup_tpu.ops.survey_fold import survey_fold_batch
+
+        if program == "fold_time_series":
+            lowered = fold_time_series.lower(
+                jnp.zeros((8, 1024), jnp.float32),
+                jnp.zeros((8, 1024), jnp.uint8), nbins=64, nints=16,
+            )
+        else:
+            lowered = survey_fold_batch.lower(
+                jnp.zeros((4, 1024), jnp.float32),
+                jnp.zeros(4, jnp.float32),
+                jnp.zeros((4, 1024), jnp.uint8), nbins=64, nints=16,
+            )
+        text = lowered.as_text()
+        assert "fold" in text and "scatter" not in text
+
+    def test_bin_tables_only_for_real_candidates(self, monkeypatch):
+        """Three candidates on one DM trial pad to a batch of eight:
+        three exact bin tables, and the counters say 3 real + 5."""
+        from peasoup_tpu import obs
+        from peasoup_tpu.core.candidates import Candidate
+        from peasoup_tpu.pipeline import folder as folder_mod
+
+        calls = []
+
+        def counting(*args):
+            calls.append(args)
+            return fold_bins_np(*args)
+
+        monkeypatch.setattr(folder_mod, "fold_bins_np", counting)
+        rng = np.random.default_rng(5)
+        trials = rng.integers(20, 45, size=(2, 4096), dtype=np.uint8)
+        cands = [
+            Candidate(dm=1.0, dm_idx=1, acc=a, snr=9.0, freq=1.0 / p)
+            for a, p in ((0.0, 0.011), (3.0, 0.017), (-4.0, 0.023))
+        ]
+        mf = folder_mod.MultiFolder(trials, 4096, 0.000256)
+        tel = obs.RunTelemetry()
+        with tel.activate():
+            out = mf.fold_outcomes(cands, len(cands))
+        assert len(out) == 3
+        assert mf.fold_bucket == 8
+        assert sorted(c[2] for c in calls) == sorted(
+            1.0 / c.freq for c in cands
+        )
+        assert tel.counters["fold.groups"] == 1
+        assert tel.counters["fold.rows"] == 3
+        assert tel.counters["fold.rows_padded"] == 5
+
 
 class TestFoldOptimise:
     def make_fold(self, nbins=64, nints=16, drift_bins=6.0, width=4):

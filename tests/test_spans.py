@@ -249,6 +249,18 @@ def test_counters_repeat_on_the_same_input(two_runs):
     assert man2["counters"]["device.fetch_bytes"] > 0
 
 
+def test_fold_counts_real_and_padding_rows(two_runs):
+    """Every fold group pads its real candidate rows to the fold
+    bucket; the two counters split the batch between them."""
+    from peasoup_tpu.pipeline.folder import MultiFolder
+
+    for man in two_runs[:2]:
+        c = man["counters"]
+        assert 1 <= c["fold.rows"] <= 2  # --npdmp 2
+        assert c["fold.rows"] + c["fold.rows_padded"] == (
+            MultiFolder.fold_bucket * c["fold.groups"])
+
+
 def test_timeline_renders_span_self_times(two_runs):
     _, man, _, _ = two_runs
     lines = render_timeline(man).splitlines()

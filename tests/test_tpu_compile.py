@@ -251,6 +251,24 @@ def test_spchain(one_chip):
     )
 
 
+def test_dense_fold(one_chip):
+    """The folder's dense fold at one DM group's batch (fold_bucket rows
+    of phase A's series, 64 bins x 16 subints): no scatter, and the
+    (rows, 16, 64, 8192) one-hot never becomes a buffer — the program
+    needs less scratch than one byte per sample."""
+    from peasoup_tpu.ops.fold import fold_time_series
+    from peasoup_tpu.pipeline.folder import MultiFolder
+
+    k, n = MultiFolder.fold_bucket, PHASES["A"]["size"]
+    args = [jax.ShapeDtypeStruct((k, n), d, sharding=one_chip)
+            for d in (jnp.float32, jnp.uint8)]
+    compiled = jax.jit(
+        lambda x, b: fold_time_series(x, b, nbins=64, nints=16)
+    ).lower(*args).compile()
+    assert "scatter" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < k * n
+
+
 def test_sharded_search_on_four_chips(topo):
     """chip_smoke --multichip's program: phase B's search with the DM
     axis over the 2x2 mesh, each chip running the one-chip kernels."""
