@@ -194,12 +194,37 @@ def resample_select_packed_planes(
     return jax.lax.optimization_barrier((oute, outo))
 
 
-def select_span(af_max: float, n: int, limit: int = 64) -> int:
+# largest shift span a select resamples; past it the gather is cheaper
+SELECT_LIMIT = 64
+
+
+def select_span(af_max: float, n: int, limit: int = SELECT_LIMIT) -> int:
     """Static shift bound for :func:`resample_select`: ceil of
     max|af|*N^2/4 plus one guard sample, or 0 when the span exceeds
     ``limit`` (caller should use the gather path instead)."""
     smax = int(np.ceil(af_max * (n / 2.0) ** 2)) + 1
     return smax if smax <= limit else 0
+
+
+def quadratic_span(afs: np.ndarray, n: int) -> int | None:
+    """Static shift bucket for the folder's quadratic resample of a
+    batch of f32 acceleration factors over an n-sample series.
+
+    The map's quad = f32(f32(i - N/2)^2 - f32(N/2 * N/2)) lies in
+    [-f32(N/2 * N/2), 0] (rounding is monotone) and reaches the low end
+    at i = N/2, so the shift rint(f32(af) * quad) is largest in size
+    there: one product per row bounds it exactly. Returns 0 when every
+    row's map is the identity (every shift rounds to 0, as in the
+    search's identity class), else that bound plus one guard sample
+    rounded up to a power of two (so a stream of batches compiles few
+    programs), or None past :data:`SELECT_LIMIT` (the caller gathers)."""
+    half = np.float32(n) / np.float32(2.0)
+    prods = np.asarray(afs, dtype=np.float32) * (half * half)
+    smax = int(np.abs(np.rint(prods)).max(initial=0))
+    if smax == 0:
+        return 0
+    span = 1 << smax.bit_length()  # >= smax + 1
+    return span if span <= SELECT_LIMIT else None
 
 
 @jax.jit

@@ -7,12 +7,23 @@ one batched device call, and every fold across all groups is optimised
 in a single batched FoldOptimiser pass — versus the reference's strictly
 sequential per-candidate fold+optimise loop.
 
-A group's batch is padded to a multiple of ``fold_bucket`` rows so one
-compiled program serves every group. Padding costs the device a
-resample and a dense fold per row (cheap: no scatter), and costs the
-host only a row copy: the exact f64 phase-bin table is built once per
-real candidate and the padding rows copy row 0's. The telemetry counters
-``fold.rows`` and ``fold.rows_padded`` count the two kinds.
+A group's batch is padded to a multiple of ``fold_bucket`` rows so
+groups share compiled programs. Padding costs the device a dense fold
+per row (cheap: no scatter), and costs the host only a row copy: the
+exact f64 phase-bin table is built once per real candidate and the
+padding rows copy row 0's. The telemetry counters ``fold.rows`` and
+``fold.rows_padded`` count the two kinds.
+
+Resample and fold run as one program whose path follows the group's
+accelerations against the series length (ops/fold.py:
+fold_accel_quadratic): a shift span up to the select's limit selects
+among shifted views (at span 0, where every shift rounds to 0, the one
+view is the series as it is), and only a span past the limit gathers.
+Each (K_pad, N) geometry compiles one program per span bucket it meets
+(0, the powers of two 2..64, and the gather): up to eight.
+``fold.resample_identity``, ``fold.resample_select`` and
+``fold.resample_gather`` count the real rows of span 0, of the larger
+spans and of the gather.
 """
 
 from __future__ import annotations
@@ -26,10 +37,10 @@ import numpy as np
 
 from ..core.candidates import Candidate
 from ..obs.telemetry import current as current_telemetry
-from ..ops.fold import fold_bins_np, fold_time_series
+from ..ops.fold import FOLD_BUCKET, fold_accel_quadratic, fold_bins_np
 from ..ops.fold_optimise import FoldOptimiser
 from ..ops.rednoise import whiten_fseries
-from ..ops.resample import accel_factor, resample_accel_quadratic
+from ..ops.resample import accel_factor, quadratic_span
 from ..plan.fft_plan import prev_power_of_two
 
 
@@ -70,7 +81,7 @@ def fold_geometry(
 class MultiFolder:
     min_period = 1e-3
     max_period = 10.0
-    fold_bucket = 8  # candidate batches padded to a multiple of this
+    fold_bucket = FOLD_BUCKET  # candidate batches padded to a multiple of this
 
     def __init__(
         self,
@@ -144,9 +155,11 @@ class MultiFolder:
         # first D2H absorbs the whole in-flight pipeline and the rest
         # are nearly free, instead of one full round trip per DM group.
         # The window is BOUNDED so peak HBM stays a few groups' worth
-        # of intermediates (each ~K_pad x nsamps f32); an unbounded
-        # queue could exhaust device memory at survey scale, and the
-        # search driver's OOM shrink-retry does not cover the folder.
+        # of intermediates (each a dereddened series and K_pad x nsamps
+        # bin bytes, and a K_pad x nsamps f32 resample on the gather
+        # path); an unbounded queue could exhaust device memory at
+        # survey scale, and the search driver's OOM shrink-retry does
+        # not cover the folder.
         max_inflight = 4
         pending = []
         all_folds, all_periods, all_cand_idx = [], [], []
@@ -191,9 +204,10 @@ class MultiFolder:
             pos5=self.pos5,
             pos25=self.pos25,
         )
-        # pad the candidate batch to a fixed width so every DM group
-        # reuses one compiled (K_pad, N) resample+fold program; padding
-        # rows repeat row 0, and only the real rows are kept on fetch
+        # pad the candidate batch to a fixed width so DM groups of one
+        # span bucket reuse one compiled (K_pad, N) resample+fold
+        # program; padding rows repeat row 0, and only the real rows
+        # are kept on fetch
         k = len(cand_ids)
         k_pad = int(np.ceil(k / self.fold_bucket) * self.fold_bucket)
         rows = np.r_[np.arange(k), np.zeros(k_pad - k, dtype=int)]
@@ -209,24 +223,30 @@ class MultiFolder:
         afs = accel_factor(
             np.asarray([cands[ci].acc for ci in ids_pad]), self.tsamp
         ).astype(np.float32)
-        xr = jax.vmap(lambda af: resample_accel_quadratic(xd, af))(
-            jnp.asarray(afs)
-        )  # (K_pad, N)
+        # the group's shift span picks the program: a select over the
+        # span (0: every row is the identity), or the gather past its
+        # limit
+        span = quadratic_span(afs, self.nsamps)
+        path = "gather" if span is None else (
+            "select" if span else "identity"
+        )
+        tel.incr(f"fold.resample_{path}", k)
         periods = np.array(
             [1.0 / cands[ci].freq for ci in ids_pad], dtype=np.float64
         )
         # the exact f64 bin table once per real candidate; the padding
         # rows copy row 0's
-        used = self.nints * (self.nsamps // self.nints)
         phase_bins = np.stack(
             [
                 fold_bins_np(self.nsamps, self.tsamp, p, self.nbins, self.nints)
                 for p in periods[:k]
             ]
         )[rows]
-        folds = fold_time_series(
-            xr[:, :used],
+        folds = fold_accel_quadratic(
+            xd,
+            jnp.asarray(afs),
             jnp.asarray(phase_bins),
+            span=span,
             nbins=self.nbins,
             nints=self.nints,
         )

@@ -614,6 +614,77 @@ class TestFold:
         for r in range(len(periods), len(rows)):
             np.testing.assert_array_equal(out[r], out[0])
 
+    @staticmethod
+    def _accel_for_shift(shift, n, tsamp):
+        """An acceleration whose quadratic map's largest shift at n
+        samples rounds to ``shift``."""
+        from peasoup_tpu.ops.resample import SPEED_OF_LIGHT
+
+        return (shift + 0.25) / (n / 2) ** 2 * 2 * SPEED_OF_LIGHT / tsamp
+
+    # the tutorial geometry (2^17 samples at 320 us) folds its whole
+    # +-5 m/s^2 grid on the identity path; the shorter series take
+    # larger accelerations to reach each span
+    @pytest.mark.parametrize(
+        "n, tsamp, shifts, accs, n_real, span",
+        [
+            (1 << 17, 3.2e-4, (), (0.0, -5.0, 5.0), 3, 0),
+            (1 << 14, 6.4e-5, (1, -1), (), 2, 2),
+            (1 << 14, 6.4e-5, (3, -2, 0), (), 3, 4),
+            (1 << 15, 6.4e-5, (8, -8, 5, 1), (), 4, 16),
+            (1 << 14, 6.4e-5, (0, 5, 0, -3, 0), (0.0,), 6, 8),
+            (1 << 14, 6.4e-5, (70, -90, 3), (), 3, None),
+            (1 << 14, 6.4e-5, (2, -1), (0.0, 2.0), 4, 4),
+        ],
+        ids=["tutorial-identity", "select-span1", "select-span3",
+             "select-span8", "mixed-identity-rows", "gather-past-limit",
+             "padding-rows"],
+    )
+    def test_fold_accel_quadratic_matches_gather(self, rng, n, tsamp,
+                                                 shifts, accs, n_real,
+                                                 span):
+        """The folder's one-program resample + fold is bitwise equal to
+        the quadratic gather followed by the dense fold, on each path;
+        rows padded to the batch of eight (repeating row 0) fold to row
+        0's output."""
+        from peasoup_tpu.ops.fold import fold_accel_quadratic
+        from peasoup_tpu.ops.resample import (
+            accel_factor, quadratic_span, resample_accel_quadratic,
+        )
+
+        nbins, nints = 64, 16
+        acc = [self._accel_for_shift(s, n, tsamp) for s in shifts]
+        acc += list(accs)
+        assert len(acc) == n_real
+        rows = np.r_[np.arange(n_real), np.zeros(8 - n_real, dtype=int)]
+        afs = accel_factor(np.asarray(acc)[rows], tsamp).astype(np.float32)
+        assert quadratic_span(afs, n) == span
+        periods = rng.uniform(0.005, 0.5, size=n_real)
+        bins = np.stack(
+            [fold_bins_np(n, tsamp, p, nbins, nints) for p in periods]
+        )[rows]
+        x = jnp.asarray(rng.normal(loc=2.0, size=n).astype(np.float32))
+        xr = jax.vmap(resample_accel_quadratic, in_axes=(None, 0))(
+            x, jnp.asarray(afs)
+        )
+        want = np.asarray(
+            fold_time_series(
+                xr[:, : bins.shape[1]], jnp.asarray(bins),
+                nbins=nbins, nints=nints,
+            )
+        )
+        got = np.asarray(
+            fold_accel_quadratic(
+                x, jnp.asarray(afs), jnp.asarray(bins),
+                span=span, nbins=nbins, nints=nints,
+            )
+        )
+        np.testing.assert_array_equal(got, want)
+        moved = [not np.array_equal(row, np.asarray(x)) for row in xr]
+        assert any(moved) == (span != 0)  # the rows really were resampled
+        for r in range(n_real, 8):
+            np.testing.assert_array_equal(got[r], got[0])
+
     @pytest.mark.parametrize("program", ["fold_time_series",
                                          "survey_fold_batch"])
     def test_fold_programs_hold_no_scatter(self, program):

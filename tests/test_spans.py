@@ -261,6 +261,46 @@ def test_fold_counts_real_and_padding_rows(two_runs):
             MultiFolder.fold_bucket * c["fold.groups"])
 
 
+RESAMPLE_PATHS = ("identity", "select", "gather")
+
+
+def test_fold_counts_rows_by_resample_path(two_runs):
+    """Each DM group folds on one resample path; the three path
+    counters split the real rows between them. In the CLI run the
+    +-5 m/s^2 grid over 2^13 samples is the identity everywhere; three
+    groups that need each path count their own rows."""
+    from peasoup_tpu.core.candidates import Candidate
+    from peasoup_tpu.ops.resample import SPEED_OF_LIGHT
+    from peasoup_tpu.pipeline.folder import MultiFolder, fold_geometry
+
+    for man in two_runs[:2]:
+        c = man["counters"]
+        assert c["fold.resample_identity"] == c["fold.rows"]
+        assert sum(c.get(f"fold.resample_{p}", 0)
+                   for p in RESAMPLE_PATHS) == c["fold.rows"]
+
+    n, tsamp = 4096, 0.000256
+    size = fold_geometry(n, tsamp)[0]  # the folded series' length
+
+    def accel(shift):  # largest quadratic shift rounds to `shift`
+        return (shift + 0.25) / (size / 2) ** 2 * 2 * SPEED_OF_LIGHT / tsamp
+
+    groups = {0: (0.0, -5.0, 5.0), 1: (accel(3), 0.0), 2: (accel(100),)}
+    cands = [
+        Candidate(dm=float(d), dm_idx=d, acc=a, snr=9.0, freq=1.0 / 0.013)
+        for d, accs in groups.items() for a in accs
+    ]
+    trials = np.random.default_rng(2).integers(
+        20, 45, size=(3, n), dtype=np.uint8)
+    tel = obs.RunTelemetry()
+    with tel.activate():
+        out = MultiFolder(trials, n, tsamp).fold_outcomes(cands, len(cands))
+    assert len(out) == len(cands)
+    assert tel.counters["fold.rows"] == 6
+    for d, path in enumerate(RESAMPLE_PATHS):
+        assert tel.counters[f"fold.resample_{path}"] == len(groups[d])
+
+
 def test_timeline_renders_span_self_times(two_runs):
     _, man, _, _ = two_runs
     lines = render_timeline(man).splitlines()

@@ -17,6 +17,7 @@ import this file.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -267,6 +268,29 @@ def test_dense_fold(one_chip):
     ).lower(*args).compile()
     assert "scatter" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < k * n
+
+
+@pytest.mark.parametrize("span", [0, 2], ids=["select-span0", "select-span2"])
+def test_fold_accel_quadratic(one_chip, span):
+    """The folder's one-program resample + fold at one DM group's batch
+    of phase A's series (the tutorial tile), on the select path at span
+    0 (the identity) and 2: no gather, no scatter, and no (rows, N) f32
+    resample temporary in device memory beside the fold."""
+    from peasoup_tpu.ops.fold import FOLD_BUCKET, fold_accel_quadratic
+
+    k, n = FOLD_BUCKET, PHASES["A"]["size"]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (((n,), jnp.float32), ((k,), jnp.float32),
+                         ((k, n), jnp.uint8))]
+    compiled = jax.jit(
+        lambda x, a, b: fold_accel_quadratic(
+            x, a, b, span=span, nbins=64, nints=16)
+    ).lower(*args).compile()
+    # HLO instructions only: the text's stack-frame table names Python
+    # functions, test names among them
+    ops = set(re.findall(r"= \S+ ([a-z-]+)\(", compiled.as_text()))
+    assert "gather" not in ops and "scatter" not in ops, ops
+    assert compiled.memory_analysis().temp_size_in_bytes < k * n * 4
 
 
 def test_sharded_search_on_four_chips(topo):
