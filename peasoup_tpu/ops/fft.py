@@ -13,14 +13,16 @@ Measured on v5e at (1416, 131072): 27.8 ms device vs 48.5 ms for
 jnp.fft.rfft — 1.75x — with max rel error 1.4e-6 vs the f64 oracle
 (~35x tighter than stock, which also tightens candidate S/N parity).
 
-Gating: the matmul path needs a power-of-two length >= _MIN_N and only
-wins on TPU-class backends (on CPU its O(N^1.5) arithmetic would bury
-pocketfft); everything else falls back to jnp.fft.rfft.
+The search runs it on TPU, feeding the interbin kernel
+(ops/pallas/interbin.py), for power-of-two lengths >= _MIN_N
+(pipeline/accel_search.py:search_route); elsewhere, and on CPU where
+its O(N^1.5) arithmetic would bury pocketfft, it runs jnp.fft.rfft.
+:func:`rfft_pow2_matmul_parts` is the jnp twin the kernels' probes
+check against.
 """
 
 from __future__ import annotations
 
-import os as _os
 from functools import lru_cache
 
 import jax
@@ -29,26 +31,11 @@ import numpy as np
 
 _MIN_N = 1 << 14
 
-# Matmul precision for the packed four-step DFT einsums. Measured trade
-# (NOTES.md round-4 continuation): the chain is layout-bound, so HIGH
-# buys only ~3 ms while perturbing the S/N chain the acc-tie parity
-# analysis is anchored to — HIGHEST stays the default; the knob records
-# the option. Read and validated ONCE at import, like the module's
-# other knobs (PEASOUP_MATMUL_FFT): it feeds traced
-# code, so a post-compile change could never take effect anyway — set
-# it before the first import.
-_PREC_CHOICES = {
-    "highest": jax.lax.Precision.HIGHEST,
-    "high": jax.lax.Precision.HIGH,
-    "default": jax.lax.Precision.DEFAULT,
-}
-_PREC_NAME = _os.environ.get("PEASOUP_FFT_PRECISION", "highest").lower()
-if _PREC_NAME not in _PREC_CHOICES:
-    raise ValueError(
-        f"PEASOUP_FFT_PRECISION must be one of {sorted(_PREC_CHOICES)}, "
-        f"got {_PREC_NAME!r}"
-    )
-_PRECISION = _PREC_CHOICES[_PREC_NAME]
+# Matmul precision for the packed four-step DFT einsums. The chain is
+# layout-bound, so HIGH would buy only ~3 ms while perturbing the S/N
+# chain the acc-tie parity analysis is anchored to (NOTES.md round-4
+# continuation).
+_PRECISION = jax.lax.Precision.HIGHEST
 
 
 @lru_cache(maxsize=None)
@@ -159,41 +146,3 @@ def rfft_pow2_matmul_parts(
     xr = arr + 0.5 * (c * bi - s * br)
     xi = aii - 0.5 * (c * br + s * bi)
     return xr.reshape(*batch, m + 1), xi.reshape(*batch, m + 1)
-
-
-def rfft_pow2_matmul(x: jnp.ndarray) -> jnp.ndarray:
-    """rfft of a pow2-length f32 series via the packed four-step matmul
-    DFT; returns complex64 (..., n//2+1) like jnp.fft.rfft."""
-    xr, xi = rfft_pow2_matmul_parts(x)
-    return jax.lax.complex(xr, xi)
-
-
-def _use_matmul(n: int) -> bool:
-    # Opt-in (PEASOUP_MATMUL_FFT=1): standalone the matmul rfft beats
-    # XLA's TPU FFT 1.75x at 35x better accuracy, but in the search
-    # pipeline the pack/untwist passes offset the matmul win (measured
-    # 280 vs 270 ms total device) and candidate parity is insensitive
-    # to the per-accel FFT's accuracy (the residual lives in the
-    # per-DM stats/whiten chain and CUDA's own f32 error) — so the
-    # stock FFT stays the default.  See NOTES.md.
-    import os
-
-    if os.environ.get("PEASOUP_MATMUL_FFT", "0") != "1":
-        return False
-    if n < _MIN_N or n & (n - 1):
-        return False
-    try:
-        platform = jax.default_backend()
-    except Exception:
-        return False
-    # TPU only: v5e was measured to win; on a GPU this would silently
-    # swap cuFFT for an O(N^1.5) dense DFT
-    return platform == "tpu"
-
-
-def rfft(x: jnp.ndarray) -> jnp.ndarray:
-    """Drop-in jnp.fft.rfft over the last axis, routed to the matmul
-    four-step on accelerator backends for pow2 lengths >= 2^14."""
-    if _use_matmul(x.shape[-1]):
-        return rfft_pow2_matmul(x)
-    return jnp.fft.rfft(x)

@@ -7,56 +7,29 @@ and adds only the odd-k/2^h gathers).
 
 TPU design. The reference's float index expression (int)(i*k/2^h + 0.5)
 is EXACT integer math: (i*k + 2^(h-1)) >> h (the double value is exactly
-representable, truncation == floor). Four implementations:
+representable, truncation == floor). Two implementations:
 
-* ``method="take"``: direct batched jnp.take gathers — the oracle.
-* ``method="mxu"``: the gather index map is PERIODIC in the
-  output index: writing i = q*2^h + r, src(i) = q*k + c_r with
-  c_r = (r*k + 2^(h-1)) >> h a compile-time constant <= k. So the
-  whole level-h harmonic-k gather is
+* ``method="take"``: direct batched jnp.take gathers — the oracle of
+  the tests and of the harmonic-peaks kernel's probe.
+* ``method="conv"`` (default, the search's path off TPU): every (h, k)
+  gather is a STRIDED 1-D CONVOLUTION. At output period P = 128 (one
+  full lane vector), i = q*P + r: src(i) = q*s + c_r with
+  s = P*k >> h (integral for h <= 7) and c_r = (r*k + 2^(h-1)) >> h
+  <= s. So the gather is conv_general_dilated(p[None, :, None], W,
+  stride=s, VALID) with the (s+1, 1, P) one-hot taps W[c_r, 0, r] = 1:
+  conv windows overlap natively, the MXU contraction is the window
+  (s+1 <= 121), and the (Q, P) output merges to natural bin order for
+  FREE because P is exactly the lane width. Because each tap column is
+  one-hot the result is the exact gather value (zeros add exactly),
+  and gathers are summed one `+` at a time in reference order, so
+  "conv" is bitwise-identical to "take" (tests assert it). It needs
+  Precision.HIGHEST on the spectrum operand: only the 3-term bf16
+  split (24 mantissa bits) keeps the gathered values exact; HIGH's
+  2-term split loses the low 8 mantissa bits (measured ~5e-6 rel
+  error).
 
-      out.reshape(Q, 2^h) = X @ C,   X[q, c] = p[q*k + c] (c <= k),
-      C[c, r] = [c == c_r]  (one column-wise one-hot per r)
-
-  where X is two dense strided reshapes/slices of p (contiguous
-  vector loads) and C is a tiny constant (k+1, 2^h) matrix: the
-  irregular gather becomes an MXU matmul. Because each C column is
-  one-hot, the matmul result is the exact gather value (zeros add
-  exactly), so "mxu" and "take" agree bitwise in f32 (tests assert
-  equality).
-* ``method="conv"`` (default): every (h, k) gather is a STRIDED 1-D
-  CONVOLUTION. At output period P = 128 (one full lane vector),
-  i = q*P + r: src(i) = q*s + c_r with s = P*k >> h (integral for
-  h <= 7) and c_r = (r*k + 2^(h-1)) >> h <= s. So the gather is
-  conv_general_dilated(p[None, :, None], W, stride=s, VALID) with the
-  (s+1, 1, P) one-hot taps W[c_r, 0, r] = 1: conv windows overlap
-  natively (no materialized X, no edge-column hack), the MXU
-  contraction is the window (s+1 <= 121), and the (Q, P) output
-  merges to natural bin order for FREE because P is exactly the lane
-  width. Gathers are summed one `+` at a time in reference order, so
-  "conv" is bitwise-identical to "take"/"mxu" (tests assert it).
-  Measured 3.3x faster than "mxu" at production shapes on v5e.
-* ``method="fused"``: "mxu" wastes >85% of the 128-deep MXU
-  contraction (k+1 <= 16 per matmul, 15 matmuls for nharms=4). At the
-  coarser output period 2^H (H = nharms), EVERY (h, k) gather shares
-  one row decomposition: writing i = q*2^H + r (r < 2^H),
-  src(i) = q*s + c_r with stride s = k*2^(H-h) and
-  c_r = (r*k + 2^(h-1)) >> h <= s (the split is exact because
-  q*2^H*k is divisible by 2^h). Stacking the per-(h,k) windows
-  X_hk[q, c] = p[q*s + c] (c <= s) along the contraction axis and the
-  one-hot columns into a block-diagonal-ish constant C with one output
-  column group of width 2^H per LEVEL gives all nharms levels'
-  fresh-gather sums in ONE matmul with contraction
-  sum(s_hk + 1) (= 135 for nharms=4) — near-full MXU depth. A cumsum
-  over the tiny level axis then forms the reference's cumulative sums.
-  Per-level results differ from "take" only by f32 summation order
-  (each level's odd-k gathers are summed in the MXU accumulator
-  instead of one `+` at a time).
-
-Both matmul methods need Precision.HIGHEST: only the 3-term bf16
-operand split (24 mantissa bits) keeps products with the 0/1
-constants — and therefore the gathered values — exact; HIGH's 2-term
-split loses the low 8 mantissa bits (measured ~5e-6 rel error).
+On TPU the search sums harmonics inside the harmonic-peaks kernel
+(ops/pallas/harmpeaks.py) instead.
 """
 
 from __future__ import annotations
@@ -66,32 +39,6 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-@lru_cache(maxsize=None)
-def _onehot_matrix(k: int, h: int) -> np.ndarray:
-    """(k+1, 2^h) f32 with C[c, r] = 1 iff (r*k + 2^(h-1)) >> h == c."""
-    r = np.arange(1 << h)
-    c_r = (r * k + (1 << (h - 1))) >> h
-    C = np.zeros((k + 1, 1 << h), dtype=np.float32)
-    C[c_r, r] = 1.0
-    return C
-
-
-def _gather_mxu(p: jnp.ndarray, nbins_pad: int, k: int, h: int) -> jnp.ndarray:
-    """out[..., i] = p[..., (i*k + 2^(h-1)) >> h] for i < nbins_pad via
-    strided reshapes + a constant one-hot matmul (p is pre-padded so all
-    slices below are in range)."""
-    q_count = nbins_pad >> h
-    body = p[..., : q_count * k].reshape(*p.shape[:-1], q_count, k)
-    # edge column c == k: p[(q+1)*k], hit when k <= 2^(h-1)
-    edge = p[..., k : k * (q_count + 1) : k][..., None]
-    x = jnp.concatenate([body, edge], axis=-1)  # (..., Q, k+1)
-    C = jnp.asarray(_onehot_matrix(k, h))
-    out = jnp.einsum(
-        "...qc,cr->...qr", x, C, precision=jax.lax.Precision.HIGHEST
-    )
-    return out.reshape(*p.shape[:-1], nbins_pad)
 
 
 _CONV_P = 128  # conv output period = the f32 lane width
@@ -118,7 +65,7 @@ def _gather_conv(x: jnp.ndarray, Q: int, k: int, h: int) -> jnp.ndarray:
     # representable in ONE bf16 term — so DEFAULT on that side halves
     # the MXU pass count while staying BITWISE equal (each output is a
     # single 1.0*x product; measured equal on v5e, gated by the
-    # bitwise ==take/mxu twin tests)
+    # bitwise ==take twin tests)
     g = jax.lax.conv_general_dilated(
         x, jnp.asarray(_conv_taps(k, h)),
         window_strides=(s,), padding="VALID",
@@ -128,83 +75,23 @@ def _gather_conv(x: jnp.ndarray, Q: int, k: int, h: int) -> jnp.ndarray:
     return g[:, :Q]  # (rows, Q, P)
 
 
-@lru_cache(maxsize=None)
-def _fused_blocks(nharms: int) -> tuple[tuple[tuple[int, int, int], ...], np.ndarray]:
-    """Contraction-block layout for the fused formulation.
-
-    Returns (blocks, C): blocks is a tuple of (h, k, s) with
-    s = k*2^(nharms-h) for every level h in 1..nharms and odd k < 2^h;
-    C is the (sum(s+1), nharms*2^nharms) f32 constant with
-    C[base_hk + c, (h-1)*2^nharms + r] = 1 iff (r*k + 2^(h-1)) >> h == c.
-    """
-    H = nharms
-    blocks = []
-    for h in range(1, H + 1):
-        for k in range(1, 1 << h, 2):
-            blocks.append((h, k, k << (H - h)))
-    K = sum(s + 1 for _, _, s in blocks)
-    C = np.zeros((K, H << H), dtype=np.float32)
-    base = 0
-    r = np.arange(1 << H)
-    for h, k, s in blocks:
-        c_r = (r * k + (1 << (h - 1))) >> h
-        C[base + c_r, ((h - 1) << H) + r] = 1.0
-        base += s + 1
-    return tuple(blocks), C
-
-
-def _fused_level_sums(p: jnp.ndarray, nharms: int) -> jnp.ndarray:
-    """(..., nharms, nbins_pad) per-LEVEL fresh-gather sums
-    sum_{k odd < 2^h} p[(i*k + 2^(h-1)) >> h] via one MXU matmul.
-    ``p`` must be padded so indices up to Q*max(s) are in range."""
-    blocks, C = _fused_blocks(nharms)
-    H = nharms
-    nbins_pad = (p.shape[-1] - 1) >> H << H  # caller pads to mult + 1
-    Q = nbins_pad >> H
-    cols = []
-    for _, _, s in blocks:
-        # window X_hk[q, c] = p[q*s + c], c in [0, s]: a contiguous
-        # reshape for c < s plus one strided slice for the edge c == s
-        cols.append(p[..., : Q * s].reshape(*p.shape[:-1], Q, s))
-        cols.append(p[..., s : s * Q + 1 : s][..., None])
-    x = jnp.concatenate(cols, axis=-1)  # (..., Q, K)
-    out = jnp.einsum(
-        "...qc,cr->...qr", x, jnp.asarray(C),
-        # one-hot C is exact in a single bf16 term (see _gather_conv)
-        precision=(jax.lax.Precision.HIGHEST, jax.lax.Precision.DEFAULT),
-    )  # (..., Q, H*2^H)
-    out = out.reshape(*p.shape[:-1], Q, H, 1 << H)
-    out = jnp.moveaxis(out, -2, -3)  # (..., H, Q, 2^H)
-    return out.reshape(*p.shape[:-1], H, nbins_pad)
-
-
-@partial(jax.jit, static_argnames=("nharms", "method", "scaled", "block_align"))
+@partial(jax.jit, static_argnames=("nharms", "method", "scaled"))
 def harmonic_sums(
     p: jnp.ndarray, *, nharms: int = 4, method: str = "conv",
-    scaled: bool = True, block_align: int = 0,
+    scaled: bool = True,
 ) -> list[jnp.ndarray]:
     """Cumulative fractional-harmonic sums of a spectrum.
 
     Args:
       p: (..., nbins) float32 spectrum (normalised).
       nharms: number of fold levels (<= 5, like the unrolled kernel).
-      method: "conv" (one strided conv per (level, harmonic); fastest),
-        "mxu" (one one-hot matmul per (level, harmonic)), "take"
-        (direct gather) — all three bitwise-identical — or "fused"
-        (all levels in one near-full-depth MXU matmul; differs only
-        in f32 summation order).
+      method: "conv" (one strided conv per (level, harmonic)) or
+        "take" (direct gather); bitwise-identical.
       scaled: apply the reference's rsqrt(2^h) per-level factor here.
-        False skips it (one full HBM pass per level) for consumers that
-        scale downstream, e.g. the Pallas peaks kernel scaling in VMEM.
-      block_align: conv method only — when > 0, levels come back PADDED
-        to a multiple of this (garbage past ``nbins``: the pad region's
-        gathers read real low bins) so a downstream blocked consumer
-        (the Pallas peaks kernel) needs no per-level pad pass; bins
-        below ``nbins`` are bitwise identical to the unpadded result.
+        False skips it for consumers that scale downstream.
 
-    Returns a list of ``nharms`` arrays shaped like ``p`` (last axis
-    padded when ``block_align``); entry h-1 is the 2^h-harmonic sum,
-    scaled by rsqrt(2^h) unless ``scaled=False``.
+    Returns a list of ``nharms`` arrays shaped like ``p``; entry h-1 is
+    the 2^h-harmonic sum, scaled by rsqrt(2^h) unless ``scaled=False``.
     """
     if not 0 < nharms <= 5:
         raise ValueError("nharms must be in 1..5")
@@ -215,12 +102,10 @@ def harmonic_sums(
 
     if method == "conv":
         P = _CONV_P
-        align = max(P, block_align)
-        npad = -(-nbins // align) * align
+        npad = -(-nbins // P) * P
         Q = npad // P
         # src indices for i < nbins stay < nbins, so zero pad is inert
-        # for the real bins (pad-region outputs gather real low bins —
-        # garbage the caller masks or slices away)
+        # for the real bins (the pad region's outputs are sliced away)
         pp = jnp.pad(p, [(0, 0)] * (p.ndim - 1) + [(0, npad + 1 - nbins)])
         x = pp.reshape(-1, pp.shape[-1], 1)
         # accumulate IN THE CONV OUTPUT BLOCK SPACE (rows, Q, P): every
@@ -234,9 +119,7 @@ def harmonic_sums(
         for h in range(1, nharms + 1):
             for k in range(1, 1 << h, 2):  # odd: new gathers this level
                 val = val + _gather_conv(x, Q, k, h)
-            flat = val.reshape(*p.shape[:-1], npad)
-            if not block_align:
-                flat = flat[..., :nbins]
+            flat = val.reshape(*p.shape[:-1], npad)[..., :nbins]
             out.append(lvl_out(flat, h))
         return out
     if method == "take":
@@ -250,33 +133,7 @@ def harmonic_sums(
                 val = val + jnp.take(p, src, axis=-1)
             out.append(lvl_out(val, h))
         return out
-
-    align = 1 << nharms
-    nbins_pad = (nbins + align - 1) // align * align
-    # strided slices below reach at most nbins_pad + align source bins;
-    # src indices for i < nbins stay < nbins, so the zero pad is inert
-    pp = jnp.pad(p, [(0, 0)] * (p.ndim - 1) + [(0, nbins_pad + align - nbins)])
-
-    if method == "fused":
-        fresh = _fused_level_sums(pp, nharms)  # (..., H, nbins_pad)
-        cum = p[..., None, :] + jnp.cumsum(fresh[..., :nbins], axis=-2)
-        if scaled:
-            scales = jnp.asarray(
-                [2.0 ** (-h / 2.0) for h in range(1, nharms + 1)],
-                jnp.float32,
-            )
-            cum = cum * scales[:, None]
-        return [cum[..., h, :] for h in range(nharms)]
-    if method != "mxu":
-        raise ValueError(f"unknown method {method!r}")
-
-    out = []
-    val = p
-    for h in range(1, nharms + 1):
-        for k in range(1, 1 << h, 2):
-            val = val + _gather_mxu(pp, nbins_pad, k, h)[..., :nbins]
-        out.append(lvl_out(val, h))
-    return out
+    raise ValueError(f"unknown method {method!r}")
 
 
 # --- audit registry (the ShapeCtx hook rebuilds the conv chain at a

@@ -89,82 +89,6 @@ def probe_pallas_resample(n: int, block: int) -> bool:
 
 
 @lru_cache(maxsize=None)
-def probe_pallas_peaks(nbins: int, nlev: int, max_peaks: int) -> bool:
-    """REAL compile+run probe of the fused threshold+cluster kernel at
-    the production bin count (cached). Oracle-checked against the jnp
-    find_peaks_device + cluster_peaks_device pair on data that
-    exercises crossings, clusters, gaps, and window edges."""
-    if not backend_supports_pallas():
-        return False
-    with _on_chip("peaks", nbins=nbins, nlev=nlev, max_peaks=max_peaks):
-        import numpy as np
-        import jax.numpy as jnp
-
-        from .peaks import find_cluster_peaks_multi
-        from ..peaks import cluster_peaks_device, find_peaks_device
-
-        rng = np.random.default_rng(0)
-        # sub-threshold noise + a planted comb: the crossing count is
-        # set by the comb alone (a few hundred), so the jnp oracle's
-        # fixed raw compaction below never overflows at ANY nbins —
-        # a chi-squared noise floor would overflow it for long
-        # observations and silently fail the probe
-        s = np.abs(rng.normal(size=(9, nbins))).astype(np.float32)
-        s[::3, :: max(1, nbins // 97)] += 30.0  # comb of crossings
-        s[1, nbins // 2 : nbins // 2 + 400 : 4] += 20.0  # dense cluster run
-        lo, hi = nbins // 10, nbins - nbins // 16
-        windows = np.tile(
-            np.asarray([[lo, hi]], np.int32), (nlev, 1)
-        )
-        # probe the PRODUCTION input configuration: levels arrive
-        # block-aligned with a GARBAGE tail past the true nbins
-        # (harmonic_sums block_align) plus the explicit nbins override —
-        # the pad region carries huge values so a masking/sentinel
-        # regression in the kernel fails the probe, not production
-        from .peaks import PEAKS_BLOCK
-
-        npad = -(-nbins // PEAKS_BLOCK) * PEAKS_BLOCK
-        sp = jnp.asarray(
-            np.pad(s, ((0, 0), (0, npad - nbins)), constant_values=1e9)
-        )
-        # probe the MULTI-level kernel (the production path): every
-        # level gets a scaled view of the same data, in-kernel scales
-        # matching the jnp oracle's pre-scaled inputs bitwise
-        scales = tuple(
-            1.0 if lv == 0 else 2.0 ** (-lv / 2.0) for lv in range(nlev)
-        )
-        ci, cs, rc, cc = find_cluster_peaks_multi(
-            [sp] * nlev, jnp.asarray(windows),
-            threshold=9.0, max_peaks=max_peaks, scales=scales,
-            nbins=nbins,
-        )
-        sp = sp[:, :nbins]  # the jnp oracle below sees the true bins
-        ci, cs, rc, cc = map(np.asarray, (ci, cs, rc, cc))
-        ok = True
-        for lv in range(nlev):
-            if not ok:
-                break
-            sc = jnp.asarray(sp * jnp.float32(scales[lv]))
-            i_, s_, c_ = find_peaks_device(
-                sc, jnp.float32(9.0), jnp.int32(lo), jnp.int32(hi),
-                max_peaks=1 << 14,
-            )
-            ji, js, jc = cluster_peaks_device(i_, s_, jnp.int32(nbins))
-            ji, js, jc, c_ = map(np.asarray, (ji, js, jc, c_))
-            ok = np.array_equal(rc[:, lv], c_) and np.array_equal(
-                cc[:, lv], jc
-            )
-            for r in range(s.shape[0]):
-                if not ok:
-                    break
-                k = min(int(jc[r]), max_peaks)
-                ok = np.array_equal(
-                    ci[r, lv, :k], ji[r, :k]
-                ) and np.array_equal(cs[r, lv, :k], js[r, :k])
-        return _oracle(ok)
-
-
-@lru_cache(maxsize=None)
 def probe_pallas_interbin(size: int, block: int) -> bool:
     """REAL compile+run probe of the fused untwist+interbin+normalise
     kernel (ops/pallas/interbin.py) at a small pow2 shape, gated on
@@ -237,7 +161,9 @@ def probe_pallas_harmpeaks(nbins: int, nharms: int, max_peaks: int) -> bool:
 
         nlev = nharms + 1
         rng = np.random.default_rng(0)
-        # sub-threshold noise + planted combs (see probe_pallas_peaks);
+        # sub-threshold noise + planted combs: the crossing count is
+        # set by the combs alone (a few hundred), so the jnp oracle's
+        # fixed raw compaction below never overflows at ANY nbins;
         # values vary across the full spectrum so every stream's gather
         # path is data-sensitive
         s = np.abs(rng.normal(size=(9, nbins))).astype(np.float32)

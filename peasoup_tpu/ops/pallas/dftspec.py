@@ -25,8 +25,8 @@ Key structural tricks:
     expansion (x = xh+xm by exact 16-bit word truncation, w likewise;
     passes xm*wh, xh*wm, xh*wh summed small-to-large) — the same
     accuracy class as XLA's Precision.HIGH (~1.5e-5 rel), which the
-    golden-recall gate accepts END TO END: the PEASOUP_FFT_PRECISION=
-    high experiment measured recall 1.0 with exact ranks and ~0 dS/N
+    golden-recall gate accepts END TO END: the einsum chain run at
+    Precision.HIGH measured recall 1.0 with exact ranks and ~0 dS/N
     deltas (NOTES.md round-4 continuation). A full six-pass
     HIGHEST-class variant was built and measured — 41 ms vs the
     chain's 46, all of the win eaten by split/pass overhead — so the
@@ -41,8 +41,9 @@ Key structural tricks:
     ACCURACY-CLASS gate against the exact HIGHEST einsum chain:
     per-bin |amp - amp_ref| / (|amp_ref| + rms) max <= 1e-3 and
     99.9%-quantile <= 2e-4 (measured 3.7e-4 / 5.7e-5; the golden-
-    recall gate remains the end-to-end arbiter). PEASOUP_FUSED_DFT=0
-    restores the einsum + interbin-kernel chain (exact HIGHEST).
+    recall gate remains the end-to-end arbiter). Where the shape does
+    not admit it, the search runs the einsum + interbin-kernel chain
+    (exact HIGHEST; pipeline/accel_search.py:search_route).
   * The mirror term Z[M-k] is built with one-hot reversals: plane
     order by an anti-identity dot on the sublane dim, lane order by
     the aligned-slice + ANTI-128 dot (interbin.py's _rev_lanes

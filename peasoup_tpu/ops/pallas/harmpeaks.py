@@ -1,7 +1,8 @@
 """Pallas TPU mega-kernel: harmonic summing fused into the peaks walk.
 
-Replaces the harmonic_sums(method="conv") -> find_cluster_peaks_multi
-pair for the production search. The conv formulation is XLA-optimal
+Replaces harmonic_sums + the per-level find_peaks_device ->
+cluster_peaks_device pair in the TPU search. The conv formulation of
+the harmonic sums (ops/harmonics.py) is XLA-optimal
 but HBM-bound BY CONSTRUCTION: the cumulative val chain is 31 (nharms=5)
 or 15 (nharms=4) separate conv+add HLOs, each of which must round-trip
 the full (rows, npad) accumulator through HBM — measured 38.7 GB /
@@ -25,11 +26,12 @@ carved from VMEM as an ALIGNED 256-wide slice (pure vreg renames) plus
 one pltpu.roll by the group's phase g*s mod 128 (Mosaic CRASHES on
 misaligned 128-slices — probed r4), then all G groups are gathered by
 one shared constant one-hot (128, 128) MXU dot. One-hot matmul is an
-exact gather (harmonics.py "conv"/"mxu" argument; Mosaic rejects
+exact gather (harmonics.py "conv" argument; Mosaic rejects
 per-operand precision, and at plain HIGHEST the one-hot side's extra
 split terms are exact zeros), so accumulated level values are BITWISE
-identical to method="take" and the walk outputs are bitwise identical
-to find_cluster_peaks_multi on conv-produced levels.
+identical to method="take" and the walk outputs (the state machine of
+peaks.py) are bitwise identical to find_peaks_device +
+cluster_peaks_device on those levels.
 
 Accumulation order per element matches the reference exactly: base
 spectrum, then levels h ascending, odd k ascending within each level —
@@ -243,14 +245,14 @@ def find_harmonic_cluster_peaks(
     interpret: bool = False,
     nbins: int | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One-dispatch equivalent of harmonic_sums(method="conv",
-    scaled=False, block_align=_BLOCK) + find_cluster_peaks_multi.
-    Returns (idxs (..., nlev, max_peaks), snrs, raw counts (..., nlev),
-    cluster counts (..., nlev)); nlev = nharms + 1, level 0 the base
-    spectrum. ``nbins`` is the TRUE bin count (idx pad sentinel);
-    windows' hi bounds are clamped to it, masking both the pad region
-    and the pad-region harmonic values (which gather real low bins,
-    exactly like the conv path's block_align garbage).
+    """One-dispatch equivalent of harmonic_sums(method="take") +
+    find_peaks_device + cluster_peaks_device on every level, scaled by
+    ``scales`` in VMEM. Returns (idxs (..., nlev, max_peaks), snrs, raw
+    counts (..., nlev), cluster counts (..., nlev)); nlev = nharms + 1,
+    level 0 the base spectrum. ``nbins`` is the TRUE bin count (idx pad
+    sentinel); windows' hi bounds are clamped to it, masking both the
+    pad region and the pad-region harmonic values (which gather real
+    low bins).
     """
     if not 0 < nharms <= 5:
         raise ValueError("nharms must be in 1..5")

@@ -21,8 +21,8 @@ scratch across a sequential k-block grid, so the whole chain is
 einsums -> one fused pass.
 
 Bin layout (matches the jnp path's pad convention): output (R, npad)
-f32 with bins k = 0..m real, k > m zeroed (npad = the peaks kernel's
-block alignment so no separate pad pass is spent downstream).
+f32 with bins k = 0..m real, k > m zeroed (npad = the harmonic-peaks
+kernel's block alignment so no separate pad pass is spent downstream).
 
 Special bins, from the real-input untwist identities:
   X[0] = Re Z[0] + Im Z[0]   (mirror wraps to Z[0] itself)

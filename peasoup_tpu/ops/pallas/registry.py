@@ -217,28 +217,6 @@ def _build_dftspec(interpret: bool = True):
     )
 
 
-def _build_peaks(interpret: bool = True):
-    import numpy as np
-
-    import jax.numpy as jnp
-
-    from .peaks import PEAKS_BLOCK, find_cluster_peaks_multi
-
-    nlev, nbins = 2, PEAKS_BLOCK
-    sp = jnp.zeros((2, nbins), jnp.float32)
-    windows = np.tile(
-        np.asarray([[8, nbins - 8]], np.int32), (nlev, 1)
-    )
-    return (
-        lambda s, w: find_cluster_peaks_multi(
-            [s] * nlev, w, threshold=9.0, max_peaks=16,
-            scales=(1.0, 0.5), nbins=nbins, interpret=interpret,
-        ),
-        (sp, jnp.asarray(windows)),
-        {},
-    )
-
-
 def _build_harmpeaks(interpret: bool = True):
     import numpy as np
 
@@ -342,18 +320,6 @@ _KERNELS: tuple[KernelSpec, ...] = (
         build=_build_dftspec,
         scalar_prefetch=0,
         lane_retile=True,
-    ),
-    KernelSpec(
-        name="pallas.peaks",
-        module="peasoup_tpu.ops.pallas.peaks",
-        entry="find_cluster_peaks_multi",
-        probe="probe_pallas_peaks",
-        twin="peasoup_tpu.ops.peaks.find_peaks_device",
-        fallback=(
-            "jnp find_peaks_device + cluster_peaks_device per level"
-        ),
-        build=_build_peaks,
-        scalar_prefetch=0,
     ),
     KernelSpec(
         name="pallas.harmpeaks",

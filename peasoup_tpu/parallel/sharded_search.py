@@ -21,7 +21,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import get_logger
 from ..obs.telemetry import current as current_telemetry
-from ..pipeline.accel_search import AccelSearchPeaks, search_block_core
+from ..pipeline.accel_search import (
+    CPU_ROUTE,
+    AccelSearchPeaks,
+    SearchRoute,
+    search_block_core,
+)
 
 log = get_logger("parallel.sharded_search")
 
@@ -33,33 +38,27 @@ def make_sharded_search_fn(
     axis: str = "dm",
     pallas_block: int = 0,
     select_smax: int = 0,
-    pallas_peaks: bool = False,
-    fused_interbin: bool = False,
-    mega_harm: bool = False,
-    fused_dft: bool = False,
-    fused_spec: bool = False,
+    route: SearchRoute = CPU_ROUTE,
 ):
     """Jitted (D, ...) -> (D, ...) search with D sharded over ``axis``.
 
     D must be a multiple of the mesh axis size (pad the trial block and
     the afs rows; padded rows are searched but discarded by the host).
     Each chip runs the block-batched core on its local trials with the
-    same kernel flags as the one-chip search, so both give bitwise
-    equal peaks; with ``pallas_block`` > 0 the Pallas resample kernel
+    same route as the one-chip search, so both give bitwise equal
+    peaks; with ``pallas_block`` > 0 the Pallas resample kernel
     runs per chip.
-    Cached (mesh/threshold/axis/block are hashable) so repeat runs reuse
+    Cached (mesh/threshold/axis/block/route are hashable) so repeat runs reuse
     the compiled executable like make_batched_search_fn.
     """
     log.debug(
         "building sharded search: %d-chip '%s' mesh, pallas_block=%d, "
-        "pallas_peaks=%s", mesh.shape[axis], axis, pallas_block,
-        pallas_peaks,
+        "route=%s", mesh.shape[axis], axis, pallas_block, route,
     )
     current_telemetry().event(
         "sharded_search_built", n_chips=int(mesh.shape[axis]), axis=axis,
-        pallas_block=int(pallas_block), pallas_peaks=bool(pallas_peaks),
-        mega_harm=bool(mega_harm), fused_dft=bool(fused_dft),
-        fused_spec=bool(fused_spec), process_index=int(jax.process_index()),
+        pallas_block=int(pallas_block), kernels=bool(route.kernels),
+        spectrum=route.spectrum, process_index=int(jax.process_index()),
     )
 
     @partial(
@@ -86,9 +85,7 @@ def make_sharded_search_fn(
                 threshold=threshold, size=size, nsamps_valid=nsamps_valid,
                 nharms=nharms, max_peaks=max_peaks, pos5=pos5, pos25=pos25,
                 pallas_block=pallas_block, select_smax=select_smax,
-                pallas_peaks=pallas_peaks, fused_interbin=fused_interbin,
-                mega_harm=mega_harm, fused_dft=fused_dft,
-                fused_spec=fused_spec,
+                route=route,
             )
 
         # check_vma off: the local body is collective-free, and the

@@ -18,13 +18,17 @@ Stages (reference line refs in parentheses):
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from functools import lru_cache, partial
-from typing import NamedTuple
+from typing import Literal, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+from ..ops.fft import _MIN_N
 from ..ops.harmonics import harmonic_sums
+from ..ops.pallas.dftspec import dftspec_supported
+from ..ops.pallas.peaks import PEAKS_BLOCK
 from ..ops.peaks import cluster_peaks_device, find_peaks_device
 from ..ops.rednoise import whiten_fseries
 from ..ops.resample import resample_accel
@@ -48,6 +52,54 @@ class AccelSearchPeaks(NamedTuple):
     snrs: jax.Array
     counts: jax.Array
     ccounts: jax.Array
+
+
+@dataclass(frozen=True)
+class SearchRoute:
+    """Which search program runs. ``kernels``: the TPU kernel chain,
+    the fused spectrum chain (ops/pallas/specchain.py) and the
+    harmonic-sum + peaks kernel (ops/pallas/harmpeaks.py); without it
+    the jnp twins run. ``spectrum``: how each resampled series becomes
+    a normalised spectrum — "dftspec" (one Pallas DFT + interbin
+    kernel, ops/pallas/dftspec.py), "interbin" (the four-step einsum
+    DFT + the interbin kernel, ops/pallas/interbin.py) or "fft" (jnp
+    rfft + interbin). Decided by :func:`search_route` alone."""
+
+    kernels: bool = False
+    spectrum: Literal["dftspec", "interbin", "fft"] = "fft"
+
+
+CPU_ROUTE = SearchRoute()
+
+
+def spectrum_pad(nbins: int) -> int:
+    """Bins of a spectrum padded to the harmonic-peaks kernel's block."""
+    return -(-nbins // PEAKS_BLOCK) * PEAKS_BLOCK
+
+
+def interbin_fits(size: int) -> bool:
+    """The interbin kernel's shape precondition: a power-of-two series
+    of at least 2^14 points whose half fills whole kernel blocks."""
+    return (
+        size >= _MIN_N
+        and not (size & (size - 1))
+        and (size // 2) % PEAKS_BLOCK == 0
+    )
+
+
+def search_route(backend: str, size: int, packed: bool) -> SearchRoute:
+    """The search program for a platform and series length. Off TPU the
+    jnp twins run. On TPU the kernel chain runs over the DFT kernel
+    where the shape admits it and the resample emits even/odd sample
+    planes (``packed``: the select path), else over the einsum DFT and
+    the interbin kernel, else (below 2^14 points) over the jnp rfft."""
+    if backend != "tpu":
+        return CPU_ROUTE
+    spectrum = "fft"
+    if interbin_fits(size):
+        dft = packed and dftspec_supported(size, spectrum_pad(size // 2 + 1))
+        spectrum = "dftspec" if dft else "interbin"
+    return SearchRoute(kernels=True, spectrum=spectrum)
 
 
 def _pad_trial(tim, *, size, nsamps_valid):
@@ -93,7 +145,7 @@ def _pre_spectrum_parts(tim, *, size, nsamps_valid, pos5, pos25):
 
 
 def _preprocess_block_fused(
-    tims, zapmask, *, size, nsamps_valid, pos5, pos25
+    tims, zapmask, *, size, nsamps_valid, pos5, pos25, interpret=False
 ):
     """Block-batched once-per-DM-trial stage with the spectrum-chain
     tail (deredden -> zap -> interbin) FUSED into one Pallas pass over
@@ -108,7 +160,9 @@ def _preprocess_block_fused(
             pos25=pos25,
         )
     )(tims)
-    re_d, im_d, s0 = interp_deredden_zap_pallas(re, im, med, zapmask)
+    re_d, im_d, s0 = interp_deredden_zap_pallas(
+        re, im, med, zapmask, interpret=interpret
+    )
     mean, _, std = spectrum_stats(s0)
     xd = jnp.fft.irfft(jax.lax.complex(re_d, im_d), n=size)
     return xd, mean, std
@@ -116,19 +170,19 @@ def _preprocess_block_fused(
 
 def _spectra_and_peaks(
     xr, mean, std, windows, *, threshold, nharms, max_peaks, stack_axis,
-    cluster=True, pallas_peaks=False, fused_interbin=False,
-    mega_harm=False, fused_dft=False,
+    cluster=True, route=CPU_ROUTE, interpret=False,
 ):
     """Post-resample stage: batched rfft, interbin, normalise, harmonic
     sums, per-level peak compaction (pipeline_multi.cu:216-234), and —
     with ``cluster`` — the min-gap peak clustering the reference runs
     on the host (peakfinder.hpp:27-56), kept on device so only cluster
-    peaks ever cross the host link. With ``pallas_peaks`` the
-    compaction + clustering run as the fused streaming kernel
-    (ops/pallas/peaks.py): same outputs, but idxs/snrs hold CLUSTER
+    peaks ever cross the host link. On the kernel route the harmonic
+    sums, compaction and clustering run as one Pallas kernel
+    (ops/pallas/harmpeaks.py): same outputs, but idxs/snrs hold CLUSTER
     peaks sized ``max_peaks`` while raw crossings are only counted —
     overflow then means ccounts > max_peaks, not counts. ``xr`` is
-    (..., A, size); mean/std broadcast against (..., A)."""
+    (..., A, size), or its even/odd sample planes for the kernel
+    spectra; mean/std broadcast against (..., A)."""
     # named scopes mirror the reference's NVTX ranges inside the jitted
     # program (pipeline_multi.cu:207, harmonicfolder.hpp:28): ops carry
     # the scope in their metadata, so profiler traces group them
@@ -142,58 +196,44 @@ def _spectra_and_peaks(
     else:
         size = 2 * xr[0].shape[-1] if packed else xr.shape[-1]
     nbins = size // 2 + 1
-    kernel_scales = pallas_peaks and cluster
-    # per-level rsqrt(2^h) factors, applied in VMEM by the kernel paths
-    # and pre-applied by harmonic_sums(scaled=True) on the jnp path
-    lvl_scales = (1.0,) + tuple(
-        2.0 ** (-h / 2.0) for h in range(1, nharms + 1)
-    )
+    kernels = route.kernels and cluster
+    spectrum = route.spectrum if kernels else "fft"
     with jax.named_scope("Acceleration-Loop"):
-        from ..ops.fft import _use_matmul, rfft_pow2_matmul_parts
-        from ..ops.spectrum import form_interpolated_parts
-
-        if fused_interbin and kernel_scales:
-            # matmul four-step packed DFT, then ONE Pallas pass does
-            # untwist + interbin + normalise and emits the spectrum
-            # already padded to the peaks kernel's block alignment
-            # (ops/pallas/interbin.py) — callers gate on the
-            # probe_pallas_interbin oracle
-            from ..ops.fft import packed_dft_z, packed_dft_z_parts
-            from ..ops.pallas.interbin import untwist_interbin_normalise
-            from ..ops.pallas.peaks import PEAKS_BLOCK
-
-            batch = (
-                xr[0].shape[:-2] if shaped
-                else xr[0].shape[:-1] if packed else xr.shape[:-1]
+        if spectrum == "fft":
+            s = form_interpolated(jnp.fft.rfft(xr, axis=-1))
+            s = normalise(s, mean, std)
+        else:
+            # the kernels emit the spectrum already padded to the
+            # harmonic-peaks kernel's block alignment
+            batch = xr[0].shape[:-2] if shaped else (
+                xr[0].shape[:-1] if packed else xr.shape[:-1]
             )
-            npad = -(-nbins // PEAKS_BLOCK) * PEAKS_BLOCK
-            if fused_dft and packed:
+            npad = spectrum_pad(nbins)
+            if spectrum == "dftspec":
                 # one Pallas kernel does DFT + untwist + interbin +
                 # normalise per row stripe in VMEM (ops/pallas/
-                # dftspec.py): kills the einsum layout copies and the
-                # Z round trip. 3-pass HIGH-class accuracy, validated
-                # end to end by the golden-recall gate (probe-gated;
-                # PEASOUP_FUSED_DFT=0 restores this einsum chain).
-                # Producers send (.., n1, n2) pre-shaped planes so the
-                # select writes the kernel's tile layout directly
-                # (flat planes would relayout-copy here)
+                # dftspec.py): no einsum layout copies, no Z round
+                # trip. 3-pass HIGH-class accuracy, validated end to
+                # end by the golden-recall gate. The select writes the
+                # (.., n1, n2) planes in the kernel's tile layout
                 from ..ops.pallas.dftspec import dft_untwist_interbin
 
-                if shaped:
-                    n1, n2 = xr[0].shape[-2:]
-                    pe = xr[0].reshape(-1, n1, n2)
-                    po = xr[1].reshape(-1, n1, n2)
-                else:
-                    half = xr[0].shape[-1]
-                    pe = xr[0].reshape(-1, half)
-                    po = xr[1].reshape(-1, half)
+                n1, n2 = xr[0].shape[-2:]
+                pe = xr[0].reshape(-1, n1, n2)
+                po = xr[1].reshape(-1, n1, n2)
                 s = dft_untwist_interbin(
                     pe, po,
                     jnp.broadcast_to(mean, batch).reshape(-1),
                     jnp.broadcast_to(std, batch).reshape(-1),
-                    npad=npad,
+                    npad=npad, interpret=interpret,
                 ).reshape(*batch, npad)
             else:
+                # matmul four-step packed DFT, then ONE Pallas pass
+                # does untwist + interbin + normalise
+                # (ops/pallas/interbin.py)
+                from ..ops.fft import packed_dft_z, packed_dft_z_parts
+                from ..ops.pallas.interbin import untwist_interbin_normalise
+
                 zr, zi = (
                     packed_dft_z_parts(*xr) if packed else packed_dft_z(xr)
                 )
@@ -201,27 +241,20 @@ def _spectra_and_peaks(
                     zr, zi,
                     jnp.broadcast_to(mean, batch).reshape(-1),
                     jnp.broadcast_to(std, batch).reshape(-1),
-                    npad=npad, block=PEAKS_BLOCK,
+                    npad=npad, block=PEAKS_BLOCK, interpret=interpret,
                 ).reshape(*batch, npad)
-        elif _use_matmul(xr.shape[-1]):
-            # matmul four-step rfft as lazy (re, im) parts: the untwist
-            # fuses into the interbin pass (no complex materialisation)
-            s = form_interpolated_parts(*rfft_pow2_matmul_parts(xr))
-            s = normalise(s, mean, std)
-        else:
-            s = form_interpolated(jnp.fft.rfft(xr, axis=-1))
-            s = normalise(s, mean, std)
-    if mega_harm and pallas_peaks and cluster:
+    if kernels:
         # harmonic summing FUSED into the peaks walk: one Pallas
-        # dispatch gathers, accumulates, scales, thresholds and
-        # clusters every level in VMEM (ops/pallas/harmpeaks.py) —
-        # no conv val-chain HBM round trips, no level arrays, no
-        # layout copies. Bitwise-equal outputs (probe-gated).
+        # dispatch gathers, accumulates, scales (rsqrt(2^h) per level),
+        # thresholds and clusters every level in VMEM
+        # (ops/pallas/harmpeaks.py). Bitwise-equal outputs (probe-gated).
+        lvl_scales = (1.0,) + tuple(
+            2.0 ** (-h / 2.0) for h in range(1, nharms + 1)
+        )
         with jax.named_scope("Harmonic summing"):
             from ..ops.pallas.harmpeaks import find_harmonic_cluster_peaks
-            from ..ops.pallas.peaks import PEAKS_BLOCK
 
-            npad = -(-nbins // PEAKS_BLOCK) * PEAKS_BLOCK
+            npad = spectrum_pad(nbins)
             if s.shape[-1] != npad:
                 s = jnp.pad(
                     s, [(0, 0)] * (s.ndim - 1) + [(0, npad - s.shape[-1])]
@@ -229,6 +262,7 @@ def _spectra_and_peaks(
             i_, s_, c_, cc_ = find_harmonic_cluster_peaks(
                 s, windows, nharms=nharms, threshold=threshold,
                 max_peaks=max_peaks, scales=lvl_scales, nbins=nbins,
+                interpret=interpret,
             )
         nb = s.ndim - 1  # batch rank
         return AccelSearchPeaks(
@@ -238,46 +272,9 @@ def _spectra_and_peaks(
             ccounts=jnp.moveaxis(cc_, nb, stack_axis),
         )
 
-    # the fused kernel applies the per-level rsqrt(2^h) factor in VMEM
-    # (one fewer full HBM pass per level); the jnp path scales here.
-    # For the kernel path the levels also come back pre-padded to the
-    # kernel's block size (block_align) so no per-level pad pass is
-    # spent — the pad region is garbage the kernel's windows mask.
     with jax.named_scope("Harmonic summing"):
-        if kernel_scales:
-            from ..ops.pallas.peaks import PEAKS_BLOCK
-
-            sums = harmonic_sums(
-                s, nharms=nharms, scaled=False, block_align=PEAKS_BLOCK
-            )
-            npad = sums[0].shape[-1]
-            if s.shape[-1] != npad:
-                s = jnp.pad(
-                    s, [(0, 0)] * (s.ndim - 1) + [(0, npad - nbins)]
-                )
-        else:
-            sums = harmonic_sums(s, nharms=nharms, scaled=True)
+        sums = harmonic_sums(s, nharms=nharms, scaled=True)
     levels = [s] + sums
-
-    if pallas_peaks and cluster:
-        # ONE kernel dispatch walks every level's threshold+cluster
-        # machine together (ops/pallas/peaks.py:find_cluster_peaks_multi)
-        from ..ops.pallas.peaks import find_cluster_peaks_multi
-
-        with jax.named_scope("Peaks"):
-            i_, s_, c_, cc_ = find_cluster_peaks_multi(
-                levels, windows, threshold=threshold, max_peaks=max_peaks,
-                scales=lvl_scales, nbins=nbins,
-            )
-        # kernel emits (..., nlev, ...); the NamedTuple wants the level
-        # axis at stack_axis
-        nb = len(levels[0].shape) - 1  # batch rank
-        return AccelSearchPeaks(
-            idxs=jnp.moveaxis(i_, nb, stack_axis),
-            snrs=jnp.moveaxis(s_, nb, stack_axis),
-            counts=jnp.moveaxis(c_, nb, stack_axis),
-            ccounts=jnp.moveaxis(cc_, nb, stack_axis),
-        )
 
     idxs, snrs, counts, ccounts = [], [], [], []
     with jax.named_scope("Peaks"):
@@ -376,11 +373,7 @@ def search_block_core(
     pallas_interpret: bool = False,
     select_smax: int = 0,
     cluster: bool = True,
-    pallas_peaks: bool = False,
-    fused_interbin: bool = False,
-    mega_harm: bool = False,
-    fused_dft: bool = False,
-    fused_spec: bool = False,
+    route: SearchRoute = CPU_ROUTE,
 ) -> AccelSearchPeaks:
     """Block-batched search: all per-DM preprocessing vmapped, then the
     (D, A) accel grid processed as single batched array programs. With
@@ -388,18 +381,23 @@ def search_block_core(
     windowed-select kernel (ops/pallas/resample.py); with
     ``select_smax`` > 0 as the gather-free jnp select
     (ops/resample.py:resample_select); otherwise the jnp gather twin.
-    Results are bitwise identical in all three modes. ``fused_spec``
-    routes the once-per-trial deredden -> zap -> interbin tail through
-    the fused Pallas pass (probe-gated by the caller).
+    Results are bitwise identical in all three modes. ``route`` (see
+    :func:`search_route`) picks the spectrum and peak programs; on the
+    kernel route the once-per-trial deredden -> zap -> interbin tail
+    runs as the fused Pallas pass. ``pallas_interpret`` runs every
+    Pallas kernel in interpret mode (tests).
     """
+    # the kernel spectra feed only the harmonic-peaks kernel, which
+    # emits cluster peaks
+    spectrum = route.spectrum if route.kernels and cluster else "fft"
     # named scopes mirror the roofline stage classification
     # (tools/scope_trace STAGE_RULES), so profiler traces attribute
     # this one jitted program's device time per stage
     with jax.named_scope("Spectrum-Chain"):
-        if fused_spec:
+        if route.kernels:
             xd, mean, std = _preprocess_block_fused(
                 tims, zapmask, size=size, nsamps_valid=nsamps_valid,
-                pos5=pos5, pos25=pos25,
+                pos5=pos5, pos25=pos25, interpret=pallas_interpret,
             )
         else:
             xd, mean, std = jax.vmap(
@@ -416,33 +414,27 @@ def search_block_core(
             xr = resample_block_pallas(
                 xd, afs, block=pallas_block, interpret=pallas_interpret
             )
+        elif select_smax > 0 and spectrum == "dftspec":
+            # the DFT kernel wants even/odd planes PRE-SHAPED (.., n1,
+            # n2): the select writes its tile layout directly, with no
+            # stride-2 deinterleave or relayout pass (bitwise-equal
+            # elements, ops/resample.py)
+            from ..ops.pallas.dftspec import plane_factors
+            from ..ops.resample import resample_select_packed_planes
+
+            n1, n2 = plane_factors(size // 2)
+            xr = resample_select_packed_planes(
+                xd, afs, smax=select_smax, n1=n1, n2=n2
+            )
+        elif select_smax > 0 and spectrum == "interbin":
+            # the packed einsum DFT wants even/odd planes
+            from ..ops.resample import resample_select_packed
+
+            xr = resample_select_packed(xd, afs, smax=select_smax)
         elif select_smax > 0:
-            if fused_interbin and cluster and pallas_peaks:
-                # the packed-DFT consumer wants even/odd planes:
-                # selecting straight into them skips the stride-2
-                # deinterleave relayout (bitwise-equal elements,
-                # ops/resample.py). The fused-DFT kernel additionally
-                # wants them PRE-SHAPED (.., n1, n2) so the select
-                # writes its tile layout with no relayout pass
-                # (resample_select_packed_planes)
-                if fused_dft:
-                    from ..ops.pallas.dftspec import plane_factors
-                    from ..ops.resample import (
-                        resample_select_packed_planes,
-                    )
+            from ..ops.resample import resample_select
 
-                    n1, n2 = plane_factors(size // 2)
-                    xr = resample_select_packed_planes(
-                        xd, afs, smax=select_smax, n1=n1, n2=n2
-                    )
-                else:
-                    from ..ops.resample import resample_select_packed
-
-                    xr = resample_select_packed(xd, afs, smax=select_smax)
-            else:
-                from ..ops.resample import resample_select
-
-                xr = resample_select(xd, afs, smax=select_smax)
+            xr = resample_select(xd, afs, smax=select_smax)
         else:
             xr = jax.vmap(resample_accel)(xd, afs)  # (D, A, size)
 
@@ -451,18 +443,15 @@ def search_block_core(
     return _spectra_and_peaks(
         xr, mean[:, None], std[:, None], windows,
         threshold=threshold, nharms=nharms, max_peaks=max_peaks,
-        stack_axis=1, cluster=cluster, pallas_peaks=pallas_peaks,
-        fused_interbin=fused_interbin, mega_harm=mega_harm,
-        fused_dft=fused_dft,
+        stack_axis=1, cluster=cluster, route=route,
+        interpret=pallas_interpret,
     )
 
 
 @lru_cache(maxsize=None)
 def make_batched_search_fn(
     threshold: float, pallas_block: int = 0, select_smax: int = 0,
-    pallas_peaks: bool = False, fused_interbin: bool = False,
-    mega_harm: bool = False, fused_dft: bool = False,
-    fused_spec: bool = False,
+    route: SearchRoute = CPU_ROUTE,
 ):
     """Jitted (D, ...) -> (D, ...) search over a block of DM trials.
 
@@ -485,9 +474,7 @@ def make_batched_search_fn(
             threshold=threshold, size=size, nsamps_valid=nsamps_valid,
             nharms=nharms, max_peaks=max_peaks, pos5=pos5, pos25=pos25,
             pallas_block=pallas_block, select_smax=select_smax,
-            cluster=cluster, pallas_peaks=pallas_peaks,
-            fused_interbin=fused_interbin, mega_harm=mega_harm,
-            fused_dft=fused_dft, fused_spec=fused_spec,
+            cluster=cluster, route=route,
         )
 
     return search_dm_block

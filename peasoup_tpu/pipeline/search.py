@@ -35,6 +35,8 @@ from ..ops.dedisperse import (
     output_scale,
 )
 from ..ops.pallas import backend_supports_pallas
+from ..ops.pallas.dftspec import dftspec_supported
+from ..ops.pallas.peaks import PEAKS_BLOCK
 from ..ops.resample import accel_factor, select_span
 from ..ops.zap import birdie_mask
 from ..plan.accel_plan import AccelerationPlan
@@ -42,7 +44,13 @@ from ..plan.dm_plan import DMPlan
 from ..plan.fft_plan import choose_fft_size
 from ..utils import ProgressBar
 from ..utils.device import device_bytes_limit
-from .accel_search import make_batched_search_fn
+from .accel_search import (
+    CPU_ROUTE,
+    interbin_fits,
+    make_batched_search_fn,
+    search_route,
+    spectrum_pad,
+)
 from .checkpoint import SearchCheckpoint
 from .distill import AccelerationDistiller, DMDistiller, HarmonicDistiller
 from .folder import MultiFolder
@@ -118,8 +126,6 @@ class SearchConfig:
     # PEASOUP_HBM_BYTES env var / --hbm_bytes CLI flag)
     dm_block: int = 0  # DM trials per device call; 0 = auto from HBM budget
     checkpoint_file: str = ""  # resumable per-DM-trial result store
-    use_pallas: bool = True  # Pallas resample kernel on TPU backends
-    use_pallas_peaks: bool = True  # fused threshold+cluster Pallas kernel
     # device sharding: 0 = auto (all local TPU chips up to
     # max_num_threads, single-device elsewhere); N = force an N-chip
     # 'dm' mesh (tests use this on the virtual CPU mesh)
@@ -931,101 +937,47 @@ class PeasoupSearch:
             af_max, size
         ) > 0
         pallas_block = 0
-        if cfg.use_pallas and resample_fits:
+        if resample_fits:
             from ..ops.pallas import probe_pallas_resample
 
             # real compile+run probe, oracle-checked (off TPU: the twin)
             pallas_block = choose_block(af_max, size)
             if not probe_pallas_resample(size, pallas_block):
                 pallas_block = 0
-        # fused threshold+compact+cluster kernel: output is cluster
-        # peaks, so overflow means cluster count > max_peaks (rare)
-        # rather than raw crossings > max_peaks (common for bright
-        # pulsars) - the escalation key switches accordingly
-        pallas_peaks = False
-        if cfg.use_pallas_peaks:
-            from ..ops.pallas import probe_pallas_peaks
-
-            pallas_peaks = probe_pallas_peaks(
-                size_spec, cfg.nharmonics + 1,
-                max(cfg.max_peaks, self._learned_max_peaks) or cfg.max_peaks,
-            )
-        self._pallas_peaks = pallas_peaks
-        self._peaks_probe_nlev = cfg.nharmonics + 1
-        self._peaks_probe_nbins = size_spec
-        # fused matmul-rfft untwist + interbin + normalise kernel
-        # (ops/pallas/interbin.py): one streaming pass replaces XLA's
-        # FFT untwist/concat/normalise passes. Needs the peaks-kernel
-        # path (its output is pre-padded to PEAKS_BLOCK), a pow2 size
-        # whose half divides the block, and the bitwise oracle probe.
-        # PEASOUP_FUSED_FFT=0 restores the stock XLA FFT chain.
-        from ..ops.fft import _MIN_N
-        from ..ops.pallas.peaks import PEAKS_BLOCK
-
-        interbin_fits = (
-            size >= _MIN_N
-            and not (size & (size - 1))
-            and (size // 2) % PEAKS_BLOCK == 0
+        # the select runs where the Pallas resample does not
+        block_smax = 0 if pallas_block else select_smax
+        # the search program follows the platform and the series length
+        # (accel_search.search_route); on TPU every kernel it names is
+        # compiled, run and checked against its twin first (a failure
+        # raises KernelUnavailable)
+        route = search_route(
+            jax.default_backend(), size, packed=block_smax > 0
         )
-        fused_interbin = False
-        if pallas_peaks and os.environ.get("PEASOUP_FUSED_FFT", "1") != "0":
-            from ..ops.pallas import probe_pallas_interbin
-
-            if interbin_fits:
-                fused_interbin = probe_pallas_interbin(size, PEAKS_BLOCK)
-        self._fused_interbin = fused_interbin
-        # harmonic+peaks mega-kernel (ops/pallas/harmpeaks.py): fuses
-        # the whole harmonic-summing val chain AND the peaks walk into
-        # one VMEM-resident Pallas dispatch — removes the conv chain's
-        # HBM round trips and the conv->peaks layout copies. Gated on
-        # the bitwise compile+run oracle; PEASOUP_MEGA_HARM=0 restores
-        # the conv+peaks pair.
-        mega_harm = False
-        if pallas_peaks and os.environ.get("PEASOUP_MEGA_HARM", "1") != "0":
-            from ..ops.pallas import probe_pallas_harmpeaks
-
-            mega_harm = probe_pallas_harmpeaks(
-                size_spec, cfg.nharmonics,
-                max(cfg.max_peaks, self._learned_max_peaks) or cfg.max_peaks,
+        if route.kernels:
+            from ..ops.pallas import (
+                probe_pallas_dftspec,
+                probe_pallas_harmpeaks,
+                probe_pallas_interbin,
+                probe_pallas_specchain,
             )
-        self._mega_harm = mega_harm
-        # fused four-step DFT + untwist + interbin + normalise kernel
-        # (ops/pallas/dftspec.py): one Pallas dispatch replaces the DFT
-        # einsums, XLA's relayout copies around them, AND the interbin
-        # kernel for the packed select-resample path. 3-pass HIGH-class
-        # accuracy, gated by probe_pallas_dftspec's two-layer oracle
-        # (per-bin envelope vs the contraction-exact twin + the
-        # documented accuracy-class bound vs the HIGHEST chain);
-        # shape-gated here so survey-scale m takes the einsum chain
-        # instead of raising at trace time. PEASOUP_FUSED_DFT=0
-        # restores the einsum + interbin-kernel chain (exact HIGHEST).
-        from ..ops.pallas.dftspec import dftspec_supported
 
-        npad_spec = -(-size_spec // PEAKS_BLOCK) * PEAKS_BLOCK
-        dftspec_fits = dftspec_supported(size, npad_spec)
-        fused_dft = False
-        if fused_interbin and os.environ.get("PEASOUP_FUSED_DFT", "1") != "0":
-            from ..ops.pallas import probe_pallas_dftspec
-
-            if dftspec_fits:
-                fused_dft = probe_pallas_dftspec(size, npad_spec)
-        self._fused_dft = fused_dft
-        # fused once-per-trial spectrum chain (ops/pallas/specchain.py):
-        # deredden -> zap -> interbin in ONE streaming pass over the
-        # (dm_block, nbins) batch instead of three HBM walks. Gated on
-        # the compile+run oracle probe (bitwise parts + FMA-envelope
-        # amplitude); PEASOUP_FUSED_SPEC=0 restores the unfused stanza.
-        fused_spec = False
-        if os.environ.get("PEASOUP_FUSED_SPEC", "1") != "0":
-            from ..ops.pallas import probe_pallas_specchain
-
-            fused_spec = probe_pallas_specchain()
-        self._fused_spec = fused_spec
+            probe_pallas_specchain()
+            probe_pallas_harmpeaks(
+                size_spec, cfg.nharmonics,
+                max(cfg.max_peaks, self._learned_max_peaks),
+            )
+            if route.spectrum == "interbin":
+                probe_pallas_interbin(size, PEAKS_BLOCK)
+            elif route.spectrum == "dftspec":
+                probe_pallas_dftspec(size, spectrum_pad(size_spec))
+        self._route = route
         # which kernels the shapes admit, beside the ones the search
         # programs were built with (the route event after the waves)
         self._route_fits = {
-            "interbin_fits": bool(interbin_fits),
-            "dftspec_fits": bool(dftspec_fits),
+            "interbin_fits": interbin_fits(size),
+            "dftspec_fits": bool(
+                dftspec_supported(size, spectrum_pad(size_spec))
+            ),
             "select_smax": int(select_smax),
             "resample_fits": bool(resample_fits),
         }
@@ -1036,34 +988,19 @@ class PeasoupSearch:
 
             from jax.sharding import NamedSharding, PartitionSpec
 
-            def build_search(pb: int, pp: bool = pallas_peaks):
-                return make_sharded_search_fn(
-                    mesh, cfg.min_snr, axis="dm", pallas_block=pb,
-                    select_smax=select_smax if pb == 0 else 0,
-                    pallas_peaks=pp, fused_interbin=fused_interbin and pp,
-                    mega_harm=self._mega_harm and pp,
-                    fused_dft=self._fused_dft and pp,
-                    fused_spec=self._fused_spec,
-                )
-
+            search_block = make_sharded_search_fn(
+                mesh, cfg.min_snr, axis="dm", pallas_block=pallas_block,
+                select_smax=block_smax, route=route,
+            )
             # stage blocks directly onto the mesh (no hop through chip 0)
             self._dm_sharding = NamedSharding(mesh, PartitionSpec("dm"))
             self._mesh = mesh
         else:
-
-            def build_search(pb: int, pp: bool = pallas_peaks):
-                return make_batched_search_fn(
-                    cfg.min_snr, pb, select_smax if pb == 0 else 0,
-                    pallas_peaks=pp, fused_interbin=fused_interbin and pp,
-                    mega_harm=self._mega_harm and pp,
-                    fused_dft=self._fused_dft and pp,
-                    fused_spec=self._fused_spec,
-                )
-
+            search_block = make_batched_search_fn(
+                cfg.min_snr, pallas_block, block_smax, route
+            )
             self._dm_sharding = None
             self._mesh = None
-        search_block = build_search(pallas_block)
-        self._build_search = build_search
         self._cur_pallas_block = pallas_block
         self._active_search_block = search_block
         tim_len = min(size, trials.shape[1])
@@ -1224,7 +1161,7 @@ class PeasoupSearch:
                     with jax.default_device(jax.devices("cpu")[0]):
                         self._run_waves(
                             waves, len(chunks), per_dm_results, ckpt,
-                            progress, build_search, dispatch_lists,
+                            progress, dispatch_lists,
                             trials, tim_len, zapmask_dev, windows,
                             size=size, nsamps_valid=nsamps_valid,
                             pos5=pos5, pos25=pos25, tsamp=fil.tsamp,
@@ -1232,7 +1169,7 @@ class PeasoupSearch:
                 else:
                     self._run_waves(
                         waves, len(chunks), per_dm_results, ckpt,
-                        progress, build_search, dispatch_lists,
+                        progress, dispatch_lists,
                         trials, tim_len, zapmask_dev, windows,
                         size=size, nsamps_valid=nsamps_valid, pos5=pos5,
                         pos25=pos25, tsamp=fil.tsamp,
@@ -1329,22 +1266,12 @@ class PeasoupSearch:
                     self._dm_sharding = None
                     self._mesh = None
                     self._cur_pallas_block = 0
-                    self._pallas_peaks = False
-                    self._mega_harm = False
-                    self._fused_interbin = False
-                    self._fused_dft = False
+                    self._route = CPU_ROUTE
                     zapmask_dev = np.asarray(zapmask_dev)
                     windows = np.asarray(windows)
-
-                    def build_search(pb: int, pp: bool = False):
-                        return make_batched_search_fn(
-                            cfg.min_snr, 0, select_smax,
-                            pallas_peaks=False, fused_interbin=False,
-                            mega_harm=False, fused_dft=False,
-                        )
-
-                    self._build_search = build_search
-                    self._active_search_block = build_search(0)
+                    self._active_search_block = make_batched_search_fn(
+                        cfg.min_snr, 0, select_smax, CPU_ROUTE
+                    )
                     log.warning(
                         "device OOM after the subband fall-through; "
                         "retrying the search on the CPU backend: %.200s",
@@ -1366,11 +1293,11 @@ class PeasoupSearch:
         timers["search_device"] = sp_search.seconds
         tel.event(
             "search_route", backend=jax.default_backend(),
-            pallas_peaks=bool(self._pallas_peaks),
-            mega_harm=bool(self._mega_harm),
-            fused_interbin=bool(self._fused_interbin),
-            fused_dft=bool(self._fused_dft),
-            fused_spec=bool(self._fused_spec),
+            pallas_peaks=self._route.kernels,
+            mega_harm=self._route.kernels,
+            fused_interbin=self._route.spectrum in ("interbin", "dftspec"),
+            fused_dft=self._route.spectrum == "dftspec",
+            fused_spec=self._route.kernels,
             resample_block=int(self._cur_pallas_block),
             **self._route_fits,
         )
@@ -1515,7 +1442,7 @@ class PeasoupSearch:
         )
 
     def _run_waves(
-        self, waves, n_chunks, per_dm_results, ckpt, progress, build_search,
+        self, waves, n_chunks, per_dm_results, ckpt, progress,
         dispatch_lists, trials, tim_len, zapmask_dev, windows,
         *, size, nsamps_valid, pos5, pos25, tsamp,
     ) -> None:
@@ -1908,6 +1835,9 @@ class PeasoupSearch:
 
         mp0 = max(cfg.max_peaks, self._learned_max_peaks)
         spec_pad = self._learned_total_pad
+        # overflow: raw crossings outgrew the compaction (jnp path) or
+        # clusters outgrew it (harmonic-peaks kernel)
+        kernels = self._route.kernels
         pend = []
         packs = []
         with tel.stage("search.dispatch"):
@@ -1915,13 +1845,7 @@ class PeasoupSearch:
                 peaks, padded = self._dispatch_chunk(
                     chunk, *args, mp0, **disp
                 )
-                # record which peaks mode produced this chunk: a
-                # mid-wave degrade must not re-judge earlier
-                # fused-kernel chunks by raw-crossing counts
-                pend.append(
-                    [chunk, mp0, peaks, padded,
-                     getattr(self, "_pallas_peaks", False)]
-                )
+                pend.append([chunk, mp0, peaks, padded])
                 packs.append(
                     pack_chunk_results(
                         peaks.idxs, peaks.snrs, peaks.counts,
@@ -1944,7 +1868,7 @@ class PeasoupSearch:
         redispatched = []
         off = 0
         for entry in pend:
-            chunk, max_peaks, peaks, padded, fused = entry
+            chunk, max_peaks, peaks, padded = entry
             n = peaks.counts.shape[0] * nlev * padded
             counts = packed_all[off : off + n].reshape(-1, nlev, padded)
             ccounts = packed_all[off + n : off + 2 * n].reshape(
@@ -1955,9 +1879,7 @@ class PeasoupSearch:
             )
             off += 2 * n + 2 * spec_pad
             redisp = False
-            # overflow: raw crossings outgrew the compaction (jnp
-            # path) or clusters outgrew it (fused-kernel path)
-            ov = ccounts if fused else counts
+            ov = ccounts if kernels else counts
             # escalations, their probes and fetches (the enclosing
             # search.wave span closes this one if an escalation raises)
             sp = None
@@ -1979,36 +1901,22 @@ class PeasoupSearch:
                     "max_peaks_escalated", old=int(old_mp),
                     new=int(max_peaks), observed=int(ov.max()),
                 )
-                # the redispatch below runs on the CURRENT active search
-                # block: resync the entry-local flag so the overflow
-                # semantics (raw counts for the jnp path, cluster counts
-                # for the kernels) match the block actually used
-                fused = getattr(self, "_pallas_peaks", False)
-                if fused:
-                    # the kernels were probed at the startup compaction
+                if kernels:
+                    # the kernel was probed at the startup compaction
                     # size: probe the escalated shape too (on a TPU a
                     # failure raises, naming the kernel)
-                    from ..ops.pallas import (
-                        probe_pallas_harmpeaks, probe_pallas_peaks,
-                    )
+                    from ..ops.pallas import probe_pallas_harmpeaks
 
-                    if getattr(self, "_mega_harm", False):
-                        probe_pallas_harmpeaks(
-                            self._peaks_probe_nbins,
-                            self._peaks_probe_nlev - 1, max_peaks,
-                        )
-                    else:
-                        probe_pallas_peaks(
-                            self._peaks_probe_nbins,
-                            self._peaks_probe_nlev, max_peaks,
-                        )
+                    probe_pallas_harmpeaks(
+                        size // 2 + 1, cfg.nharmonics, max_peaks
+                    )
                 peaks, padded = self._dispatch_chunk(
                     chunk, *args, max_peaks, **disp
                 )
                 counts = tel.fetched(np.asarray(peaks.counts))
                 ccounts = tel.fetched(np.asarray(peaks.ccounts))
-                ov = ccounts if fused else counts
-                entry[1:] = [max_peaks, peaks, padded, fused]
+                ov = ccounts if kernels else counts
+                entry[1:] = [max_peaks, peaks, padded]
                 redisp = True
             if sp is not None:
                 sp.end()
@@ -2024,7 +1932,7 @@ class PeasoupSearch:
         # the size so the next wave's speculation covers it.
         with tel.stage("search.unpack"):
             for i, (entry, ccounts) in enumerate(zip(pend, ccounts_list)):
-                chunk, max_peaks, peaks, padded, _ = entry
+                chunk, max_peaks, peaks, padded = entry
                 cc0 = np.minimum(ccounts, max_peaks)
                 total = int(cc0.sum())
                 total_pad = 1 << max(6, int(np.ceil(np.log2(max(1, total)))))

@@ -33,6 +33,7 @@ from peasoup_tpu.analysis.contracts import (
 )
 from peasoup_tpu.analysis.findings import Baseline, Finding
 from peasoup_tpu.analysis.rules import all_rules
+from peasoup_tpu.ops.pallas.registry import kernel_specs
 from peasoup_tpu.analysis.runner import (
     AUDIT_SCHEMA_PATH,
     render_text,
@@ -648,7 +649,7 @@ class TestKernelEngine:
 
     def test_real_kernel_registry_is_clean(self):
         result = _full_audit_result()
-        assert len(result.kernels_checked) >= 9
+        assert len(result.kernels_checked) == len(kernel_specs())
         assert result.clean
 
     def test_registry_covers_every_pallas_module(self):
@@ -738,7 +739,7 @@ class TestFourEngineAcceptance:
         assert result.clean, render_text(result, verbose=True)
         assert result.files_scanned > 100
         assert len(result.programs_checked) >= 30
-        assert len(result.kernels_checked) >= 9
+        assert len(result.kernels_checked) == len(kernel_specs())
         assert len(result.ladder_rungs) >= 2
         assert all(
             len(v) >= 2 for v in result.ladder_coverage.values()

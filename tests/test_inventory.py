@@ -24,7 +24,7 @@ INVENTORY = [
     ("peasoup_tpu.ops.peaks", [
         "find_peaks_device", "cluster_peaks", "cluster_peaks_device",
     ]),
-    ("peasoup_tpu.ops.pallas.peaks", ["find_cluster_peaks_pallas"]),
+    ("peasoup_tpu.ops.pallas.harmpeaks", ["find_harmonic_cluster_peaks"]),
     ("peasoup_tpu.ops.fold", ["fold_time_series", "fold_bins_np"]),
     ("peasoup_tpu.ops.fold_optimise", ["FoldOptimiser"]),
     ("peasoup_tpu.ops.rednoise", [

@@ -185,154 +185,67 @@ class TestBlockCoreParity:
         np.testing.assert_array_equal(np.asarray(plain.counts), np.asarray(pallas.counts))
 
 
-class TestPallasPeaks:
-    """Fused threshold+compact+cluster kernel (ops/pallas/peaks.py) vs
-    the jnp find_peaks_device + cluster_peaks_device pair, interpret
-    mode. Covers sparse/dense crossings, window edges, multi-level
-    tables, cluster overflow, and row/bin padding."""
+@pytest.mark.parametrize("backend,size,packed,want", [
+    ("tpu", 1 << 13, True, (True, "fft")),  # below the interbin kernel
+    ("tpu", 1 << 17, True, (True, "dftspec")),  # the tutorial cell
+    ("tpu", 1 << 21, True, (True, "interbin")),  # the survey cell
+    ("tpu", 1 << 23, True, (True, "interbin")),  # the four-chip cell
+    ("cpu", 1 << 17, True, (False, "fft")),
+    ("cpu", 1 << 21, True, (False, "fft")),
+    # the Pallas resample emits whole series, not planes for the DFT
+    ("tpu", 1 << 17, False, (True, "interbin")),
+])
+def test_search_route(backend, size, packed, want):
+    """One search program per platform and series length."""
+    from peasoup_tpu.pipeline.accel_search import SearchRoute, search_route
 
-    def test_fuzz_parity(self):
-        import jax.numpy as jnp
+    assert search_route(backend, size, packed) == SearchRoute(*want)
 
-        from peasoup_tpu.ops.pallas.peaks import find_cluster_peaks_pallas
-        from peasoup_tpu.ops.peaks import (
-            cluster_peaks_device,
-            find_peaks_device,
-        )
 
-        rng = np.random.default_rng(7)
-        for trial in range(6):
-            rows = int(rng.integers(1, 5))
-            n = int(rng.integers(600, 9000))
-            dense = rng.random() < 0.4
-            s = (rng.normal(size=(rows, n)).astype(np.float32) ** 2) * (
-                3.0 if dense else 1.0
-            )
-            thr = 6.0
-            nlev = 3
-            windows = np.stack(
-                [
-                    [int(rng.integers(0, n // 3)),
-                     int(rng.integers(n // 2, n + 1))]
-                    for _ in range(nlev)
-                ]
-            ).astype(np.int32)
-            lvl = int(rng.integers(0, nlev))
-            mx = 32
-            sp = jnp.asarray(s)
-            ci, cs, rc, cc = find_cluster_peaks_pallas(
-                sp, jnp.asarray(windows), lvl,
-                threshold=thr, max_peaks=mx, interpret=True,
-            )
-            i_, s_, c_ = find_peaks_device(
-                sp, jnp.float32(thr), jnp.int32(windows[lvl, 0]),
-                jnp.int32(windows[lvl, 1]), max_peaks=1 << 13,
-            )
-            ji, js, jc = cluster_peaks_device(i_, s_, jnp.int32(n))
-            ci, cs, rc, cc = map(np.asarray, (ci, cs, rc, cc))
-            ji, js, jc, c_ = map(np.asarray, (ji, js, jc, c_))
-            np.testing.assert_array_equal(rc, c_)
-            np.testing.assert_array_equal(cc, jc)
-            for r in range(rows):
-                k = min(int(jc[r]), mx)
-                np.testing.assert_array_equal(ci[r, :k], ji[r, :k])
-                np.testing.assert_array_equal(cs[r, :k], js[r, :k])
-                if int(jc[r]) <= mx:
-                    assert (ci[r, k:] == n).all()
-                    assert (cs[r, k:] == 0).all()
+def test_block_core_kernel_route_matches_twin():
+    """The TPU route below 2^14 points (the specchain kernel, the jnp
+    spectrum, the harmonic-peaks kernel), in interpret mode, against
+    the CPU route's jnp twins: the same peaks, S/N inside the
+    specchain kernel's amplitude envelope."""
+    from peasoup_tpu.ops.pallas.specchain import s0_envelope
+    from peasoup_tpu.pipeline.accel_search import (
+        SearchRoute,
+        search_block_core,
+        search_route,
+    )
+    from peasoup_tpu.pipeline.search import _level_windows
 
-    def test_block_core_pallas_peaks_matches_jnp(self):
-        import jax.numpy as jnp
-
-        from peasoup_tpu.pipeline.accel_search import search_block_core
-        from peasoup_tpu.pipeline.search import _level_windows
-        import peasoup_tpu.ops.pallas.peaks as ppk
-
-        rng = np.random.default_rng(3)
-        size, nharms = 2048, 2
-        d, a = 2, 3
-        t = np.arange(size)
-        tims = jnp.asarray(
-            np.clip(
-                rng.normal(30, 3, size=(d, size))
-                + 12.0 * (((t * 0.000256) / 0.016) % 1.0 < 0.08),
-                0, 255,
-            ).astype(np.uint8)
-        )
-        afs = jnp.asarray(np.zeros((d, a), np.float32))
-        zap = jnp.zeros(size // 2 + 1, bool)
-        windows = jnp.asarray(_level_windows(size, nharms, 0.1, 1100.0, 0.000256))
-        kw = dict(
-            threshold=6.0, size=size, nsamps_valid=size, nharms=nharms,
-            max_peaks=64, pos5=8, pos25=80,
-        )
-        plain = search_block_core(tims, afs, zap, windows, **kw)
-        # route the kernel through interpret mode for the CPU test
-        # (production now uses the merged multi-level kernel)
-        orig = ppk._build_multi.__wrapped__
-
-        def interp_build(*args):
-            return orig(*args[:-1], True)
-
-        ppk._build_multi.cache_clear()
-        ppk._build_multi = interp_build
-        try:
-            fused = search_block_core(
-                tims, afs, zap, windows, **kw, pallas_peaks=True
-            )
-        finally:
-            import functools
-            ppk._build_multi = functools.lru_cache(maxsize=None)(orig)
+    rng = np.random.default_rng(3)
+    size, nharms = 2048, 2
+    d, a = 2, 3
+    t = np.arange(size)
+    tims = jnp.asarray(
+        np.clip(
+            rng.normal(30, 3, size=(d, size))
+            + 12.0 * (((t * 0.000256) / 0.016) % 1.0 < 0.08),
+            0, 255,
+        ).astype(np.uint8)
+    )
+    afs = jnp.asarray(np.zeros((d, a), np.float32))
+    zap = jnp.zeros(size // 2 + 1, bool)
+    windows = jnp.asarray(_level_windows(size, nharms, 0.1, 1100.0, 0.000256))
+    kw = dict(
+        threshold=6.0, size=size, nsamps_valid=size, nharms=nharms,
+        max_peaks=64, pos5=8, pos25=80,
+    )
+    route = search_route("tpu", size, packed=True)
+    assert route == SearchRoute(kernels=True, spectrum="fft")
+    twin = search_block_core(tims, afs, zap, windows, **kw)
+    kern = search_block_core(
+        tims, afs, zap, windows, **kw, route=route, pallas_interpret=True
+    )
+    for field in ("idxs", "counts", "ccounts"):
         np.testing.assert_array_equal(
-            np.asarray(plain.idxs), np.asarray(fused.idxs)
+            np.asarray(getattr(twin, field)), np.asarray(getattr(kern, field))
         )
-        np.testing.assert_array_equal(
-            np.asarray(plain.snrs), np.asarray(fused.snrs)
-        )
-        np.testing.assert_array_equal(
-            np.asarray(plain.counts), np.asarray(fused.counts)
-        )
-        np.testing.assert_array_equal(
-            np.asarray(plain.ccounts), np.asarray(fused.ccounts)
-        )
-
-
-class TestPeaksPaddedLevels:
-    def test_padded_garbage_tail_masked(self):
-        """The production input configuration: block-aligned levels with
-        a garbage tail past the true nbins plus the explicit nbins
-        override — the kernel must mask the tail (window clamp) and pad
-        idx slots with the TRUE nbins sentinel."""
-        import jax.numpy as jnp
-
-        import peasoup_tpu.ops.pallas.peaks as ppk
-
-        nbins, npad, rows = 1025, 4096, 8
-        rng = np.random.default_rng(5)
-        s = np.abs(rng.normal(size=(rows, nbins))).astype(np.float32)
-        s[:, 100] = 30.0
-        sp = jnp.asarray(
-            np.pad(s, ((0, 0), (0, npad - nbins)), constant_values=1e9)
-        )
-        # window hi deliberately set PAST nbins: the clamp must cap it
-        windows = jnp.asarray(np.asarray([[10, npad]], np.int32))
-        orig = ppk._build_multi.__wrapped__
-        ppk._build_multi.cache_clear()
-        ppk._build_multi = lambda *a: orig(*a[:-1], True)  # interpret
-        try:
-            ci, cs, rc, cc = ppk.find_cluster_peaks_multi(
-                [sp], windows, threshold=9.0, max_peaks=16,
-                scales=(1.0,), nbins=nbins,
-            )
-        finally:
-            import functools
-
-            ppk._build_multi = functools.lru_cache(maxsize=None)(orig)
-        rc, cc, ci, cs = map(np.asarray, (rc, cc, ci, cs))
-        assert (rc[:, 0] == 1).all(), rc[:, 0]  # only the planted peak
-        assert (cc[:, 0] == 1).all()
-        assert (ci[:, 0, 0] == 100).all()
-        assert (ci[:, 0, 1:] == nbins).all()  # TRUE-nbins sentinel
+    assert int(np.asarray(twin.ccounts).sum()) > 0
+    snr_twin, snr_kern = np.asarray(twin.snrs), np.asarray(kern.snrs)
+    assert (np.abs(snr_kern - snr_twin) <= s0_envelope(snr_twin)).all()
 
 
 class TestHarmPeaks:

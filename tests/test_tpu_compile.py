@@ -191,29 +191,11 @@ def test_specchain(one_chip, phase):
 
 
 @pytest.mark.parametrize("phase", ["A", "B"])
-def test_peaks(one_chip, phase):
-    from peasoup_tpu.ops.pallas import peaks
-
-    assert peaks._SUB == 24
-    nlev = NHARMS + 1
-    scales = tuple(2.0 ** (-h / 2.0) for h in range(nlev))
-    windows = jnp.asarray(level_windows(phase))
-    nbins = PHASES[phase]["size"] // 2 + 1
-    compile_on(
-        one_chip,
-        lambda s: peaks.find_cluster_peaks_multi(
-            [s] * nlev, windows, threshold=9.0, max_peaks=MAX_PEAKS,
-            scales=scales, nbins=nbins,
-        ),
-        ((PHASES[phase]["dm_block"], PHASES[phase]["accels"], npad(phase)),
-         jnp.float32),
-    )
-
-
-@pytest.mark.parametrize("phase", ["A", "B"])
 def test_harmpeaks(one_chip, phase):
+    from peasoup_tpu.ops.pallas import peaks
     from peasoup_tpu.ops.pallas.harmpeaks import find_harmonic_cluster_peaks
 
+    assert peaks._SUB == 24
     scales = tuple(2.0 ** (-h / 2.0) for h in range(NHARMS + 1))
     windows = jnp.asarray(level_windows(phase))
     nbins = PHASES[phase]["size"] // 2 + 1
@@ -226,6 +208,35 @@ def test_harmpeaks(one_chip, phase):
         ((PHASES[phase]["dm_block"], PHASES[phase]["accels"], npad(phase)),
          jnp.float32),
     )
+
+
+@pytest.mark.parametrize("phase", ["A", "B"])
+def test_search_program(one_chip, phase):
+    """The whole one-chip search program at the route the phase's shape
+    takes on a TPU: the specchain kernel, the spectrum kernel (the DFT
+    kernel at 2^17 points, the interbin kernel at 2^21) and the
+    harmonic-peaks kernel."""
+    from peasoup_tpu.pipeline.accel_search import (
+        make_batched_search_fn, search_route,
+    )
+
+    size, d = PHASES[phase]["size"], PHASES[phase]["dm_block"]
+    route = search_route("tpu", size, packed=True)
+    assert route.spectrum == ("dftspec" if phase == "A" else "interbin")
+    fn = make_batched_search_fn(9.0, 0, 1, route)
+    args = [
+        jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+            ((d, size), jnp.uint8),
+            ((d, PHASES[phase]["accels"]), jnp.float32),
+            ((size // 2 + 1,), jnp.bool_),
+            ((NHARMS + 1, 2), jnp.int32),
+        )
+    ]
+    text = fn.lower(
+        *args, size=size, nsamps_valid=size, nharms=NHARMS,
+        max_peaks=MAX_PEAKS, pos5=26, pos25=268,
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
 
 
 def test_spchain(one_chip):
@@ -299,14 +310,13 @@ def test_sharded_search_on_four_chips(topo):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from peasoup_tpu.parallel.sharded_search import make_sharded_search_fn
+    from peasoup_tpu.pipeline.accel_search import search_route
 
     mesh = Mesh(np.array(topo.devices[:4]), ("dm",))
-    fn = make_sharded_search_fn(
-        mesh, 9.0, pallas_block=0, select_smax=1, pallas_peaks=True,
-        fused_interbin=True, mega_harm=True, fused_dft=False,
-        fused_spec=True,
-    )
     size = PHASES["B"]["size"]
+    fn = make_sharded_search_fn(
+        mesh, 9.0, select_smax=1, route=search_route("tpu", size, True)
+    )
     d = 4 * PHASES["B"]["dm_block"]
     dm, rep = NamedSharding(mesh, P("dm")), NamedSharding(mesh, P())
     args = (
@@ -420,14 +430,13 @@ def test_sharded_search_at_the_64us_beam(topo):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from peasoup_tpu.parallel.sharded_search import make_sharded_search_fn
+    from peasoup_tpu.pipeline.accel_search import search_route
 
     mesh = Mesh(np.array(topo.devices[:4]), ("dm",))
-    fn = make_sharded_search_fn(
-        mesh, 9.0, pallas_block=0, select_smax=1, pallas_peaks=True,
-        fused_interbin=True, mega_harm=True, fused_dft=False,
-        fused_spec=True,
-    )
     size, d = 1 << 23, 4 * 7
+    fn = make_sharded_search_fn(
+        mesh, 9.0, select_smax=1, route=search_route("tpu", size, True)
+    )
     dm, rep = NamedSharding(mesh, P("dm")), NamedSharding(mesh, P())
     compiled = fn.lower(
         jax.ShapeDtypeStruct((d, size), jnp.uint8, sharding=dm),
