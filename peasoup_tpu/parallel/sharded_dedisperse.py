@@ -3,9 +3,12 @@
 The reference dedisperses across ALL GPUs in one node
 (`dedisp_create_plan_multi`, reference include/transforms/
 dedisperser.hpp:25-31). Here the DM-trial axis is laid out on the mesh's
-``dm`` axis with ``shard_map``. The input's packed bytes go to every
-chip once per beam (2 bits a sample for 2-bit data: a quarter of the
-unpacked bytes, a sixteenth of an f32 copy). Each chip then walks the
+``dm`` axis with ``shard_map``. The host sends the input's packed bytes
+once per beam (2 bits a sample for 2-bit data: a quarter of the
+unpacked bytes, a sixteenth of an f32 copy), each chip a 1/N block of
+consecutive time rows; an all-gather over the chips' interconnect then
+assembles the whole input on every chip, since each chip's trials read
+every channel at every sample. Each chip then walks the
 channel-chunk x DM-segment decomposition of the one-chip
 ``ops.dedisperse.dedisperse_device``, sized from the same budget, over
 ITS slice of the delay table: it unpacks one channel chunk at a time,
@@ -47,8 +50,9 @@ def _shard_map_nocheck(local_fn, mesh, in_specs, out_specs):
 
 
 def packed_input(fil) -> tuple[np.ndarray, int]:
-    """(samples, nbits) to replicate for ``fil``: its packed bytes when
-    whole bytes hold whole channel groups, else its (T, C) samples."""
+    """(samples, nbits) to lay over the mesh for ``fil``: its packed
+    bytes when whole bytes hold whole channel groups, else its (T, C)
+    samples."""
     raw = getattr(fil, "raw", None)
     if (
         raw is not None and fil.nbits in (1, 2, 4)
@@ -186,23 +190,41 @@ def block_rows(pallas: bool, t_in: int, out_nsamps: int, spread: int) -> int:
     return -(-rows // 32) * 32
 
 
+def _put_rows(rows: np.ndarray, mesh: Mesh, axis: str):
+    """(head, tail, bytes sent) for the (T, B) ``rows`` on ``mesh``: the
+    leading T - T % n_dev rows sharded over ``axis`` (each chip takes
+    T / n_dev consecutive rows from the host), the few rows past them
+    replicated. Both are views of ``rows``: no host copy."""
+    n_dev = mesh.shape[axis]
+    cut = rows.shape[0] - rows.shape[0] % n_dev
+    head = jax.device_put(rows[:cut], NamedSharding(mesh, P(axis, None)))
+    tail = jax.device_put(rows[cut:], NamedSharding(mesh, P()))
+    return head, tail, rows[:cut].nbytes + n_dev * rows[cut:].nbytes
+
+
 @lru_cache(maxsize=None)
 def _blocked_bytes(mesh: Mesh, nrows: int):
-    """Jitted replicated (T, B) -> (B, nrows, 128): each byte column (a
-    group of channels) becomes blocked rows of 128 samples, zero past T,
-    so a channel chunk is one contiguous slice in the kernels' layout."""
+    """Jitted (head, tail) from ``_put_rows`` -> replicated (B, nrows,
+    128): the head all-gathered over the mesh and the tail appended give
+    the (T, B) rows, and each byte column (a group of channels) becomes
+    blocked rows of 128 samples, zero past T, so a channel chunk is one
+    contiguous slice in the kernels' layout."""
+    rep = NamedSharding(mesh, P())
 
     @jax.named_scope("unpack_fil")
-    def blocked(rows):
+    def blocked(head, tail):
+        rows = jnp.concatenate(
+            [jax.lax.with_sharding_constraint(head, rep), tail]
+        )
         t_in = rows.shape[0]
         x = jnp.pad(rows.T, ((0, 0), (0, nrows * 128 - t_in)))
         return x.reshape(rows.shape[1], nrows, 128)
 
-    return jax.jit(blocked, out_shardings=NamedSharding(mesh, P()))
+    return jax.jit(blocked, out_shardings=rep)
 
 
 class _Layout(NamedTuple):
-    rows: np.ndarray  # (T, bytes a sample row) as replicated
+    rows: np.ndarray  # (T, bytes a sample row) as the file packs it
     delays: np.ndarray  # padded to n_dev * per_dev trials
     use_pallas: bool
     per_dev: int
@@ -263,20 +285,24 @@ def chip_bytes(
     use_pallas: bool | None = None, interpret: bool = False,
 ) -> int:
     """Bytes one chip holds at the peak of ``dedisperse_sharded`` with
-    these arguments: the replicated input beside its blocked copy while
-    it is laid out, then the blocked copy, one channel chunk unpacked
-    (8-bit and f32), a DM segment's f32 partials (the sum, the chunk's
-    part and the scaled sum) and the chip's share of the 8-bit trials.
-    Callers shard only where this fits a chip."""
+    these arguments: while the input is laid out, the chip's block of
+    rows from the host (its 1/n_dev share and the tail rows), the rows
+    all-gathered from every chip, their padded transpose and its blocked
+    copy (the chip's compiler keeps both); then the blocked copy, one
+    channel chunk unpacked (8-bit and f32), a DM segment's f32 partials
+    (the sum, the chunk's part and the scaled sum) and the chip's share
+    of the 8-bit trials. Callers shard only where this fits a chip."""
     lay = _layout(
         samples, delays, out_nsamps, n_dev, nbits=nbits, block=block,
         chunk_bytes=chunk_bytes, use_pallas=use_pallas, interpret=interpret,
     )
-    blocked = lay.rows.shape[1] * lay.nrows * 128
+    t_in, width = lay.rows.shape
+    own = (t_in // n_dev + t_in % n_dev) * width
+    blocked = width * lay.nrows * 128
     chunk = lay.cc * lay.nrows * 128 * 5
     partials = 3 * lay.seg * out_nsamps * 4
     share = lay.per_dev * out_nsamps
-    return int(max(lay.rows.nbytes + blocked,
+    return int(max(own + lay.rows.nbytes + 2 * blocked,
                    blocked + chunk + partials + share))
 
 
@@ -333,12 +359,15 @@ def dedisperse_sharded(
     )
 
     with tel.stage("dedispersion.replicate"):
-        rows_dev = jax.block_until_ready(_blocked_bytes(mesh, lay.nrows)(
-            jax.device_put(lay.rows, NamedSharding(mesh, P()))
-        ))
+        head, tail, h2d = _put_rows(lay.rows, mesh, axis)
+        rows_dev = jax.block_until_ready(
+            _blocked_bytes(mesh, lay.nrows)(head, tail)
+        )
+        del head, tail  # the chip's block goes before the trials come
     tel.incr("dedisp.shards", n_dev)
     tel.incr("dedisp.chunks", (lay.per_dev // lay.seg) * -(-nchans // lay.cc))
     tel.incr("dedisp.replicated_bytes", int(lay.rows.nbytes))
+    tel.incr("dedisp.h2d_bytes", int(h2d))
     with tel.stage("dedispersion.shard"):
         fn = _sharded_program(
             mesh, axis, lay.rows.shape[0], nchans, nbits, lay.per_dev,

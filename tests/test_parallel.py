@@ -278,20 +278,34 @@ class TestPackedShardedDedispersion:
         return fil, delays, kill, nsamps - int(delays.max())
 
     # ndm 77 over 4 chips: not a multiple of 4 x 8, and 20 trials a chip
-    # make two DM segments under the small budget below
-    @pytest.mark.parametrize("nbits,use_pallas", [
-        (2, False), (8, False), (2, True),
+    # make two DM segments under the small budget below; 2050 samples
+    # leave two time rows past the chips' equal blocks
+    @pytest.mark.parametrize("nbits,use_pallas,nsamps", [
+        pytest.param(2, False, 2048, id="2-False"),
+        pytest.param(8, False, 2048, id="8-False"),
+        pytest.param(2, True, 2048, id="2-True"),
+        pytest.param(2, False, 2050, id="2-False-2050"),
+        pytest.param(8, False, 2050, id="8-False-2050"),
     ])
-    def test_bitwise_equals_one_chip_engine(self, nbits, use_pallas):
+    def test_bitwise_equals_one_chip_engine(self, nbits, use_pallas, nsamps,
+                                            monkeypatch):
         from peasoup_tpu import obs
         from peasoup_tpu.io.sigproc import pack_bits
         from peasoup_tpu.ops.dedisperse import dedisperse_device, output_scale
+        from peasoup_tpu.parallel import sharded_dedisperse as sd
         from peasoup_tpu.parallel.sharded_dedisperse import (
             dedisperse_sharded, shard_plan,
         )
 
+        put_rows, puts = sd._put_rows, []
+
+        def spy(*args):
+            puts.append(put_rows(*args))
+            return puts[-1]
+
+        monkeypatch.setattr(sd, "_put_rows", spy)
         ndm = 77
-        fil, delays, kill, out_nsamps = self.inputs(ndm)
+        fil, delays, kill, out_nsamps = self.inputs(ndm, nsamps)
         scale = output_scale(2, int(kill.sum()))
         single = np.asarray(dedisperse_device(
             fil, delays, kill, out_nsamps, scale=scale,
@@ -324,6 +338,26 @@ class TestPackedShardedDedispersion:
         assert {"dedispersion.replicate", "dedispersion.shard"} <= set(
             tel.timers
         )
+        # the host sent each chip a quarter of the time rows, and the few
+        # rows past them to every chip
+        (head, tail, _), = puts
+        rows = samples.reshape(nsamps, -1)
+        cut = nsamps - nsamps % 4
+        assert {s.data.shape for s in head.addressable_shards} == {
+            (cut // 4, rows.shape[1])}
+        assert tail.shape == (nsamps % 4, rows.shape[1])
+        assert tel.counters["dedisp.h2d_bytes"] == (
+            samples.nbytes + 3 * rows[cut:].nbytes)
+        # every chip gathers the whole blocked input, byte for byte
+        lay = sd._layout(
+            samples, delays, out_nsamps, 4, nbits=nbits, block=2,
+            chunk_bytes=budget, use_pallas=use_pallas, interpret=use_pallas,
+        )
+        blocked = sd._blocked_bytes(mesh, lay.nrows)(head, tail)
+        want = np.pad(rows.T, ((0, 0), (0, lay.nrows * 128 - nsamps)))
+        for s in blocked.addressable_shards:
+            np.testing.assert_array_equal(
+                np.asarray(s.data), want.reshape(-1, lay.nrows, 128))
 
 
 class TestShardedSearchDriver:
@@ -387,7 +421,8 @@ class TestShardedSearchDriver:
     # the cell htru_medlat.one_beam.t4 per chip: 1024 channels x
     # (2^23 + 4096) samples, 743 DM trials over 4 chips, against a v5e's
     # 16909336064 B; packed 1, 2 or 4 bits a sample it fits, stored
-    # 8-bit it replicates four times the bytes and has to stay on one
+    # 8-bit every chip gathers four times the bytes and it has to stay
+    # on one
     @pytest.mark.parametrize("nbits,fits", [
         (1, True), (2, True), (4, True), (8, False),
     ])
@@ -405,6 +440,9 @@ class TestShardedSearchDriver:
         need = chip_bytes(samples, delays, nsamps - 2630, 4, nbits=nbits,
                           use_pallas=False)
         assert need >= samples.nbytes + ndm * (nsamps - 2630) // 4
+        # laying the input out: a chip's quarter, the gathered rows, their
+        # padded transpose and its blocked copy
+        assert need >= samples.nbytes // 4 + 3 * samples.nbytes
         assert (need <= 16909336064) is fits
 
     def test_replicated_input_past_a_chip_stays_on_one(self, tmp_path,

@@ -391,11 +391,23 @@ def test_sharded_dedisperse_on_four_chips(topo):
     assert mem.temp_size_in_bytes < 3 * CHUNK_BYTES
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < 16e9 * 0.8
+    # the blocking program takes each chip's quarter of the time rows
+    # from the host and all-gathers them; its peak is chip_bytes' layout
+    # term: the quarter, the gathered rows, their padded transpose and
+    # the blocked copy
+    rows_bytes = t_in * nchans // 4
     blocked = _blocked_bytes(mesh, nrows).lower(
-        jax.ShapeDtypeStruct((t_in, nchans // 4), jnp.uint8, sharding=rep)
+        jax.ShapeDtypeStruct((t_in, nchans // 4), jnp.uint8,
+                             sharding=NamedSharding(mesh, P("dm", None))),
+        jax.ShapeDtypeStruct((0, nchans // 4), jnp.uint8, sharding=rep),
     ).compile()
-    assert blocked.memory_analysis().output_size_in_bytes < 1.01 * (
-        t_in * nchans // 4)
+    assert "all-gather" in blocked.as_text()
+    mem = blocked.memory_analysis()
+    assert mem.argument_size_in_bytes == rows_bytes // 4
+    assert mem.output_size_in_bytes < 1.01 * rows_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) <= (
+        rows_bytes // 4 + rows_bytes + 2 * nchans // 4 * nrows * 128)
 
 
 def test_harmpeaks_probe_at_the_64us_beam(one_chip):
